@@ -10,16 +10,17 @@ at constant per-window collective cost.
 
 Those counts are not asserted from prose — they are **pinned by jaxpr
 inspection** here (and in ``tests/test_read_lane.py``): the model walks
-every sub-jaxpr (scan bodies, shard_map bodies, pjit calls) of the
+every sub-jaxpr (scan bodies, shard_map bodies, jit calls) of the
 actual production programs and censuses collective primitives. The
-analytic projection then combines the pinned counts with the recorded
-single-chip v5e measurements (``mesh_engine_r05`` /
-``mesh_engine_r17`` in results.json) to project mixed SET+GET windows
-across chip counts.
+analytic projection then combines the pinned counts with per-chip SET
+and GET rates to project mixed SET+GET windows across chip counts. The
+rates are arguments: they must come from a run on the attached chip
+(none is recorded yet), so without them only the census prints.
 
 Usage::
 
-    JAX_PLATFORMS=cpu python benchmarks/ici_model.py [--record]
+    JAX_PLATFORMS=cpu python benchmarks/ici_model.py \
+        [--set-rate OPS_PER_S --get-rate OPS_PER_S]
 
 Run under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to
 trace against a genuinely multi-device mesh; the jaxpr census is
@@ -39,6 +40,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # ---------------------------------------------------------------------------
 # Jaxpr collective census
@@ -53,22 +55,29 @@ COLLECTIVE_PRIMS = frozenset({
 })
 
 
-def _sub_jaxprs(v):
-    from jax.extend import core as jex_core  # noqa: F401  (version probe)
-    from jax import core
+# primitives that carry their body as a sub-jaxpr parameter: finding
+# none under one of these means the walker no longer understands the
+# jaxpr representation, and every count below it would be vacuous
+_HIGHER_ORDER_PRIMS = frozenset({
+    "scan", "while", "cond", "jit", "pjit", "shard_map", "closed_call",
+    "core_call", "remat", "checkpoint", "custom_jvp_call",
+    "custom_vjp_call",
+})
 
-    jaxpr_types = []
-    for mod in (core,):
-        for nm in ("Jaxpr", "ClosedJaxpr"):
-            t = getattr(mod, nm, None)
-            if t is not None:
-                jaxpr_types.append(t)
-    jaxpr_types = tuple(jaxpr_types)
-    if isinstance(v, jaxpr_types):
-        yield getattr(v, "jaxpr", v)
+
+def _sub_jaxprs(v):
+    if isinstance(v, ClosedJaxpr):
+        yield v.jaxpr
+    elif isinstance(v, Jaxpr):
+        yield v
     elif isinstance(v, (tuple, list)):
         for x in v:
             yield from _sub_jaxprs(x)
+    elif hasattr(v, "eqns") or hasattr(v, "jaxpr"):
+        raise TypeError(
+            f"jaxpr-like {type(v).__name__} is not a jax.extend.core "
+            "Jaxpr/ClosedJaxpr: the collective census cannot recurse"
+        )
 
 
 def _walk(jaxpr, counts: dict) -> None:
@@ -76,16 +85,26 @@ def _walk(jaxpr, counts: dict) -> None:
         name = eqn.primitive.name
         if name in COLLECTIVE_PRIMS:
             counts[name] = counts.get(name, 0) + 1
-        for v in eqn.params.values():
-            for sub in _sub_jaxprs(v):
-                _walk(sub, counts)
+        subs = [
+            sub for v in eqn.params.values() for sub in _sub_jaxprs(v)
+        ]
+        if name in _HIGHER_ORDER_PRIMS and not subs:
+            raise TypeError(
+                f"found no sub-jaxpr under higher-order primitive "
+                f"{name!r} (params {sorted(eqn.params)}): the collective "
+                "census cannot recurse"
+            )
+        for sub in subs:
+            _walk(sub, counts)
 
 
 def count_collectives(fn, *args, **kwargs) -> dict:
     """Static census of collective primitives over the whole jaxpr tree
-    (scan/while bodies, cond branches, shard_map and pjit sub-jaxprs).
+    (scan/while bodies, cond branches, shard_map and jit sub-jaxprs).
     A primitive inside a scan body counts ONCE here; executed counts
-    are (static count) x (trip counts), derived analytically below."""
+    are (static count) x (trip counts), derived analytically below.
+    Raises ``TypeError`` when it cannot recurse into a body, so "zero
+    collectives" is never the result of having looked at nothing."""
     closed = jax.make_jaxpr(functools.partial(fn, **kwargs))(*args)
     counts: dict = {}
     _walk(closed.jaxpr, counts)
@@ -177,14 +196,6 @@ def census(n_shards: int = 8, n_replicas: int = 3, W: int = 8,
 # Analytic projection
 # ---------------------------------------------------------------------------
 
-# single-chip v5e measurements (benchmarks/results.json, rounds 5/17;
-# see docs/PERFORMANCE.md "Reading the tiers" for host attribution)
-MEASURED_V5E = {
-    "set_dec_per_s": 3.1e6,       # mesh_engine_r05 pure-SET windows
-    "get_reads_per_s": 1.46e6,    # get_windows_device_lane (value dl)
-    "mixed_dec_per_s": 0.688e6,   # mixed_set_get_device_lane (lane off)
-}
-
 # interconnect parameters (approximate public figures; the projection's
 # shape is insensitive to them because the probe lane moves ZERO ICI
 # bytes — they only set where the CONSENSUS lane would start to bend)
@@ -194,28 +205,25 @@ ICI = {
 }
 
 
-def project(census_doc: dict, chips=(1, 2, 4, 8),
-            get_fracs=(0.5, 0.9), S_per_chip: int = 4096,
-            W: int = 32, max_phases: int = 4,
-            probe_uplift: float = 1.0) -> dict:
+def project(census_doc: dict, set_rate: float, get_rate: float,
+            chips=(1, 2, 4, 8), get_fracs=(0.5, 0.9),
+            S_per_chip: int = 4096, W: int = 32,
+            max_phases: int = 4) -> dict:
     """Project mixed SET+GET throughput across shard-axis chip counts.
 
     Model (deliberately conservative — windows serialize, no pipeline
     overlap credit):
 
-    - Per-chip slot rate and probe rate are the MEASURED single-chip
-      v5e figures; ``probe_uplift`` scales the GET rate for the probe
-      path's meta-only readback (5 B/op vs the full value plane) —
-      default 1.0 claims nothing that was not measured.
+    - ``set_rate`` / ``get_rate`` are one chip's SET decisions/s and
+      probe reads/s, measured on the attached chip by the caller.
     - Shard-axis scaling is linear: the census pins ZERO collectives
       over the shard axis, so S_total = chips x S_per_chip rides the
       same per-window collective budget.
     - Replica-axis collectives cost
       ``executed/window x hop_latency + bytes/bw`` — at i8 vote planes
       (W x S_local x R bytes per all_gather) this is microseconds
-      against a ~1.6 ms dispatch floor, i.e. the consensus lane stays
-      dispatch-bound well past these chip counts (the model reports
-      the ICI term so the crossover is visible, not hidden).
+      (the model reports the ICI term so the crossover with the
+      per-window dispatch cost is visible, not hidden).
     """
     ex = census_doc["executed_per_window"]
     n_coll = ex["consensus_get_window"]
@@ -226,13 +234,11 @@ def project(census_doc: dict, chips=(1, 2, 4, 8),
         + bytes_per_gather / (ICI["replica_axis_bw_GBps"] * 1e9)
     )
 
-    set_rate = MEASURED_V5E["set_dec_per_s"]
-    probe_rate = MEASURED_V5E["get_reads_per_s"] * probe_uplift
     rows = []
     for gf in get_fracs:
         for c in chips:
             # serialized-window harmonic composition, scaled by chips
-            per_chip = 1.0 / ((1.0 - gf) / set_rate + gf / probe_rate)
+            per_chip = 1.0 / ((1.0 - gf) / set_rate + gf / get_rate)
             total = per_chip * c
             rows.append({
                 "chips": c,
@@ -244,8 +250,7 @@ def project(census_doc: dict, chips=(1, 2, 4, 8),
         "model": "serialized-window harmonic, linear shard-axis scaling",
         "assumptions": {
             "S_per_chip": S_per_chip, "W": W, "max_phases": max_phases,
-            "probe_uplift": probe_uplift,
-            "measured_v5e": MEASURED_V5E,
+            "set_rate": set_rate, "get_rate": get_rate,
             "ici": ICI,
             "consensus_ici_s_per_window": ici_s_per_window,
             "probe_ici_s_per_window": 0.0,
@@ -263,6 +268,12 @@ def project(census_doc: dict, chips=(1, 2, 4, 8),
 
 
 def main() -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--set-rate", type=float, help="one chip's SET dec/s")
+    ap.add_argument("--get-rate", type=float, help="one chip's reads/s")
+    args = ap.parse_args()
     c = census()
     assert c["probe_is_collective_free"], (
         "lookup_only traced WITH collectives — the read lane's "
@@ -271,22 +282,21 @@ def main() -> int:
     assert c["executed_per_window"]["consensus_get_window"] > 0, (
         "consensus window traced with zero collectives — census broken"
     )
-    proj = project(c)
-    doc = {"census": c, "projection": proj}
+    doc = {"census": c}
+    if args.set_rate and args.get_rate:
+        doc["projection"] = project(c, args.set_rate, args.get_rate)
     print(json.dumps(doc, indent=1))
-    for r in proj["rows"]:
+    for r in doc.get("projection", {}).get("rows", ()):
         mark = "OK " if r["meets_2M"] else "   "
         print(
             f"{mark} chips={r['chips']} get_frac={r['get_frac']:.1f} "
             f"-> {r['projected_ops_per_s'] / 1e6:.2f}M ops/s"
         )
-    if "--record" in sys.argv:
-        path = Path(__file__).parent / "results.json"
-        rec = json.loads(path.read_text()) if path.exists() else {}
-        sect = rec.setdefault("mesh_engine_r17", {})
-        sect["ici_model"] = doc
-        path.write_text(json.dumps(rec, indent=1))
-        print("recorded -> results.json mesh_engine_r17.ici_model")
+    if "projection" not in doc:
+        print(
+            "census only: pass --set-rate and --get-rate (per-chip rates "
+            "measured on the attached chip) for the projection"
+        )
     return 0
 
 

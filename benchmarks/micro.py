@@ -94,7 +94,7 @@ def bench_serialization_comparison() -> dict:
             )
     # native (C extension) vs pure-Python binary on the hot frames —
     # "binary" above already routes through the native codec when built;
-    # this isolates the speedup (VERDICT r03 item 4: >=5x on small)
+    # this isolates the speedup (the gate: >=5x on small frames)
     bc = BinarySerializer()
     if bc._native is not None:
         for sz, msg in (("small", small), ("large", large)):
@@ -107,7 +107,7 @@ def bench_serialization_comparison() -> dict:
             / out["binary_py_small_roundtrips_per_sec"],
             2,
         )
-    # snapshot recovery frame (SyncResponse, VERDICT r04 next-#8): a
+    # snapshot recovery frame (SyncResponse): a
     # multi-MB KV snapshot through the codec, native vs Python, at the
     # engine's production compression threshold — records whether
     # recovery could ever be codec-bound

@@ -33,10 +33,12 @@ from rabia_tpu.apps.kvstore import (
 )
 from rabia_tpu.apps.vector_kv import VectorShardedKV
 from rabia_tpu.core.blocks import build_block
+from rabia_tpu.core.compile_cache import place_compile_cache
 from rabia_tpu.parallel import MeshEngine
 
 
 def main() -> int:
+    place_compile_cache()
     S, R = 16, 5
     eng = MeshEngine(
         lambda: VectorShardedKV(S, capacity=1 << 12),

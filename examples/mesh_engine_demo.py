@@ -17,11 +17,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from rabia_tpu.apps.kvstore import encode_set_bin
 from rabia_tpu.apps.vector_kv import VectorShardedKV
+from rabia_tpu.core.compile_cache import place_compile_cache
 from rabia_tpu.core.errors import RabiaError
 from rabia_tpu.parallel import MeshEngine
 
 
 def main() -> int:
+    place_compile_cache()
     S, R = 8, 5
     eng = MeshEngine(
         lambda: VectorShardedKV(S, capacity=1 << 12),

@@ -66,6 +66,9 @@ def _selftest() -> int:
     import jax
     import jax.numpy as jnp
 
+    from rabia_tpu.core.compile_cache import place_compile_cache
+
+    place_compile_cache()
     t0 = time.perf_counter()
     from rabia_tpu.kernel import ClusterKernel
 

@@ -138,7 +138,10 @@ def resolve_runtime_workers(engine) -> int:
     ``min(shards, max(1, cores - 1))`` — one core stays with the Python
     control plane, and hosts with <= 2 cores run the historical
     single-thread runtime. Capped at 64 groups (the classifier's
-    bitmask width) and at the shard count."""
+    bitmask width) and at the number of non-empty groups the contiguous
+    ``chunk = ceil(shards / workers)`` split yields (8 shards over 7
+    workers is chunk 2, i.e. 4 groups — never an empty or inverted
+    range; ``rtm_create`` applies the same clamp)."""
     env = os.environ.get("RABIA_RT_WORKERS")
     w = None
     if env:
@@ -150,7 +153,10 @@ def resolve_runtime_workers(engine) -> int:
         w = getattr(engine.config, "runtime_workers", None)
     if w is None:
         w = max(1, (os.cpu_count() or 1) - 1)
-    return max(1, min(int(w), 64, engine.n_shards))
+    n = max(1, int(engine.n_shards))
+    w = max(1, min(int(w), 64, n))
+    chunk = (n + w - 1) // w
+    return (n + chunk - 1) // chunk
 
 
 def runtime_available(engine) -> bool:

@@ -382,7 +382,9 @@ class FleetProcHarness:
     def _spawn(self, i: int):
         env = dict(os.environ)
         env["PYTHONPATH"] = f"{REPO}{os.pathsep}" + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # gateway children never need the chip: assigned, not defaulted
+        # (a default loses to an environment that names the TPU)
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             [
                 sys.executable, "-m", "rabia_tpu.fleet.gateway_proc",

@@ -1790,8 +1790,11 @@ void* rtm_create(const int64_t* dims, const int64_t* ptrs, const int64_t* fns,
   if (W < 1) W = 1;
   if (W > 64) W = 64;
   if (W > c->n) W = c->n > 0 ? c->n : 1;
-  c->W = W;
   c->chunk = (c->n + W - 1) / W;
+  // only the groups the contiguous split actually yields: n=8 over W=7
+  // is chunk 2, i.e. 4 non-empty groups (a 5th would start past n)
+  if (c->chunk > 0) W = (int32_t)((c->n + c->chunk - 1) / c->chunk);
+  c->W = W;
   int i = 0;
   void* rk0 = (void*)ptrs[i++];
   c->tr = (void*)ptrs[i++];

@@ -32,11 +32,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rabia_tpu.core.types import ABSENT, V0, V1, VQUESTION, f_plus_1, quorum_size

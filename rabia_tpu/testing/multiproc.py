@@ -50,6 +50,10 @@ def run_replica_cluster(
     ports = free_ports(n)
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{REPO}{os.pathsep}" + env.get("PYTHONPATH", "")
+    # replica children run the host transport engine and never need the
+    # chip; assigned (not defaulted) so an environment that names the
+    # TPU cannot hand N children a device one process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     procs = [
         subprocess.Popen(
             [

@@ -201,7 +201,9 @@ class RecoveryHarness:
     def _spawn(self, i: int) -> ReplicaProc:
         env = dict(os.environ)
         env["PYTHONPATH"] = f"{REPO}{os.pathsep}" + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # replica children never need the chip: assigned, not defaulted
+        # (a default loses to an environment that names the TPU)
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             [
                 sys.executable, "-m", "rabia_tpu.testing.recovery",

@@ -10,7 +10,7 @@ co-tenant rather than total starvation) and runs ``pytest tests/``
 
 The reference pins its timing behavior on dedicated CI runners; this
 repo's tests must instead hold on a shared 1-core box, so load
-tolerance is a first-class gate (VERDICT r4 item 5). CI runs this as
+tolerance is a first-class gate. CI runs this as
 its own tier; locally:
 
     python scripts/load_soak.py [--runs 5] [--duty 0.6] [--hogs 1]

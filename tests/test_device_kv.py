@@ -959,10 +959,9 @@ class TestDeviceGetWindows:
 
     def test_deeper_inflight_pipe_byte_identical(self):
         # device_store_inflight=3 keeps three dispatched-but-unresolved
-        # windows in the pipe (the throughput-mode default: with one
-        # fetch worker per window it measured 1.05-2.4x depth 1 —
-        # inflight_depth_ab in benchmarks/results.json); responses and
-        # final content must be byte-identical to the host path
+        # windows in the pipe (the throughput-mode default, with one
+        # fetch worker per window); responses and final content must be
+        # byte-identical to the host path
         n = 8
         dev = _mk(n, device=True, device_store_inflight=3, window=2)
         host = _mk(n, device=False, window=2)

@@ -725,4 +725,4 @@ class TestBackendFencing:
                     num_shards=2, shard_pad_multiple=2, backend="jax"
                 ),
             )
-        assert any("directly-attached" in r.message for r in caplog.records)
+        assert any("fenced backend" in r.message for r in caplog.records)

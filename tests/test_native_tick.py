@@ -14,8 +14,8 @@ pins the native fast path to them:
   conformance of the native outbound framing);
 - ingest edge cases: spoofed envelopes dropped, future votes carried,
   stale votes reported to the repair path;
-- the config-1 serial-latency budget regression test (VERDICT r05 weak
-  #1): proposer-direct commit p50 under budget with the fast path on.
+- the config-1 serial-latency budget regression test: proposer-direct
+  commit p50 under budget with the fast path on.
 
 The randomized twin of the conformance gate lives in
 ``scripts/fuzz_conformance.py --tick`` (fresh schedules every run).
@@ -280,8 +280,8 @@ class TestSerialLatencyBudget:
         "mode", ["plain", "traced", "flight", "apply"]
     )
     async def test_config1_serial_latency_budget(self, mode):
-        """Pin the config-1 regression (VERDICT r05 weak #1, p50 1.6 →
-        2.49 ms): proposer-direct serial commits through the native tick
+        """Pin the config-1 regression (p50 1.6 → 2.49 ms, CPU host):
+        proposer-direct serial commits through the native tick
         path must hold a p50 budget. The budget is sized for a loaded
         2-core CI host — the Python tick path measures ~4.2-4.7 ms here,
         the native path ~2.3 ms, so the gate catches a regression to the

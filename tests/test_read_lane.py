@@ -281,3 +281,22 @@ class TestCollectiveCensus:
         )
         assert c["executed_per_window"]["consensus_get_window"] == 2 * 4 * 4
         assert c["executed_per_window"]["probe_window_lookup_only"] == 0
+
+    def test_census_raises_when_it_cannot_recurse(self, monkeypatch):
+        """A census that finds no sub-jaxprs must fail, not report zero
+        collectives (the jax 0.9 regression: Jaxpr/ClosedJaxpr left
+        ``jax.core``, the walker recursed into nothing, and every
+        program read as collective-free)."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        from benchmarks import ici_model
+
+        def scanned(x):
+            return lax.scan(lambda c, _: (c + 1, ()), x, None, length=3)[0]
+
+        assert ici_model.count_collectives(scanned, jnp.zeros(2)) == {}
+        monkeypatch.setattr(ici_model, "ClosedJaxpr", type("Gone", (), {}))
+        monkeypatch.setattr(ici_model, "Jaxpr", type("Gone", (), {}))
+        with pytest.raises(TypeError, match="cannot recurse"):
+            ici_model.count_collectives(scanned, jnp.zeros(2))

@@ -474,6 +474,86 @@ class TestShardGroupRuntime:
                     if attempt:
                         raise
 
+    def test_partition_never_yields_empty_or_inverted_group(self):
+        """chunk = ceil(n / W) can yield fewer than W groups (8 shards
+        over 7 workers is chunk 2 -> 4 groups; a 5th would start at
+        lo=10 > hi=8). The resolved count is clamped to the groups the
+        split yields, for every geometry incl. workers > shards."""
+        from types import SimpleNamespace
+
+        from rabia_tpu.engine.runtime_bridge import resolve_runtime_workers
+
+        for n in range(1, 70):
+            for asked in range(1, 80):
+                eng = SimpleNamespace(
+                    n_shards=n,
+                    config=SimpleNamespace(runtime_workers=asked),
+                )
+                w = resolve_runtime_workers(eng)
+                chunk = (n + w - 1) // w
+                ranges = [
+                    (g * chunk, n if g == w - 1 else (g + 1) * chunk)
+                    for g in range(w)
+                ]
+                assert 1 <= w <= min(asked, 64, n)
+                assert all(lo < hi <= n for lo, hi in ranges), (n, asked)
+                assert ranges[0][0] == 0 and ranges[-1][1] == n
+                assert all(
+                    a[1] == b[0] for a, b in zip(ranges, ranges[1:])
+                )
+        eng = SimpleNamespace(
+            n_shards=8, config=SimpleNamespace(runtime_workers=7)
+        )
+        assert resolve_runtime_workers(eng) == 4
+
+    @pytest.mark.parametrize("S,asked,want", [(8, 7, 4), (5, 9, 5)])
+    def test_uneven_worker_split_commits(self, monkeypatch, S, asked, want):
+        """The geometry an 8-core host's auto count (cores - 1 = 7)
+        produces for 8 shards, and workers > shards: block waves over
+        every group commit natively (the unclamped split gave a worker
+        lo > hi and a negative-size memset in collect_opens)."""
+        monkeypatch.setenv("RABIA_RT_WORKERS", str(asked))
+
+        async def run():
+            R = 3
+            _, nets, engines, machines, tasks = await _mk_cluster(S, R)
+            try:
+                rtm = engines[0]._rtm
+                assert rtm is not None and rtm.workers == want
+                assert len(rtm._extra_rks) == want - 1
+                assert {rtm._group_of(s) for s in range(S)} == set(
+                    range(want)
+                )
+                waves_before = rtm.counter("waves_native")
+                for _ in range(3):
+                    futs = []
+                    for e in engines:
+                        mine = _own_shards(e, S)
+                        if len(mine) == 0:
+                            continue
+                        futs.append(
+                            await e.submit_block(
+                                build_block(
+                                    mine,
+                                    [
+                                        [encode_set_bin(f"u{int(s)}", "v")]
+                                        for s in mine
+                                    ],
+                                )
+                            )
+                        )
+                    results = await asyncio.wait_for(
+                        asyncio.gather(*futs), 20.0
+                    )
+                    for r in results:
+                        for entry in r:
+                            assert not isinstance(entry, Exception)
+                assert rtm.counter("waves_native") > waves_before
+            finally:
+                await _teardown(engines, tasks, nets)
+
+        asyncio.run(run())
+
     def test_workers_clamp_and_single_worker_identity(self, monkeypatch):
         """workers never exceed the shard count, and workers=1 keeps the
         historical single-ring geometry (no sibling rk contexts)."""
