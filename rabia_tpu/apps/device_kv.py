@@ -54,6 +54,7 @@ from typing import NamedTuple, Optional, Union
 
 import numpy as np
 
+from rabia_tpu.core.tracing import device_annotation
 from rabia_tpu.core.types import V0, V1
 from rabia_tpu.apps.vector_kv import _RESP_DT
 
@@ -338,6 +339,9 @@ class DeviceKVTable:
         # new program: the engine's latency governor must not read that
         # dispatch's wall time as window latency
         self.compiled_on_last_call = False
+        # host bytes device_put for window dispatches, ever (the engine's
+        # devkv_upload_bytes_total reads it)
+        self.upload_bytes = 0
 
     # -- host-side packing -------------------------------------------------
 
@@ -371,8 +375,29 @@ class DeviceKVTable:
         kwin u8[W,S,Ku], vwin u8[W,S,VWu])`` or None when any op is
         outside the requested envelope (wrong opcode, >1 op per shard,
         key/value over the table widths) — the caller demotes."""
+        with device_annotation("rabia.cycle.pack.parse"):
+            parsed = self._parse_window(blocks, allow)
+        if parsed is None:
+            return None
+        parsed, ku, vu = parsed
         W = len(blocks)
         S = self.S
+        with device_annotation("rabia.cycle.pack.alloc"):
+            kind_w = np.zeros((W, S), np.int8)
+            klen_w = np.zeros((W, S), np.int16)
+            vlen_w = np.zeros((W, S), np.int16)
+            kwin_w = np.zeros((W, S, ku), np.uint8)
+            vwin_w = np.zeros((W, S, vu), np.uint8)
+        with device_annotation("rabia.cycle.pack.gather"):
+            self._gather_into(
+                parsed, kind_w, klen_w, vlen_w, kwin_w, vwin_w
+            )
+        return kind_w, klen_w, vlen_w, kwin_w, vwin_w
+
+    def _parse_window(self, blocks, allow: str) -> Optional[tuple]:
+        """Parse and validate every block of a window against the
+        ``allow`` envelope: ``(parsed blocks, ku, vu)`` with the
+        bucketed key/value widths, or None when any op is outside it."""
         parsed = []
         ku = vu = 4
         for b in blocks:
@@ -405,11 +430,15 @@ class DeviceKVTable:
             ku = max(ku, _bucket(int(klen.max())))
             vu = max(vu, _bucket(int(vlen.max(initial=0))))
             parsed.append((b, dbuf, off, klen, vlen, opcode))
-        kind_w = np.zeros((W, S), np.int8)
-        klen_w = np.zeros((W, S), np.int16)
-        vlen_w = np.zeros((W, S), np.int16)
-        kwin_w = np.zeros((W, S, ku), np.uint8)
-        vwin_w = np.zeros((W, S, vu), np.uint8)
+        return parsed, ku, vu
+
+    def _gather_into(
+        self, parsed, kind_w, klen_w, vlen_w, kwin_w, vwin_w
+    ) -> None:
+        """Fill the zeroed ``[W, S, ...]`` planes from the parsed blocks
+        (the native one-pass gather on the grid shape, else numpy)."""
+        W, _, ku = kwin_w.shape
+        vu = vwin_w.shape[2]
         kcols = np.arange(ku)[None, :]
         vcols = np.arange(vu)[None, :]
         # batch the W per-block gathers into ONE: concatenate the block
@@ -443,7 +472,7 @@ class DeviceKVTable:
             if self._native_pack_gather(
                 dbuf_all, off_all, klen_all, vlen_all, n, kwin_w, vwin_w
             ):
-                return kind_w, klen_w, vlen_w, kwin_w, vwin_w
+                return
         kw = dbuf_all[(off_all + _SET_HDR)[:, None] + kcols]
         kw = np.where(kcols < klen_all[:, None], kw, 0)
         vidx = np.minimum(
@@ -464,7 +493,6 @@ class DeviceKVTable:
             vlen_w[t_all, sh_all] = vlen_all
             kwin_w[t_all, sh_all] = kw
             vwin_w[t_all, sh_all] = vw
-        return kind_w, klen_w, vlen_w, kwin_w, vwin_w
 
     def _native_pack_gather(
         self, dbuf_all, off_all, klen_all, vlen_all, n, kwin_w, vwin_w
@@ -546,6 +574,11 @@ class DeviceKVTable:
     def _dict_from_gathered(
         self, g: tuple, max_dict: int = 32
     ) -> Optional[DeviceDictOps]:
+        with device_annotation("rabia.cycle.pack.dict"):
+            return self._dict_rows(g, max_dict)
+
+    @staticmethod
+    def _dict_rows(g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
         _kind, klen_w, vlen_w, kwin_w, vwin_w = g
         W, S = klen_w.shape
         ku = kwin_w.shape[2]
@@ -707,6 +740,31 @@ class DeviceKVTable:
             )
         return DeviceWindowOps(*(self._put_waves(a) for a in ops))
 
+    def _placing(self, *operands):
+        """The span around one dispatch's ``device_put``s: ``operands``
+        are the host arrays placed under it, counted into
+        ``upload_bytes``."""
+        nbytes = sum(a.nbytes for a in operands)
+        self.upload_bytes += nbytes
+        return device_annotation("rabia.dispatch.place", bytes=nbytes)
+
+    def _program(self, key: tuple, build):
+        """The jitted program of signature ``key``, built by ``build()``
+        on a miss (which ``compiled_on_last_call`` then says)."""
+        fn = self._fused_cache.get(key)
+        self.compiled_on_last_call = fn is None
+        if fn is None:
+            fn = self._fused_cache[key] = build()
+        return fn
+
+    def _calling(self, key: tuple):
+        """The span around the jitted call that follows ``_program(key,
+        ...)``: a program's first call traces, lowers and compiles, and
+        carries its own name and signature into the trace."""
+        if self.compiled_on_last_call:
+            return device_annotation("rabia.jit.first_call", sig=str(key))
+        return device_annotation("rabia.dispatch.call")
+
     def _build_lookup(self, Ku4: int, D: Optional[int] = None):
         """Jitted GET window: consensus slot window + a read-only match
         over the table (no state mutation, no version advance). ``D``
@@ -726,28 +784,31 @@ class DeviceKVTable:
         def lookup(state, alive, base, depth, klen_t, kwin_t, *, W,
                    max_phases):
             used, keyw, klen, ver, valw, vlen, _sver = state
-            wave = jnp.arange(W, dtype=I32)[:, None] < depth
-            present = wave & col[None, :]
-            votes = jnp.where(
-                present[:, :, None], I8(V1), I8(V0)
-            ) * jnp.ones((1, 1, kernel.R), I8)
-            decided = kernel.slot_window(
-                votes, alive, base, n_slots=W, max_phases=max_phases
-            )
-            all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth
+                present = wave & col[None, :]
+                votes = jnp.where(
+                    present[:, :, None], I8(V1), I8(V0)
+                ) * jnp.ones((1, 1, kernel.R), I8)
+                decided = kernel.slot_window(
+                    votes, alive, base, n_slots=W, max_phases=max_phases
+                )
+                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
             def match_body(klen_w, kwin_w):
-                klen_w = klen_w.astype(jnp.int32)
-                eq = (
-                    used
-                    & (klen == klen_w[:, None])
-                    & (keyw == kwin_w[:, None, :]).all(-1)
-                )  # [S, P]
-                found = eq.any(1) & (klen_w > 0)
-                oh = eq & found[:, None]  # at most one slot matches
-                rver = (ver * oh).sum(1)
-                rvlen = (vlen * oh).sum(1)
-                rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
+                with jax.named_scope("key_match"):
+                    klen_w = klen_w.astype(jnp.int32)
+                    eq = (
+                        used
+                        & (klen == klen_w[:, None])
+                        & (keyw == kwin_w[:, None, :]).all(-1)
+                    )  # [S, P]
+                    found = eq.any(1) & (klen_w > 0)
+                with jax.named_scope("get_gather"):
+                    oh = eq & found[:, None]  # at most one slot matches
+                    rver = (ver * oh).sum(1)
+                    rvlen = (vlen * oh).sum(1)
+                    rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
                 return found, rver, rvlen, rval
 
             if D is None:
@@ -814,29 +875,31 @@ class DeviceKVTable:
             ops = _pad_dict_idx(ops, W)
             D = ops.dkl.shape[1]
             key = ("getdict", W, ops.dk.shape[2], D)
-            fn = self._fused_cache.get(key)
-            self.compiled_on_last_call = fn is None
-            if fn is None:
-                fn = self._build_lookup(key[2], D)
-                self._fused_cache[key] = fn
+            fn = self._program(
+                key, lambda: self._build_lookup(key[2], D)
+            )
             # only the key dictionary is uploaded: the lookup
             # never reads values, and uploading the dead dv plane would
             # cost as much as the keys themselves at D=32
-            kdict = (
-                self._put_waves(ops.idx),
-                self._put_shards(ops.dkl),
-                self._put_shards(ops.dk),
-            )
-            return fn(
-                self.state if state is None else state,
-                self.kernel.place(alive),
-                self._put_shards(base),
-                np.int32(depth),
-                kdict,
-                None,
-                W=W,
-                max_phases=max_phases,
-            )
+            with self._placing(alive, base, ops.idx, ops.dkl, ops.dk):
+                alive_d = self.kernel.place(alive)
+                base_d = self._put_shards(base)
+                kdict = (
+                    self._put_waves(ops.idx),
+                    self._put_shards(ops.dkl),
+                    self._put_shards(ops.dk),
+                )
+            with self._calling(key):
+                return fn(
+                    self.state if state is None else state,
+                    alive_d,
+                    base_d,
+                    np.int32(depth),
+                    kdict,
+                    None,
+                    W=W,
+                    max_phases=max_phases,
+                )
         klen, kwin = ops
         if klen.shape[0] < W:
             pad = W - klen.shape[0]
@@ -847,21 +910,23 @@ class DeviceKVTable:
                 [kwin, np.zeros((pad,) + kwin.shape[1:], kwin.dtype)]
             )
         key = ("get", W, kwin.shape[2])
-        fn = self._fused_cache.get(key)
-        self.compiled_on_last_call = fn is None
-        if fn is None:
-            fn = self._build_lookup(kwin.shape[2])
-            self._fused_cache[key] = fn
-        return fn(
-            self.state if state is None else state,
-            self.kernel.place(alive),
-            self._put_shards(base),
-            np.int32(depth),
-            self._put_waves(klen),
-            self._put_waves(kwin),
-            W=W,
-            max_phases=max_phases,
-        )
+        fn = self._program(key, lambda: self._build_lookup(key[2]))
+        with self._placing(alive, base, klen, kwin):
+            alive_d = self.kernel.place(alive)
+            base_d = self._put_shards(base)
+            klen_d = self._put_waves(klen)
+            kwin_d = self._put_waves(kwin)
+        with self._calling(key):
+            return fn(
+                self.state if state is None else state,
+                alive_d,
+                base_d,
+                np.int32(depth),
+                klen_d,
+                kwin_d,
+                W=W,
+                max_phases=max_phases,
+            )
 
     def _build_lookup_only(self, Ku4: int, D: Optional[int] = None):
         """Jitted CONSENSUS-FREE read window: the same read-only match
@@ -884,17 +949,19 @@ class DeviceKVTable:
             used, keyw, klen, ver, valw, vlen, _sver = state
 
             def match_body(klen_w, kwin_w):
-                klen_w = klen_w.astype(jnp.int32)
-                eq = (
-                    used
-                    & (klen == klen_w[:, None])
-                    & (keyw == kwin_w[:, None, :]).all(-1)
-                )  # [S, P]
-                found = eq.any(1) & (klen_w > 0)
-                oh = eq & found[:, None]  # at most one slot matches
-                rver = (ver * oh).sum(1)
-                rvlen = (vlen * oh).sum(1)
-                rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
+                with jax.named_scope("key_match"):
+                    klen_w = klen_w.astype(jnp.int32)
+                    eq = (
+                        used
+                        & (klen == klen_w[:, None])
+                        & (keyw == kwin_w[:, None, :]).all(-1)
+                    )  # [S, P]
+                    found = eq.any(1) & (klen_w > 0)
+                with jax.named_scope("get_gather"):
+                    oh = eq & found[:, None]  # at most one slot matches
+                    rver = (ver * oh).sum(1)
+                    rvlen = (vlen * oh).sum(1)
+                    rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
                 return found, rver, rvlen, rval
 
             if D is None:
@@ -940,22 +1007,22 @@ class DeviceKVTable:
             ops = _pad_dict_idx(ops, W)
             D = ops.dkl.shape[1]
             key = ("rodict", W, ops.dk.shape[2], D)
-            fn = self._fused_cache.get(key)
-            self.compiled_on_last_call = fn is None
-            if fn is None:
-                fn = self._build_lookup_only(key[2], D)
-                self._fused_cache[key] = fn
-            kdict = (
-                self._put_waves(ops.idx),
-                self._put_shards(ops.dkl),
-                self._put_shards(ops.dk),
+            fn = self._program(
+                key, lambda: self._build_lookup_only(key[2], D)
             )
-            return fn(
-                self.state if state is None else state,
-                kdict,
-                None,
-                W=W,
-            )
+            with self._placing(ops.idx, ops.dkl, ops.dk):
+                kdict = (
+                    self._put_waves(ops.idx),
+                    self._put_shards(ops.dkl),
+                    self._put_shards(ops.dk),
+                )
+            with self._calling(key):
+                return fn(
+                    self.state if state is None else state,
+                    kdict,
+                    None,
+                    W=W,
+                )
         klen, kwin = ops
         if klen.shape[0] < W:
             pad = W - klen.shape[0]
@@ -966,17 +1033,19 @@ class DeviceKVTable:
                 [kwin, np.zeros((pad,) + kwin.shape[1:], kwin.dtype)]
             )
         key = ("ro", W, kwin.shape[2])
-        fn = self._fused_cache.get(key)
-        self.compiled_on_last_call = fn is None
-        if fn is None:
-            fn = self._build_lookup_only(key[2])
-            self._fused_cache[key] = fn
-        return fn(
-            self.state if state is None else state,
-            self._put_waves(klen),
-            self._put_waves(kwin),
-            W=W,
+        fn = self._program(
+            key, lambda: self._build_lookup_only(key[2])
         )
+        with self._placing(klen, kwin):
+            klen_d = self._put_waves(klen)
+            kwin_d = self._put_waves(kwin)
+        with self._calling(key):
+            return fn(
+                self.state if state is None else state,
+                klen_d,
+                kwin_d,
+                W=W,
+            )
 
     @staticmethod
     def _apply_set_wave(carry, ok_w, klen_t, vlen_t, kwin_t, vwin_t, Pc):
@@ -988,31 +1057,36 @@ class DeviceKVTable:
         so prefix equality + length equality IS full-key equality.
         Updates are one-hot word SELECTS, not dynamic-index scatters
         (which lower poorly on TPU)."""
+        import jax
         import jax.numpy as jnp
 
         used, keyw, klen, ver, valw, vlen, sver = carry
-        eq = (
-            used
-            & (klen == klen_t[:, None])
-            & (keyw == kwin_t[:, None, :]).all(-1)
-        )  # [S, P]
-        found = eq.any(1)
-        slot = jnp.where(found, jnp.argmax(eq, 1), jnp.argmax(~used, 1))
-        full = used.all(1)
-        apply = ok_w & (found | ~full)
-        overflow = jnp.any(ok_w & ~found & full)
-        onehot = (
-            jnp.arange(Pc)[None, :] == slot[:, None]
-        ) & apply[:, None]  # [S, P]
-        oh3 = onehot[:, :, None]
-        used = used | onehot
-        keyw = jnp.where(oh3, kwin_t[:, None, :], keyw)
-        klen = jnp.where(onehot, klen_t[:, None], klen)
-        new_ver = sver + 1
-        ver = jnp.where(onehot, new_ver[:, None], ver)
-        valw = jnp.where(oh3, vwin_t[:, None, :], valw)
-        vlen = jnp.where(onehot, vlen_t[:, None], vlen)
-        sver = jnp.where(apply, new_ver, sver)
+        with jax.named_scope("key_match"):
+            eq = (
+                used
+                & (klen == klen_t[:, None])
+                & (keyw == kwin_t[:, None, :]).all(-1)
+            )  # [S, P]
+            found = eq.any(1)
+        with jax.named_scope("apply_set"):
+            slot = jnp.where(
+                found, jnp.argmax(eq, 1), jnp.argmax(~used, 1)
+            )
+            full = used.all(1)
+            apply = ok_w & (found | ~full)
+            overflow = jnp.any(ok_w & ~found & full)
+            onehot = (
+                jnp.arange(Pc)[None, :] == slot[:, None]
+            ) & apply[:, None]  # [S, P]
+            oh3 = onehot[:, :, None]
+            used = used | onehot
+            keyw = jnp.where(oh3, kwin_t[:, None, :], keyw)
+            klen = jnp.where(onehot, klen_t[:, None], klen)
+            new_ver = sver + 1
+            ver = jnp.where(onehot, new_ver[:, None], ver)
+            valw = jnp.where(oh3, vwin_t[:, None, :], valw)
+            vlen = jnp.where(onehot, vlen_t[:, None], vlen)
+            sver = jnp.where(apply, new_ver, sver)
         return (used, keyw, klen, ver, valw, vlen, sver), overflow
 
     def _build_fused_dict(self, Ku4: int, VWu4: int, D: int):
@@ -1033,15 +1107,16 @@ class DeviceKVTable:
         col = jnp.arange(S) < n
 
         def fused(state, alive, base, depth, ops, *, W, max_phases):
-            wave = jnp.arange(W, dtype=I32)[:, None] < depth
-            present = wave & col[None, :]
-            votes = jnp.where(
-                present[:, :, None], I8(V1), I8(V0)
-            ) * jnp.ones((1, 1, kernel.R), I8)
-            decided = kernel.slot_window(
-                votes, alive, base, n_slots=W, max_phases=max_phases
-            )
-            all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth
+                present = wave & col[None, :]
+                votes = jnp.where(
+                    present[:, :, None], I8(V1), I8(V0)
+                ) * jnp.ones((1, 1, kernel.R), I8)
+                decided = kernel.slot_window(
+                    votes, alive, base, n_slots=W, max_phases=max_phases
+                )
+                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
             dk_full = jnp.pad(ops.dk, ((0, 0), (0, 0), (0, K4 - Ku4)))
             dv_full = jnp.pad(ops.dv, ((0, 0), (0, 0), (0, VW4 - VWu4)))
@@ -1064,15 +1139,16 @@ class DeviceKVTable:
             new_state, over_w = lax.scan(
                 wave_step, state, (present, ops.idx)
             )
-            flags = jnp.stack(
-                [
-                    all_v1.astype(I32),
-                    jnp.any(over_w).astype(I32),
-                    jnp.any(
-                        new_state[6] >= jnp.int32(2**31 - 2)
-                    ).astype(I32),
-                ]
-            )
+            with jax.named_scope("flags"):
+                flags = jnp.stack(
+                    [
+                        all_v1.astype(I32),
+                        jnp.any(over_w).astype(I32),
+                        jnp.any(
+                            new_state[6] >= jnp.int32(2**31 - 2)
+                        ).astype(I32),
+                    ]
+                )
             return new_state, flags
 
         return jax.jit(fused, static_argnames=("W", "max_phases"))
@@ -1092,15 +1168,16 @@ class DeviceKVTable:
         def fused(state, alive, base, depth, ops, *, W, max_phases):
             # initial votes generated on device: every live replica
             # proposes V1 for the depth in-window waves of real shards
-            wave = jnp.arange(W, dtype=I32)[:, None] < depth  # [W, 1]
-            present = wave & col[None, :]  # [W, S]
-            votes = jnp.where(
-                present[:, :, None], I8(V1), I8(V0)
-            ) * jnp.ones((1, 1, kernel.R), I8)
-            decided = kernel.slot_window(
-                votes, alive, base, n_slots=W, max_phases=max_phases
-            )  # i8[W, S]
-            all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth  # [W, 1]
+                present = wave & col[None, :]  # [W, S]
+                votes = jnp.where(
+                    present[:, :, None], I8(V1), I8(V0)
+                ) * jnp.ones((1, 1, kernel.R), I8)
+                decided = kernel.slot_window(
+                    votes, alive, base, n_slots=W, max_phases=max_phases
+                )  # i8[W, S]
+                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
             # pad the op windows to the table widths once, outside the
             # scan (zero tails keep prefix-compare == full-key compare)
@@ -1126,15 +1203,16 @@ class DeviceKVTable:
                 state,
                 (present, ops.klen, ops.vlen, kwin_full, vwin_full),
             )
-            flags = jnp.stack(
-                [
-                    all_v1.astype(I32),
-                    jnp.any(over_w).astype(I32),
-                    jnp.any(
-                        new_state[6] >= jnp.int32(2**31 - 2)
-                    ).astype(I32),
-                ]
-            )
+            with jax.named_scope("flags"):
+                flags = jnp.stack(
+                    [
+                        all_v1.astype(I32),
+                        jnp.any(over_w).astype(I32),
+                        jnp.any(
+                            new_state[6] >= jnp.int32(2**31 - 2)
+                        ).astype(I32),
+                    ]
+                )
             return new_state, flags
 
         return jax.jit(fused, static_argnames=("W", "max_phases"))
@@ -1169,20 +1247,29 @@ class DeviceKVTable:
                 )
             )
         key = (W, ops.kwin.shape[2], ops.vwin.shape[2])
-        fused = self._fused_cache.get(key)
-        self.compiled_on_last_call = fused is None
-        if fused is None:
-            fused = self._build_fused(key[1], key[2])
-            self._fused_cache[key] = fused
-        return fused(
-            self.state if state is None else state,
-            self.kernel.place(alive),
-            self._put_shards(base),
-            np.int32(depth),
-            self._place_ops(ops),
-            W=W,
-            max_phases=max_phases,
+        return self._dispatch_set(
+            key, lambda: self._build_fused(key[1], key[2]),
+            alive, base, depth, ops, W, max_phases, state,
         )
+
+    def _dispatch_set(self, key, build, alive, base, depth, ops, W,
+                      max_phases, state):
+        """Place and call one SET window program (either op form)."""
+        fn = self._program(key, build)
+        with self._placing(alive, base, *ops):
+            alive_d = self.kernel.place(alive)
+            base_d = self._put_shards(base)
+            ops_d = self._place_ops(ops)
+        with self._calling(key):
+            return fn(
+                self.state if state is None else state,
+                alive_d,
+                base_d,
+                np.int32(depth),
+                ops_d,
+                W=W,
+                max_phases=max_phases,
+            )
 
     def _build_mixed(self, Ku4: int, VWu4: int, Gp: int,
                      D: Optional[int] = None):
@@ -1216,15 +1303,16 @@ class DeviceKVTable:
 
         def mixed(state, alive, base, depth, kind_w, gidx, ops, *, W,
                   max_phases):
-            wave = jnp.arange(W, dtype=I32)[:, None] < depth
-            present = wave & col[None, :]
-            votes = jnp.where(
-                present[:, :, None], I8(V1), I8(V0)
-            ) * jnp.ones((1, 1, kernel.R), I8)
-            decided = kernel.slot_window(
-                votes, alive, base, n_slots=W, max_phases=max_phases
-            )
-            all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth
+                present = wave & col[None, :]
+                votes = jnp.where(
+                    present[:, :, None], I8(V1), I8(V0)
+                ) * jnp.ones((1, 1, kernel.R), I8)
+                decided = kernel.slot_window(
+                    votes, alive, base, n_slots=W, max_phases=max_phases
+                )
+                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
             def step_body(carry, ok_w, kind_t, klen_t, vlen_t, kwin_t,
                           vwin_t):
@@ -1232,51 +1320,54 @@ class DeviceKVTable:
                 klen_t = klen_t.astype(jnp.int32)
                 vlen_t = vlen_t.astype(jnp.int32)
                 kind_t = kind_t.astype(jnp.int32)
-                eq = (
-                    used
-                    & (klen == klen_t[:, None])
-                    & (keyw == kwin_t[:, None, :]).all(-1)
-                )  # [S, P]
-                found = eq.any(1)
+                with jax.named_scope("key_match"):
+                    eq = (
+                        used
+                        & (klen == klen_t[:, None])
+                        & (keyw == kwin_t[:, None, :]).all(-1)
+                    )  # [S, P]
+                    found = eq.any(1)
                 # reads (GET/DEL/EXISTS found bits) are against the
                 # wave-entry state, before this wave's applies touch the
                 # table; gver/gval carry data for GET ops only (a DEL's
                 # response is its found bit, an EXISTS's is a boolean)
-                rsel = (kind_t >= 2) & (klen_t > 0)
-                gsel = found & rsel
-                oh_get = eq & (found & (kind_t == 2))[:, None]
-                gver = (ver * oh_get).sum(1)
-                gvlen = (vlen * oh_get).sum(1)
-                gval = (valw * oh_get[:, :, None]).sum(1)
-                # DEL applies: clear the matched slot (the table is
-                # compare-all associative — no probe chains to repair,
-                # unlike the host twin's open addressing) and bump the
-                # shard version exactly like the host store's delete()
-                # does on a successful delete
-                del_hit = ok_w & (kind_t == 3) & found
-                used = used & ~(eq & del_hit[:, None])
-                sver = sver + del_hit
-                # SET applies: same one-hot word-select update as the
-                # pure-SET program, gated on this op BEING a SET
-                is_set = ok_w & (kind_t == 1)
-                slot = jnp.where(
-                    found, jnp.argmax(eq, 1), jnp.argmax(~used, 1)
-                )
-                full = used.all(1)
-                apply = is_set & (found | ~full)
-                overflow = jnp.any(is_set & ~found & full)
-                onehot = (
-                    jnp.arange(Pc)[None, :] == slot[:, None]
-                ) & apply[:, None]
-                oh3 = onehot[:, :, None]
-                used = used | onehot
-                keyw = jnp.where(oh3, kwin_t[:, None, :], keyw)
-                klen = jnp.where(onehot, klen_t[:, None], klen)
-                new_ver = sver + 1
-                ver = jnp.where(onehot, new_ver[:, None], ver)
-                valw = jnp.where(oh3, vwin_t[:, None, :], valw)
-                vlen = jnp.where(onehot, vlen_t[:, None], vlen)
-                sver = jnp.where(apply, new_ver, sver)
+                with jax.named_scope("get_gather"):
+                    rsel = (kind_t >= 2) & (klen_t > 0)
+                    gsel = found & rsel
+                    oh_get = eq & (found & (kind_t == 2))[:, None]
+                    gver = (ver * oh_get).sum(1)
+                    gvlen = (vlen * oh_get).sum(1)
+                    gval = (valw * oh_get[:, :, None]).sum(1)
+                with jax.named_scope("apply_set"):
+                    # DEL applies: clear the matched slot (the table is
+                    # compare-all associative — no probe chains to
+                    # repair, unlike the host twin's open addressing) and
+                    # bump the shard version exactly like the host
+                    # store's delete() does on a successful delete
+                    del_hit = ok_w & (kind_t == 3) & found
+                    used = used & ~(eq & del_hit[:, None])
+                    sver = sver + del_hit
+                    # SET applies: same one-hot word-select update as the
+                    # pure-SET program, gated on this op BEING a SET
+                    is_set = ok_w & (kind_t == 1)
+                    slot = jnp.where(
+                        found, jnp.argmax(eq, 1), jnp.argmax(~used, 1)
+                    )
+                    full = used.all(1)
+                    apply = is_set & (found | ~full)
+                    overflow = jnp.any(is_set & ~found & full)
+                    onehot = (
+                        jnp.arange(Pc)[None, :] == slot[:, None]
+                    ) & apply[:, None]
+                    oh3 = onehot[:, :, None]
+                    used = used | onehot
+                    keyw = jnp.where(oh3, kwin_t[:, None, :], keyw)
+                    klen = jnp.where(onehot, klen_t[:, None], klen)
+                    new_ver = sver + 1
+                    ver = jnp.where(onehot, new_ver[:, None], ver)
+                    valw = jnp.where(oh3, vwin_t[:, None, :], valw)
+                    vlen = jnp.where(onehot, vlen_t[:, None], vlen)
+                    sver = jnp.where(apply, new_ver, sver)
                 return (used, keyw, klen, ver, valw, vlen, sver), (
                     overflow,
                     gsel,
@@ -1334,22 +1425,24 @@ class DeviceKVTable:
             new_state, (over_w, gfound, gver, gvlen, gval) = lax.scan(
                 wave_step, state, xs
             )
-            flags = jnp.stack(
-                [
-                    all_v1.astype(I32),
-                    jnp.any(over_w).astype(I32),
-                    jnp.any(
-                        new_state[6] >= jnp.int32(2**31 - 2)
-                    ).astype(I32),
-                ]
-            )
+            with jax.named_scope("flags"):
+                flags = jnp.stack(
+                    [
+                        all_v1.astype(I32),
+                        jnp.any(over_w).astype(I32),
+                        jnp.any(
+                            new_state[6] >= jnp.int32(2**31 - 2)
+                        ).astype(I32),
+                    ]
+                )
             # device-side gather of the GET-bearing waves + two-plane
             # meta pack: [0]=version, [1]=(vlen<<1)|found
-            gfound_g = jnp.take(gfound, gidx, axis=0).astype(I32)
-            gver_g = jnp.take(gver, gidx, axis=0)
-            gvlen_g = jnp.take(gvlen, gidx, axis=0)
-            gval_g = jnp.take(gval, gidx, axis=0)
-            meta = jnp.stack([gver_g, (gvlen_g << 1) | gfound_g])
+            with jax.named_scope("get_gather"):
+                gfound_g = jnp.take(gfound, gidx, axis=0).astype(I32)
+                gver_g = jnp.take(gver, gidx, axis=0)
+                gvlen_g = jnp.take(gvlen, gidx, axis=0)
+                gval_g = jnp.take(gval, gidx, axis=0)
+                meta = jnp.stack([gver_g, (gvlen_g << 1) | gfound_g])
             return new_state, flags, meta, gval_g
 
         return jax.jit(mixed, static_argnames=("W", "max_phases"))
@@ -1397,41 +1490,33 @@ class DeviceKVTable:
         else:
             key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
             build = lambda: self._build_mixed(key[2], key[3], Gp)
-        fn = self._fused_cache.get(key)
-        self.compiled_on_last_call = fn is None
-        if fn is None:
-            fn = build()
-            self._fused_cache[key] = fn
-        return fn(
-            self.state if state is None else state,
-            self.kernel.place(alive),
-            self._put_shards(base),
-            np.int32(depth),
-            self._put_waves(kind),
-            gidx,
-            self._place_ops(ops),
-            W=W,
-            max_phases=max_phases,
-        )
+        fn = self._program(key, build)
+        with self._placing(alive, base, kind, *ops):
+            alive_d = self.kernel.place(alive)
+            base_d = self._put_shards(base)
+            kind_d = self._put_waves(kind)
+            ops_d = self._place_ops(ops)
+        with self._calling(key):
+            return fn(
+                self.state if state is None else state,
+                alive_d,
+                base_d,
+                np.int32(depth),
+                kind_d,
+                gidx,
+                ops_d,
+                W=W,
+                max_phases=max_phases,
+            )
 
     def _decide_apply_dict(self, alive, base, depth, ops: DeviceDictOps,
                            W: int, max_phases: int, state=None):
         ops = _pad_dict_idx(ops, W)
         D = ops.dkl.shape[1]
         key = ("dictset", W, ops.dk.shape[2], ops.dv.shape[2], D)
-        fn = self._fused_cache.get(key)
-        self.compiled_on_last_call = fn is None
-        if fn is None:
-            fn = self._build_fused_dict(key[2], key[3], D)
-            self._fused_cache[key] = fn
-        return fn(
-            self.state if state is None else state,
-            self.kernel.place(alive),
-            self._put_shards(base),
-            np.int32(depth),
-            self._place_ops(ops),
-            W=W,
-            max_phases=max_phases,
+        return self._dispatch_set(
+            key, lambda: self._build_fused_dict(key[2], key[3], D),
+            alive, base, depth, ops, W, max_phases, state,
         )
 
     def adopt(self, new_state) -> None:

@@ -16,29 +16,30 @@ store.rs:15) and leaves profiling to external tools. Here:
   ``report()`` shape, not two (docs/OBSERVABILITY.md).
 - :func:`span` — ``with span("engine.tick.drain"): ...`` context manager
   against the module singleton.
-- :func:`device_annotation` — wraps ``jax.profiler.TraceAnnotation`` so
-  kernel steps show up named in TensorBoard/XLA traces; no-op when
-  profiling is off or jax is absent.
-- :func:`device_trace` — ``with device_trace(logdir):`` wraps
-  ``jax.profiler.trace`` for capturing a device profile around a workload.
+- :func:`device_annotation` — the device lane's one span helper:
+  ``with device_annotation("rabia.cycle.pack"): ...`` emits a
+  ``jax.profiler.TraceAnnotation`` (a TraceMe event in the profiler's own
+  ``.xplane.pb``, on the device trace's clock) and, when the tracer is
+  enabled, records the same interval into the :class:`Tracer` under the
+  same name. No-op when jax is absent and the tracer is off.
 
 Span naming taxonomy (dotted, coarse→fine):
   engine.tick.{drain,open,kernel,apply,timeouts}
   engine.kernel.{start,route,step,outbox}
   wire.{serialize,deserialize}
   sm.apply
+  rabia.cycle.{pack,book,wait,settle}, rabia.cycle.pack.{parse,alloc,gather,dict},
+    rabia.cycle.settle.download
+  rabia.devkv.{decide_apply,lookup_window,mixed_apply,read_probe}
+    (reserved for the dispatch spans: the benchmark selects them by prefix)
+  rabia.dispatch.{place,call}, rabia.jit.first_call
 """
 
 from __future__ import annotations
 
-import contextlib
-import logging
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Iterator
-
-logger = logging.getLogger("rabia_tpu.tracing")
 
 
 @dataclass
@@ -83,18 +84,6 @@ class Tracer:
 
     def reset(self) -> None:
         self.spans.clear()
-
-    def log_report(self, level: int = logging.INFO) -> None:
-        for name, row in self.report().items():
-            logger.log(
-                level,
-                "span %-28s n=%-8d total=%8.3fs avg=%8.1fus max=%8.1fus",
-                name,
-                row["count"],
-                row["total_s"],
-                row["avg_us"],
-                row["max_us"],
-            )
 
 
 tracer = Tracer()
@@ -143,33 +132,54 @@ def span(name: str):
     return _Span(name)
 
 
-@contextlib.contextmanager
-def device_annotation(name: str) -> Iterator[None]:
-    """Name a region in XLA device traces (no-op when jax is absent).
+_annotation_cls = False  # unresolved; None when jax is absent
 
-    The annotation object is created OUTSIDE the yield so a body exception
-    propagates unharmed (a bare ``except: yield`` around a yield would
-    destroy it with 'generator didn't stop after throw()')."""
+
+def _resolve_annotation_cls():
+    global _annotation_cls
     try:
-        import jax.profiler
-
-        ann = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        ann = None
-    if ann is None:
-        yield
-    else:
-        with ann:
-            yield
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        TraceAnnotation = None
+    _annotation_cls = TraceAnnotation
+    return TraceAnnotation
 
 
-@contextlib.contextmanager
-def device_trace(logdir: str) -> Iterator[None]:
-    """Capture a jax device profile (TensorBoard format) around a block."""
-    import jax.profiler
+class _DeviceSpan:
+    """A profiler annotation that also feeds the :class:`Tracer`. Like the
+    annotation, whose event begins when it is made, it counts from its
+    construction."""
 
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+    __slots__ = ("name", "ann", "t0")
+
+    def __init__(self, name: str, ann):
+        self.name = name
+        self.ann = ann
+        self.t0 = time.perf_counter()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        tracer.record(self.name, time.perf_counter() - self.t0)
+        return False
+
+
+def device_annotation(name: str, **stats):
+    """``with device_annotation("rabia.cycle.pack", bytes=n): ...`` — the
+    device lane's span: a TraceMe event in the JAX profiler's trace (the
+    ``stats`` become the event's arguments in the trace viewer), and an
+    aggregate under the same name in the :class:`Tracer` when that is
+    enabled. With no profiler session and the tracer off it reads no
+    clock and allocates only the annotation. The span begins where this
+    is called (a ``TraceAnnotation``'s event starts at its construction,
+    not at ``__enter__``): call it in the ``with`` statement itself."""
+    cls = _annotation_cls
+    if cls is False:
+        cls = _resolve_annotation_cls()
+    ann = cls(name, **stats) if cls is not None else None
+    if tracer.enabled:
+        return _DeviceSpan(name, ann)
+    return ann if ann is not None else _NOOP

@@ -53,7 +53,7 @@ import numpy as np
 
 from rabia_tpu.core.errors import RabiaError, ValidationError
 from rabia_tpu.core.state_machine import StateMachine
-from rabia_tpu.core.tracing import device_annotation
+from rabia_tpu.core.tracing import device_annotation, tracer
 from rabia_tpu.core.types import (
     ABSENT,
     V0,
@@ -417,6 +417,7 @@ class MeshEngine:
         from rabia_tpu.obs import MetricsRegistry
 
         m = self.metrics = MetricsRegistry()
+        m.attach_tracer(tracer)  # RABIA_TRACE=1: the rabia.* spans below
         self._h_window_settle = m.histogram(
             "commit_stage_seconds",
             "Device window dispatch→settle latency (the mesh plane's "
@@ -500,6 +501,20 @@ class MeshEngine:
             "devkv_read_probe_windows_total",
             "Consensus-free lookup_only probe windows dispatched",
             fn=lambda: self._read_stats["probe_windows"],
+        )
+        m.counter(
+            "devkv_upload_bytes_total",
+            "Host bytes placed on the device for window dispatches (the "
+            "bytes= of the rabia.dispatch.place spans)",
+            fn=lambda: self._dev.upload_bytes if self._dev is not None else 0,
+        )
+        m.counter(
+            "devkv_program_builds_total",
+            "Window programs built, one per distinct signature (each "
+            "one's first call is a rabia.jit.first_call span)",
+            fn=lambda: (
+                len(self._dev._fused_cache) if self._dev is not None else 0
+            ),
         )
         self._h_read_batch = m.histogram(
             "devkv_read_batch_ops",
@@ -1091,7 +1106,8 @@ class MeshEngine:
         if head_kind == 2:
             return self._run_cycle_fullwidth_device_get(depth)
         entries = [self._full_blocks[i] for i in range(depth)]  # peek
-        ops = self._dev.pack_window_auto([e[0] for e in entries])
+        with device_annotation("rabia.cycle.pack"):
+            ops = self._dev.pack_window_auto([e[0] for e in entries])
         if ops is None:
             applied = self._dev_drain_pipe()
             self._demote_device_store()
@@ -1112,52 +1128,52 @@ class MeshEngine:
                 self.alive, base, depth, ops, W=W,
                 max_phases=self.max_phases, state=state_base,
             )
-        # a new (W, widths) signature compiles inside this dispatch —
-        # seconds of jit, not window latency
-        self._lat_invalidate |= (
-            self._dev.compiled_on_last_call and self._lat_timing
-        )
-        self.cycles += 1
-        # version responses are DERIVED, not transferred: a clean
-        # all-V1 full-width window advances every covered shard's
-        # version by exactly one per wave, so the host mirror + wave
-        # index reproduces the device counters bit-for-bit (pinned by
-        # tests/test_device_kv.py against the host store). While a
-        # DEL-bearing window is in flight the mirror base is unknown —
-        # derivation then defers to settlement like the mixed lane's
-        # (_dev_settle_set patches the provisional segment).
-        deferred = self._dev_defer > 0
-        if deferred:
-            vers = None
-            sver_delta = None
-            seg_start = np.zeros_like(self._dev_sver)
-            seg_end = np.zeros_like(self._dev_sver)
-        else:
-            vers = (
-                self._dev_sver[None, : self.S]
-                + np.arange(1, W + 1, dtype=np.int64)[:, None]
+        with device_annotation("rabia.cycle.book"):
+            # a new (W, widths) signature compiles inside this dispatch —
+            # seconds of jit, not window latency
+            self._lat_invalidate |= (
+                self._dev.compiled_on_last_call and self._lat_timing
             )
-            # retain this window's value bytes host-side: (shard,
-            # version) uniquely identifies content, so the GET lane can
-            # answer reads without downloading values (see _dev_resolve)
-            seg_start = self._dev_sver.copy()
-            seg_end = seg_start.copy()
-            seg_end[:n] += depth
-        if isinstance(ops, DeviceDictOps):
-            seg = _DictSeg(seg_start, seg_end, ops.idx, ops.dvl, ops.dv)
-        else:
-            seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
-        if deferred:
-            seg.provisional = True
-            self._dev_defer += 1
-        self._dev_push_segment(seg)
-        if not deferred:
-            self._dev_sver[:n] += depth
-            sver_delta = np.zeros_like(self._dev_sver)
-            sver_delta[:n] = depth
-        self._dev_commit_window(entries, depth)
-        return self._dev_push_window(
-            {
+            self.cycles += 1
+            # version responses are DERIVED, not transferred: a clean
+            # all-V1 full-width window advances every covered shard's
+            # version by exactly one per wave, so the host mirror + wave
+            # index reproduces the device counters bit-for-bit (pinned by
+            # tests/test_device_kv.py against the host store). While a
+            # DEL-bearing window is in flight the mirror base is unknown —
+            # derivation then defers to settlement like the mixed lane's
+            # (_dev_settle_set patches the provisional segment).
+            deferred = self._dev_defer > 0
+            if deferred:
+                vers = None
+                sver_delta = None
+                seg_start = np.zeros_like(self._dev_sver)
+                seg_end = np.zeros_like(self._dev_sver)
+            else:
+                vers = (
+                    self._dev_sver[None, : self.S]
+                    + np.arange(1, W + 1, dtype=np.int64)[:, None]
+                )
+                # retain this window's value bytes host-side: (shard,
+                # version) uniquely identifies content, so the GET lane can
+                # answer reads without downloading values (see _dev_resolve)
+                seg_start = self._dev_sver.copy()
+                seg_end = seg_start.copy()
+                seg_end[:n] += depth
+            if isinstance(ops, DeviceDictOps):
+                seg = _DictSeg(seg_start, seg_end, ops.idx, ops.dvl, ops.dv)
+            else:
+                seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
+            if deferred:
+                seg.provisional = True
+                self._dev_defer += 1
+            self._dev_push_segment(seg)
+            if not deferred:
+                self._dev_sver[:n] += depth
+                sver_delta = np.zeros_like(self._dev_sver)
+                sver_delta[:n] = depth
+            self._dev_commit_window(entries, depth)
+            rec = {
                 "kind": "set",
                 "flags_fut": self._dev_fetcher().submit(np.asarray, flags_dev),
                 "new_state": new_state,
@@ -1169,7 +1185,7 @@ class MeshEngine:
                 "sver_delta": sver_delta,
                 "deferred": deferred,
             }
-        )
+        return self._dev_push_window(rec)
 
     def _dev_commit_window(self, entries, depth: int):
         """Shared commit bookkeeping for every device window kind: pop
@@ -1268,7 +1284,8 @@ class MeshEngine:
             # chained on settled cleanly before it reached the head
             dirty = False
         else:
-            flags = rec["flags_fut"].result()  # <=12 bytes: the readback
+            with device_annotation("rabia.cycle.wait", what="flags"):
+                flags = rec["flags_fut"].result()  # <=12 bytes: the readback
             if rec["kind"] == "get":
                 dirty = not int(flags)  # lookup returns the all_v1 scalar
             else:
@@ -1339,7 +1356,6 @@ class MeshEngine:
                         self._queued_entries += 1
             self._demote_device_store()
             return 0
-        self._dev_pipe.pop(0)
         # dispatch->settle latency: what a client actually waits at the
         # current pipe depth (depth multiplies it — the reason governed
         # mode defaults to depth 1); surfaced via governor_stats.
@@ -1349,16 +1365,24 @@ class MeshEngine:
             dt = time.perf_counter() - rec["t0"]
             self._lat_settle.append(dt * 1e3)
             self._h_window_settle.observe(dt)
-        # "get" windows are read-only: new_state is the (unchanged)
-        # state they chained on, so adopting is a no-op by value and
-        # keeps the pipe invariant uniform
-        self._dev.adopt(rec["new_state"])
-        if rec["kind"] == "set":
-            self._dev_settle_set(rec)
-        elif rec["kind"] == "mixed":
-            self._dev_settle_mixed(rec)
-        else:
-            self._dev_settle_get(rec)
+        # the meta readback is waited for here, apart from the settle:
+        # the settle's own .result() then returns at once
+        meta_fut = rec.get("meta_fut")
+        if meta_fut is not None:
+            with device_annotation("rabia.cycle.wait", what="meta"):
+                meta_fut.result()
+        with device_annotation("rabia.cycle.settle"):
+            self._dev_pipe.pop(0)
+            # "get" windows are read-only: new_state is the (unchanged)
+            # state they chained on, so adopting is a no-op by value and
+            # keeps the pipe invariant uniform
+            self._dev.adopt(rec["new_state"])
+            if rec["kind"] == "set":
+                self._dev_settle_set(rec)
+            elif rec["kind"] == "mixed":
+                self._dev_settle_mixed(rec)
+            else:
+                self._dev_settle_get(rec)
         return rec["depth"] * rec["n"]
 
     def _dev_settle_set(self, rec) -> None:
@@ -1411,8 +1435,9 @@ class MeshEngine:
             # eviction edge: the window pays the value-plane download
             self._read_stats["fallback"] += depth * rec["n"]
             vlen_d, valw_d = rec["val_dev"]
-            vlen = np.asarray(vlen_d)
-            valw = np.asarray(valw_d)
+            with device_annotation("rabia.cycle.settle.download"):
+                vlen = np.asarray(vlen_d)
+                valw = np.asarray(valw_d)
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             if resolved:
@@ -1484,7 +1509,8 @@ class MeshEngine:
             if resolved:
                 rsv = self._dev_make_resolver()
             else:
-                gval_h = np.asarray(rec["gval_dev"])
+                with device_annotation("rabia.cycle.settle.download"):
+                    gval_h = np.asarray(rec["gval_dev"])
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             row_kind = kind[t]
@@ -1549,7 +1575,8 @@ class MeshEngine:
             batch.append(self._read_pending.popleft())
         if not batch:
             return 0
-        packed = self._dev.pack_get_window_auto([e[0] for e in batch])
+        with device_annotation("rabia.cycle.pack"):
+            packed = self._dev.pack_get_window_auto([e[0] for e in batch])
         if packed is None:
             # outside the read envelope (long key, malformed op): put
             # the batch back and demote — the flush below hands every
@@ -1564,18 +1591,18 @@ class MeshEngine:
             found_d, ver_d, vlen_d, valw_d = self._dev.lookup_only(
                 packed, W=W, state=state_base
             )
-        self._lat_invalidate |= (
-            self._dev.compiled_on_last_call and self._lat_timing
-        )
-        self.cycles += 1
-        depth = len(batch)
-        n = self.n_shards
-        self._read_stats["probe"] += depth * n
-        self._read_stats["probe_windows"] += 1
-        self._h_read_batch.observe(float(depth))
-        pool = self._dev_fetcher()
-        return self._dev_push_window(
-            {
+        with device_annotation("rabia.cycle.book"):
+            self._lat_invalidate |= (
+                self._dev.compiled_on_last_call and self._lat_timing
+            )
+            self.cycles += 1
+            depth = len(batch)
+            n = self.n_shards
+            self._read_stats["probe"] += depth * n
+            self._read_stats["probe_windows"] += 1
+            self._h_read_batch.observe(float(depth))
+            pool = self._dev_fetcher()
+            rec = {
                 "kind": "read",
                 "flags_fut": None,  # nothing decided, nothing to read
                 "meta_fut": pool.submit(
@@ -1590,7 +1617,7 @@ class MeshEngine:
                 "seg": None,
                 "sver_delta": None,
             }
-        )
+        return self._dev_push_window(rec)
 
     def _run_cycle_fullwidth_device_get(self, depth: int) -> int:
         """GET-only full-width windows through the device table's
@@ -1616,7 +1643,8 @@ class MeshEngine:
         W = self.window
         n = self.n_shards
         entries = [self._full_blocks[i] for i in range(depth)]
-        packed = self._dev.pack_get_window_auto([e[0] for e in entries])
+        with device_annotation("rabia.cycle.pack"):
+            packed = self._dev.pack_get_window_auto([e[0] for e in entries])
         if packed is None:
             # drain BEFORE demoting so in-flight windows' applied counts
             # reach the caller (demote's internal drain discards them)
@@ -1633,15 +1661,15 @@ class MeshEngine:
                     max_phases=self.max_phases, state=state_base,
                 )
             )
-        self._lat_invalidate |= (
-            self._dev.compiled_on_last_call and self._lat_timing
-        )
-        self.cycles += 1
-        self._read_stats["slot"] += depth * n  # GETs that consumed slots
-        self._dev_commit_window(entries, depth)
-        pool = self._dev_fetcher()
-        return self._dev_push_window(
-            {
+        with device_annotation("rabia.cycle.book"):
+            self._lat_invalidate |= (
+                self._dev.compiled_on_last_call and self._lat_timing
+            )
+            self.cycles += 1
+            self._read_stats["slot"] += depth * n  # GETs that consumed slots
+            self._dev_commit_window(entries, depth)
+            pool = self._dev_fetcher()
+            rec = {
                 "kind": "get",
                 "flags_fut": pool.submit(np.asarray, all_v1_d),
                 "meta_fut": pool.submit(
@@ -1656,7 +1684,7 @@ class MeshEngine:
                 "seg": None,
                 "sver_delta": None,
             }
-        )
+        return self._dev_push_window(rec)
 
     def _run_cycle_fullwidth_device_mixed(self, count: int) -> int:
         """Full-width window MIXING SET and GET ops (per op, via the
@@ -1678,7 +1706,10 @@ class MeshEngine:
         W = self.window
         n = self.n_shards
         entries = [self._full_blocks[i] for i in range(count)]
-        packed = self._dev.pack_mixed_window_auto([e[0] for e in entries])
+        with device_annotation("rabia.cycle.pack"):
+            packed = self._dev.pack_mixed_window_auto(
+                [e[0] for e in entries]
+            )
         if packed is None:
             # drain BEFORE demoting so in-flight windows' applied counts
             # reach the caller (demote's internal drain discards them)
@@ -1707,48 +1738,48 @@ class MeshEngine:
                 self.alive, base, count, kind, get_waves, ops, W=W,
                 max_phases=self.max_phases, state=state_base,
             )
-        self._lat_invalidate |= (
-            self._dev.compiled_on_last_call and self._lat_timing
-        )
-        self.cycles += 1
-        # GET ops that rode consensus slots inside the mixed window
-        # (kind 2; DEL/EXISTS are not reads for the read-lane counters)
-        self._read_stats["slot"] += int((kind == 2).sum())
-        # derived SET versions: host mirror + inclusive per-shard SET
-        # count (GET waves advance nothing). Deferred windows push a
-        # PROVISIONAL segment (empty placeholder range — matches no
-        # resolver lookup, exempt from eviction) and leave the mirror
-        # untouched; settlement patches range + svers from the exact
-        # bump vector (SET always, DEL on found) and advances the
-        # mirror then.
-        is_set = kind == 1  # [count, S]
-        set_cum = np.cumsum(is_set, axis=0, dtype=np.int64)
-        if deferred:
-            svers = None
-            sver_delta = None
-            seg = _MixedSeg(
-                np.zeros_like(self._dev_sver),
-                np.zeros_like(self._dev_sver),
-                vlen_plane, vwin_plane, set_cum, kind,
+        with device_annotation("rabia.cycle.book"):
+            self._lat_invalidate |= (
+                self._dev.compiled_on_last_call and self._lat_timing
             )
-            seg.provisional = True
-            self._dev_push_segment(seg)
-            self._dev_defer += 1
-        else:
-            svers = self._dev_sver[None, : self.S] + set_cum
-            seg_start = self._dev_sver.copy()
-            seg = _MixedSeg(
-                seg_start, seg_start + set_cum[-1], vlen_plane, vwin_plane,
-                svers, kind,
-            )
-            self._dev_push_segment(seg)
-            sver_delta = np.zeros_like(self._dev_sver)
-            sver_delta[: self.S] = set_cum[-1]
-            self._dev_sver += sver_delta
-        self._dev_commit_window(entries, count)
-        pool = self._dev_fetcher()
-        return self._dev_push_window(
-            {
+            self.cycles += 1
+            # GET ops that rode consensus slots inside the mixed window
+            # (kind 2; DEL/EXISTS are not reads for the read-lane counters)
+            self._read_stats["slot"] += int((kind == 2).sum())
+            # derived SET versions: host mirror + inclusive per-shard SET
+            # count (GET waves advance nothing). Deferred windows push a
+            # PROVISIONAL segment (empty placeholder range — matches no
+            # resolver lookup, exempt from eviction) and leave the mirror
+            # untouched; settlement patches range + svers from the exact
+            # bump vector (SET always, DEL on found) and advances the
+            # mirror then.
+            is_set = kind == 1  # [count, S]
+            set_cum = np.cumsum(is_set, axis=0, dtype=np.int64)
+            if deferred:
+                svers = None
+                sver_delta = None
+                seg = _MixedSeg(
+                    np.zeros_like(self._dev_sver),
+                    np.zeros_like(self._dev_sver),
+                    vlen_plane, vwin_plane, set_cum, kind,
+                )
+                seg.provisional = True
+                self._dev_push_segment(seg)
+                self._dev_defer += 1
+            else:
+                svers = self._dev_sver[None, : self.S] + set_cum
+                seg_start = self._dev_sver.copy()
+                seg = _MixedSeg(
+                    seg_start, seg_start + set_cum[-1], vlen_plane, vwin_plane,
+                    svers, kind,
+                )
+                self._dev_push_segment(seg)
+                sver_delta = np.zeros_like(self._dev_sver)
+                sver_delta[: self.S] = set_cum[-1]
+                self._dev_sver += sver_delta
+            self._dev_commit_window(entries, count)
+            pool = self._dev_fetcher()
+            rec = {
                 "kind": "mixed",
                 "flags_fut": pool.submit(np.asarray, flags_dev),
                 # meta fetched optimistically alongside the flags (a
@@ -1772,7 +1803,7 @@ class MeshEngine:
                 "sver_delta": sver_delta,
                 "deferred": deferred,
             }
-        )
+        return self._dev_push_window(rec)
 
     def _dev_push_segment(self, seg) -> None:
         """Retain one committed device window's value bytes (a

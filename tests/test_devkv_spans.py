@@ -1,0 +1,350 @@
+"""The device lane's spans and counters (core/tracing.device_annotation).
+
+Every window of the device lane enters the same spans once, whatever its
+kind: ``rabia.cycle.{pack,book,wait,settle}`` beside the dispatch span
+``rabia.devkv.<program>``, with ``rabia.cycle.pack.*`` and
+``rabia.dispatch.*`` / ``rabia.jit.first_call`` nested inside. With the
+tracer on (``RABIA_TRACE=1``) they aggregate into ``Tracer.report()`` and
+``rabia_span_seconds``; with it off nothing is recorded and nothing the
+lane computes changes. Runs on the virtual CPU mesh.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import struct
+
+import numpy as np
+import pytest
+
+from rabia_tpu.apps.kvstore import encode_set_bin
+from rabia_tpu.apps.vector_kv import VectorShardedKV
+from rabia_tpu.core.blocks import build_block
+from rabia_tpu.core.tracing import tracer
+from rabia_tpu.parallel import MeshEngine, make_mesh
+
+N_SHARDS = 8
+WINDOW = 4
+N_WINDOWS = 3
+
+PACK_PARTS = tuple(
+    f"rabia.cycle.pack.{p}" for p in ("parse", "alloc", "gather", "dict")
+)
+CALLS = ("rabia.dispatch.call", "rabia.jit.first_call")
+PROGRAM = {
+    "set": "rabia.devkv.decide_apply",
+    "get": "rabia.devkv.lookup_window",
+    "mixed": "rabia.devkv.mixed_apply",
+}
+
+
+@pytest.fixture
+def traced():
+    was = tracer.enabled
+    tracer.reset()
+    tracer.enabled = True
+    try:
+        yield tracer
+    finally:
+        tracer.enabled = was
+        tracer.reset()
+
+
+def _engine(**kw) -> MeshEngine:
+    return MeshEngine(
+        lambda: VectorShardedKV(N_SHARDS, capacity=1 << 12),
+        n_shards=N_SHARDS,
+        n_replicas=3,
+        mesh=make_mesh(),
+        window=WINDOW,
+        device_store=True,
+        **kw,
+    )
+
+
+def _get(key: str) -> bytes:
+    return bytes([2]) + struct.pack("<H", len(key)) + key.encode()
+
+
+def _block(kind: str, rng) -> object:
+    """One full-width block: all SETs, all GETs, or a SET/GET interleaving
+    per shard (which only the mixed program takes)."""
+    cmds = []
+    for s in range(N_SHARDS):
+        key = f"k{s}_{int(rng.integers(0, 3))}"
+        is_set = kind == "set" or (kind == "mixed" and s % 2 == 0)
+        if is_set:
+            cmds.append([encode_set_bin(key, "v" * int(rng.integers(1, 20)))])
+        else:
+            cmds.append([_get(key)])
+    return build_block(list(range(N_SHARDS)), cmds)
+
+
+def _window(eng: MeshEngine, kind: str, rng) -> list:
+    """Submit one whole window of ``kind`` and dispatch it."""
+    futs = [eng.submit_block(_block(kind, rng)) for _ in range(WINDOW)]
+    before = eng.cycles
+    eng.run_cycle()
+    assert eng.cycles == before + 1
+    return futs
+
+
+def _total(name: str) -> float:
+    st = tracer.spans.get(name)
+    return st.total_s if st is not None else 0.0
+
+
+def _count(report: dict, *names: str) -> int:
+    return sum(report[n]["count"] for n in names if n in report)
+
+
+class TestSpansPerWindow:
+    def test_each_kind_enters_every_span_once_a_window(self, traced):
+        eng = _engine()
+        rng = np.random.default_rng(7)
+        first_calls = 0
+        for kind in ("set", "get", "mixed"):
+            traced.reset()
+            for _ in range(N_WINDOWS):
+                _window(eng, kind, rng)
+            eng.flush()
+            assert eng.device_lane_active
+            rep = traced.report()
+            for name in ("rabia.cycle.pack", "rabia.cycle.book",
+                         "rabia.cycle.settle", PROGRAM[kind], *PACK_PARTS,
+                         "rabia.dispatch.place"):
+                assert rep[name]["count"] == N_WINDOWS, (kind, name)
+            assert _count(rep, *CALLS) == N_WINDOWS, kind
+            # a SET window waits for its flags, the others for meta too
+            waits = rep["rabia.cycle.wait"]["count"]
+            assert waits == N_WINDOWS * (1 if kind == "set" else 2), kind
+            # rabia.devkv.* is reserved: this kind's dispatch span, no other
+            devkv = [n for n in rep if n.startswith("rabia.devkv.")]
+            assert devkv == [PROGRAM[kind]], kind
+            # nested spans lie inside their parents
+            assert sum(map(_total, PACK_PARTS)) <= _total("rabia.cycle.pack")
+            inside = _total("rabia.dispatch.place") + sum(map(_total, CALLS))
+            assert inside <= _total(PROGRAM[kind])
+            first_calls += _count(rep, "rabia.jit.first_call")
+        # one first call per distinct program signature, ever
+        snap = eng.metrics.snapshot()
+        assert first_calls == len(eng._dev._fused_cache)
+        assert snap["rabia_devkv_program_builds_total"] == first_calls
+        eng.close()
+
+    def test_read_probe_window_enters_the_same_spans(self, traced):
+        eng = _engine(device_read_lane=True)
+        rng = np.random.default_rng(11)
+        _window(eng, "set", rng)
+        eng.flush()
+        traced.reset()
+        for _ in range(WINDOW):
+            eng.submit_block(_block("get", rng))
+        eng.flush()
+        rep = traced.report()
+        for name in ("rabia.cycle.pack", "rabia.devkv.read_probe",
+                     "rabia.cycle.book", "rabia.cycle.settle",
+                     "rabia.dispatch.place"):
+            assert rep[name]["count"] == 1, name
+        assert rep["rabia.cycle.wait"]["count"] == 1  # meta; no flags
+        eng.close()
+
+    @pytest.mark.parametrize("kind", ["get", "mixed"])
+    def test_value_download_is_named_inside_the_settle(self, traced, kind):
+        """Reads of versions whose host segment was evicted make the settle
+        download the window's value planes: the one blocking transfer in
+        it, under a span of its own."""
+        eng = _engine()
+        eng._dev_vseg_cap = 1  # evict every segment but the newest
+        rng = np.random.default_rng(23)
+        for _ in range(2):
+            _window(eng, "set", rng)
+        eng.flush()
+        traced.reset()
+        _window(eng, kind, rng)
+        eng.flush()
+        rep = traced.report()
+        assert rep["rabia.cycle.settle.download"]["count"] == 1
+        assert _total("rabia.cycle.settle.download") <= _total("rabia.cycle.settle")
+        eng.close()
+
+    def test_prometheus_carries_every_span(self, traced):
+        eng = _engine()
+        for kind in ("set", "get", "mixed"):
+            for _ in range(2):  # the same window twice: a build, then a call
+                _window(eng, kind, np.random.default_rng(5))
+        eng.flush()
+        text = eng.metrics.render_prometheus()
+        for name in ("rabia.cycle.pack", *PACK_PARTS, "rabia.cycle.book",
+                     "rabia.cycle.wait", "rabia.cycle.settle",
+                     *PROGRAM.values(), "rabia.dispatch.place", *CALLS):
+            assert f'rabia_span_seconds_count{{span="{name}"}}' in text, name
+        assert "rabia_devkv_upload_bytes_total" in text
+        assert "rabia_devkv_program_builds_total" in text
+        eng.close()
+
+
+def test_profiler_events_tile_the_dispatch(tmp_path):
+    """The same spans as TraceMe events in a profiler trace (an annotation's
+    event begins where it is made): inside one ``rabia.devkv.*`` event the
+    place comes first and the call after it, without overlap, and the pack's
+    parts follow one another inside the pack."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _engine()
+    rng = np.random.default_rng(19)
+    _window(eng, "mixed", rng)  # the program's first call, untraced
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        _window(eng, "mixed", np.random.default_rng(19))
+        eng.flush()
+    finally:
+        jax.profiler.stop_trace()
+    eng.close()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("rabia."):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns)
+                    )
+    assert all("#" not in name for name in events)  # the stats stay apart
+    one = {name: v[0] for name, v in events.items() if len(v) == 1}
+    order = ["rabia.cycle.pack", "rabia.devkv.mixed_apply", "rabia.cycle.book"]
+    for a, b in zip(order, order[1:]):
+        assert one[a][1] <= one[b][0], (a, b)
+    parts = [one[p] for p in PACK_PARTS]
+    assert one["rabia.cycle.pack"][0] <= parts[0][0]
+    assert parts[-1][1] <= one["rabia.cycle.pack"][1]
+    for a, b in zip(parts, parts[1:]):
+        assert a[1] <= b[0]
+    devkv, place, call = (
+        one[n] for n in ("rabia.devkv.mixed_apply", "rabia.dispatch.place",
+                         "rabia.dispatch.call")
+    )
+    assert devkv[0] <= place[0] <= place[1] <= call[0] <= call[1] <= devkv[1]
+    assert len(events["rabia.cycle.settle"]) == 2  # both windows settled
+
+
+class TestUploadBytes:
+    @pytest.mark.parametrize("kind", ["set", "get", "mixed"])
+    def test_counter_grows_by_the_placed_operands(self, kind):
+        eng = _engine()
+        rng = np.random.default_rng(13)
+        blocks = [_block(kind, rng) for _ in range(WINDOW)]
+        dev = eng._dev
+        beside = eng.alive.nbytes + eng.S * 4  # alive mask + base slots
+        if kind == "set":
+            ops = dev.pack_window_auto(blocks)
+            want = beside + sum(a.nbytes for a in ops)
+        elif kind == "get":
+            ops = dev.pack_get_window_auto(blocks)
+            # the key dictionary only: a lookup never uploads values
+            want = beside + ops.idx.nbytes + ops.dkl.nbytes + ops.dk.nbytes
+        else:
+            kinds, ops, _vlen, _vwin = dev.pack_mixed_window_auto(blocks)
+            want = beside + kinds.nbytes + sum(a.nbytes for a in ops)
+        before = eng.metrics.snapshot()["rabia_devkv_upload_bytes_total"]
+        for b in blocks:
+            eng.submit_block(b)
+        eng.run_cycle()
+        after = eng.metrics.snapshot()["rabia_devkv_upload_bytes_total"]
+        assert after - before == want
+        eng.flush()
+        eng.close()
+
+
+def _content(sm: VectorShardedKV) -> dict:
+    """``{(shard, key): (value, version)}`` of one host replica."""
+    st = sm.store
+    out = {}
+    for slot in np.nonzero(st.state == 1)[0].tolist():
+        key = st.key_lanes[slot].view(np.uint8)[: int(st.key_len[slot])]
+        out[(int(st.shard_col[slot]), key.tobytes())] = (
+            st._value_at(slot), int(st.version[slot]),
+        )
+    return out
+
+
+def _replies_and_state(enabled: bool):
+    was = tracer.enabled
+    tracer.reset()
+    tracer.enabled = enabled
+    try:
+        eng = _engine()
+        rng = np.random.default_rng(21)
+        futs = []
+        for kind in ("set", "mixed", "get", "set", "mixed"):
+            futs += _window(eng, kind, rng)
+        eng.flush()
+        assert eng.device_lane_active
+        replies = [[bytes(g[0]) for g in f.result()] for f in futs]
+        report = tracer.report()
+        state = [np.asarray(a).tobytes() for a in eng._dev.state]
+        eng.sync_to_host()
+        stores = [_content(sm) for sm in eng.sms]
+        eng.close()
+        return replies, state, stores, report
+    finally:
+        tracer.enabled = was
+        tracer.reset()
+
+
+def test_tracer_off_records_nothing_and_changes_nothing():
+    replies_on, state_on, stores_on, report_on = _replies_and_state(True)
+    replies_off, state_off, stores_off, report_off = _replies_and_state(False)
+    assert report_on and "rabia.cycle.pack" in report_on
+    assert report_off == {}
+    assert replies_on == replies_off
+    assert state_on == state_off
+    assert stores_on == stores_off
+
+
+class TestNamedScopes:
+    SCOPES = ("consensus", "key_match", "apply_set", "get_gather", "flags")
+
+    def _mixed_call(self, eng):
+        """The mixed program of one window and its (host) arguments."""
+        rng = np.random.default_rng(17)
+        dev = eng._dev
+        kinds, ops = dev.pack_mixed_window(
+            [_block("mixed", rng) for _ in range(WINDOW)]
+        )
+        gidx = np.arange(WINDOW, dtype=np.int32)
+        fn = dev._build_mixed(ops.kwin.shape[2], ops.vwin.shape[2], WINDOW)
+        base = np.zeros(eng.S, np.int32)
+        args = (dev.state, eng.alive, base, np.int32(WINDOW), kinds, gidx, ops)
+        return fn, args
+
+    def test_scopes_are_in_the_lowered_program_and_change_no_output(
+        self, monkeypatch
+    ):
+        import jax
+
+        eng = _engine()
+        fn, args = self._mixed_call(eng)
+        text = fn.lower(*args, W=WINDOW, max_phases=4).as_text(debug_info=True)
+        for scope in self.SCOPES:
+            assert scope in text, scope
+        with_scopes = jax.tree.leaves(fn(*args, W=WINDOW, max_phases=4))
+
+        monkeypatch.setattr(
+            jax, "named_scope", lambda name: contextlib.nullcontext()
+        )
+        bare_fn, _ = self._mixed_call(eng)
+        bare_text = bare_fn.lower(*args, W=WINDOW, max_phases=4).as_text(
+            debug_info=True
+        )
+        assert "key_match" not in bare_text
+        bare = jax.tree.leaves(bare_fn(*args, W=WINDOW, max_phases=4))
+        assert len(bare) == len(with_scopes)
+        for a, b in zip(with_scopes, bare):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        eng.close()
