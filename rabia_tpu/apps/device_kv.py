@@ -86,6 +86,21 @@ def _fold_words(a: np.ndarray) -> np.ndarray:
     return h
 
 
+def _row_hash(klen, vlen, kwin, vwin) -> np.ndarray:
+    """The dictionary packer's 64-bit hash of each (key, value) row of
+    ``[..., B]`` byte planes: equal rows hash equal, the converse is
+    verified and never trusted."""
+    h = klen.astype(np.uint64) * _HASH_W[0]
+    h += vlen.astype(np.uint64) * _HASH_W[1]
+    h += _fold_words(kwin) * _HASH_W[2]
+    h += _fold_words(vwin) * _HASH_W[3]
+    return h
+
+
+# real shard columns the dictionary probe reads before the whole window
+_PROBE_SHARDS = 8
+
+
 def _bucket(n: int, lo: int = 4) -> int:
     """Round up to a power of two (>= lo, multiple of 4 for u32 views)."""
     b = lo
@@ -342,6 +357,10 @@ class DeviceKVTable:
         # host bytes device_put for window dispatches, ever (the engine's
         # devkv_upload_bytes_total reads it)
         self.upload_bytes = 0
+        # dictionary attempts by outcome, ever (the engine's
+        # devkv_dict_attempts_total reads it): "rejected" is the full
+        # path's D > max_dict or a failed verification
+        self.dict_attempts = {"built": 0, "probe_rejected": 0, "rejected": 0}
 
     # -- host-side packing -------------------------------------------------
 
@@ -577,8 +596,37 @@ class DeviceKVTable:
         with device_annotation("rabia.cycle.pack.dict"):
             return self._dict_rows(g, max_dict)
 
+    def _dict_rows(self, g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
+        """The window's dictionary form, or None when it has none:
+        the probe first, then the full path, counted by outcome."""
+        if self._dict_probe_rejects(g, max_dict):
+            self.dict_attempts["probe_rejected"] += 1
+            return None
+        d = self._dict_full(g, max_dict)
+        self.dict_attempts["rejected" if d is None else "built"] += 1
+        return d
+
+    def _dict_probe_rejects(self, g: tuple, max_dict: int) -> bool:
+        """Exact early rejection, before any pass over the whole
+        window: D is the maximum over shards of a shard's distinct
+        rows, and a shard's distinct-hash count is what the full path
+        would read for it, so one probed shard over ``max_dict`` settles
+        that the full path would return None. Reads a few real shard
+        columns, evenly strided; never the zero padding of the S axis."""
+        _kind, klen_w, vlen_w, kwin_w, vwin_w = g
+        if klen_w.shape[0] <= max_dict:
+            return False  # a shard has at most W distinct rows
+        k = min(self.n_shards, _PROBE_SHARDS)
+        cols = np.arange(k) * self.n_shards // k
+        h = _row_hash(
+            klen_w[:, cols], vlen_w[:, cols], kwin_w[:, cols], vwin_w[:, cols]
+        )  # [W, k]
+        h.sort(axis=0)
+        distinct = 1 + (h[1:] != h[:-1]).sum(axis=0)
+        return bool((distinct > max_dict).any())
+
     @staticmethod
-    def _dict_rows(g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
+    def _dict_full(g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
         _kind, klen_w, vlen_w, kwin_w, vwin_w = g
         W, S = klen_w.shape
         ku = kwin_w.shape[2]
@@ -589,10 +637,7 @@ class DeviceKVTable:
         # axis-1 sorts over the W window positions — O(S * W log W) on
         # short rows instead of a global (S*W)-row lexsort. Both were
         # the dominant pack costs at W=128.
-        h = klen_w.astype(np.uint64) * _HASH_W[0]
-        h += vlen_w.astype(np.uint64) * _HASH_W[1]
-        h += _fold_words(kwin_w) * _HASH_W[2]
-        h += _fold_words(vwin_w) * _HASH_W[3]
+        h = _row_hash(klen_w, vlen_w, kwin_w, vwin_w)
         if bool((h == h[:1]).all()):
             # every wave repeats its shard's single row (the steady
             # state of uniform workloads): D=1 with wave 0 as the

@@ -508,6 +508,21 @@ class MeshEngine:
             "bytes= of the rabia.dispatch.place spans)",
             fn=lambda: self._dev.upload_bytes if self._dev is not None else 0,
         )
+        for _outcome in ("built", "probe_rejected", "rejected"):
+            m.counter(
+                "devkv_dict_attempts_total",
+                "Dictionary-upload attempts of the window packers by "
+                "outcome: built, probe_rejected = a probed shard holds "
+                "over max_dict distinct rows (decided before the whole "
+                "window is hashed), rejected = the full path's count or "
+                "its byte verification",
+                {"outcome": _outcome},
+                fn=(
+                    lambda o=_outcome: self._dev.dict_attempts[o]
+                    if self._dev is not None
+                    else 0
+                ),
+            )
         m.counter(
             "devkv_program_builds_total",
             "Window programs built, one per distinct signature (each "

@@ -1161,3 +1161,153 @@ class TestDeviceGetWindows:
         assert [list(map(bytes, g)) for g in gd.result()] == [
             list(map(bytes, g)) for g in gh.result()
         ]
+
+
+# -- the dictionary probe: an exact early exit of _dict_rows ---------------
+
+_PROBE_W = 40  # waves a window: more than max_dict, so a shard can overflow
+_MAX_DICT = 32
+_UNPROBED = 5  # a shard the eight strided probe columns skip at 12 and 16
+
+
+def _probe_row(allow: str, key: str, r: int) -> bytes:
+    """Row ``r``'s op under ``allow``; a mixed window interleaves kinds."""
+    if allow == "set" or (allow == "mixed" and r % 2 == 0):
+        return encode_set_bin(key, f"v{r}")
+    return TestDeviceGetWindows._enc_get(key)
+
+
+def _probe_blocks(n: int, allow: str, case: str) -> list:
+    """One window of ``_PROBE_W`` full-width blocks whose shards hold, by
+    ``case``: one row each (D = 1); exactly ``max_dict`` distinct rows
+    each; ``max_dict`` each and one more in one unprobed shard; more in
+    every shard; or four rows that a first-byte hash folds into two."""
+
+    def cmd(s: int, w: int) -> bytes:
+        if case == "one_row":
+            r = s % 2
+        elif case == "at_max":
+            r = w % _MAX_DICT
+        elif case == "one_unprobed_over":
+            r = w % (_MAX_DICT + (s == _UNPROBED))
+        elif case == "all_over":
+            r = w % (_MAX_DICT + 1)
+        else:  # "collision"
+            r = w % 4
+            return _probe_row(allow, "ab"[r % 2] + str(r // 2), r)
+        return _probe_row(allow, f"k{r:02d}", r)
+
+    return [
+        build_block(list(range(n)), [[cmd(s, w)] for s in range(n)])
+        for w in range(_PROBE_W)
+    ]
+
+
+def _first_byte_fold(a: np.ndarray) -> np.ndarray:
+    """A forged weak fold: rows that share their first byte collide."""
+    return a[..., 0].astype(np.uint64)
+
+
+def _assert_same_dict(got, want) -> None:
+    if got is None or want is None:
+        assert got is None and want is None
+        return
+    assert type(got) is type(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+@pytest.fixture(scope="module")
+def probe_tables():
+    """One table with zero padding on the S axis (12 shards of 16) and
+    one without (16 of 16), on the 8-device CPU mesh."""
+    engines = {n: _mk(n, device=True, window=_PROBE_W) for n in (12, 16)}
+    assert engines[12]._dev.S == 16 and engines[16]._dev.S == 16
+    yield {n: e._dev for n, e in engines.items()}
+    for e in engines.values():
+        e.close()
+
+
+@pytest.mark.parametrize("n", [12, 16], ids=["padded", "full"])
+@pytest.mark.parametrize("allow", ["set", "get", "mixed"])
+class TestDictProbe:
+    """``_dict_rows`` = the probe, then ``_dict_full``: the probe says
+    None only where the full path alone says None."""
+
+    @pytest.mark.parametrize(
+        "case,outcome",
+        [
+            ("one_row", "built"),
+            ("at_max", "built"),  # (c): exactly max_dict still compresses
+            ("one_unprobed_over", "rejected"),
+            ("all_over", "probe_rejected"),
+            ("collision", "rejected"),
+        ],
+    )
+    def test_equals_the_full_path(
+        self, probe_tables, monkeypatch, n, allow, case, outcome
+    ):
+        from rabia_tpu.apps import device_kv
+
+        if case == "collision":
+            monkeypatch.setattr(device_kv, "_fold_words", _first_byte_fold)
+        dev = probe_tables[n]
+        g = dev._gather_window(_probe_blocks(n, allow, case), allow)
+        assert g is not None
+        before = dict(dev.dict_attempts)
+        got = dev._dict_rows(g, _MAX_DICT)
+        counted = {
+            o: dev.dict_attempts[o] - before[o] for o in dev.dict_attempts
+        }
+        assert counted == {
+            o: int(o == outcome) for o in dev.dict_attempts
+        }
+        _assert_same_dict(got, dev._dict_full(g, _MAX_DICT))
+        assert (got is not None) == (outcome == "built")
+        if outcome == "built":
+            D = 1 if case == "one_row" else _MAX_DICT
+            assert got.dkl.shape == (dev.S, D)
+
+    def test_rejection_reads_a_few_shards_and_never_sorts_the_window(
+        self, probe_tables, monkeypatch, n, allow
+    ):
+        from rabia_tpu.apps import device_kv
+
+        folded, sorts = [], []
+        fold, argsort = device_kv._fold_words, np.argsort
+
+        def counting_fold(a):
+            folded.append(a.shape)
+            return fold(a)
+
+        def counting_argsort(*args, **kw):
+            sorts.append(1)
+            return argsort(*args, **kw)
+
+        monkeypatch.setattr(device_kv, "_fold_words", counting_fold)
+        monkeypatch.setattr(np, "argsort", counting_argsort)
+        dev = probe_tables[n]
+        g = dev._gather_window(_probe_blocks(n, allow, "all_over"), allow)
+        assert dev._dict_rows(g, _MAX_DICT) is None
+        assert len(folded) == 2 and not sorts
+        assert all(s[:2] == (_PROBE_W, 8) for s in folded)
+        # the counts do count: a window that compresses reaches both
+        g = dev._gather_window(_probe_blocks(n, allow, "at_max"), allow)
+        assert dev._dict_rows(g, _MAX_DICT) is not None
+        assert sorts and any(s[:2] == (_PROBE_W, dev.S) for s in folded)
+
+    def test_probe_reads_no_column_beyond_the_real_shards(
+        self, probe_tables, n, allow
+    ):
+        # (d): rows that only a read of columns >= n_shards could see
+        dev = probe_tables[n]
+        g = dev._gather_window(_probe_blocks(n, allow, "one_row"), allow)
+        _kind, klen_w, _vlen, _kwin, _vwin = g
+        beyond = klen_w[:, dev.n_shards :]
+        assert not beyond.any()  # the S axis' padding is all zero
+        beyond[:] = np.arange(1, _PROBE_W + 1, dtype=klen_w.dtype)[:, None]
+        assert not dev._dict_probe_rejects(g, _MAX_DICT)
+        # the same rows in a real, probed column are seen
+        klen_w[:, 0] = np.arange(1, _PROBE_W + 1, dtype=klen_w.dtype)
+        assert dev._dict_probe_rejects(g, _MAX_DICT)

@@ -56,7 +56,7 @@ def _engine(**kw) -> MeshEngine:
         n_shards=N_SHARDS,
         n_replicas=3,
         mesh=make_mesh(),
-        window=WINDOW,
+        window=kw.pop("window", WINDOW),
         device_store=True,
         **kw,
     )
@@ -181,6 +181,56 @@ class TestSpansPerWindow:
             assert f'rabia_span_seconds_count{{span="{name}"}}' in text, name
         assert "rabia_devkv_upload_bytes_total" in text
         assert "rabia_devkv_program_builds_total" in text
+        eng.close()
+
+
+class TestDictAttempts:
+    OUTCOMES = ("built", "probe_rejected", "rejected")
+
+    def _counts(self, eng) -> dict:
+        snap = eng.metrics.snapshot()
+        return {
+            o: snap[f'rabia_devkv_dict_attempts_total{{outcome="{o}"}}']
+            for o in self.OUTCOMES
+        }
+
+    def test_counter_counts_one_outcome_per_window_packed(self, traced):
+        eng = _engine()
+        rng = np.random.default_rng(29)
+        for kind in ("set", "get", "mixed"):
+            for _ in range(N_WINDOWS):
+                _window(eng, kind, rng)
+        eng.flush()
+        # four waves of three keys a shard: every window compresses
+        packed = 3 * N_WINDOWS
+        assert self._counts(eng) == {
+            "built": packed, "probe_rejected": 0, "rejected": 0,
+        }
+        text = eng.metrics.render_prometheus()
+        for o in self.OUTCOMES:
+            assert f'rabia_devkv_dict_attempts_total{{outcome="{o}"}}' in text
+        # the attempt keeps its span: once a window, inside the pack
+        rep = traced.report()
+        assert rep["rabia.cycle.pack.dict"]["count"] == packed
+        assert rep["rabia.cycle.pack"]["count"] == packed
+        assert _total("rabia.cycle.pack.dict") <= _total("rabia.cycle.pack")
+        eng.close()
+
+    def test_window_over_max_dict_is_probe_rejected(self, traced):
+        waves = 40  # every wave another row: over the 32 of max_dict
+        eng = _engine(window=waves)
+        for w in range(waves):
+            eng.submit_block(build_block(
+                list(range(N_SHARDS)),
+                [[encode_set_bin(f"k{w}", f"v{w}")] for _ in range(N_SHARDS)],
+            ))
+        eng.run_cycle()
+        eng.flush()
+        assert eng.device_lane_active
+        assert self._counts(eng) == {
+            "built": 0, "probe_rejected": 1, "rejected": 0,
+        }
+        assert traced.report()["rabia.cycle.pack.dict"]["count"] == 1
         eng.close()
 
 
