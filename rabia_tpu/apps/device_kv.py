@@ -304,6 +304,51 @@ class MixedFrameGroups(_ShardFrameGroups):
         return self._get._frame(s)
 
 
+class TableDump(dict):
+    """``DeviceKVTable.dump()``'s result: the table's live entries as
+    arrays, entry ``i`` being ``shards[i]`` (i64[n], ascending),
+    ``keys[i, :klens[i]]`` (u8[n, K], zero tails), ``vbuf[o:o + vlens[i]]``
+    with ``o = vlens[:i].sum()`` (the values back to back, one ``bytes``),
+    ``versions[i]`` (i64[n]); and ``shard_version`` (i64[n_shards]).
+
+    ``d["rows"]`` builds the entries as ``(shard, key, value, version)``
+    tuples on each read and is not kept: ``sync_into`` reads the arrays,
+    unless the dump it is handed holds ``"rows"`` of its own."""
+
+    def __missing__(self, name):
+        if name != "rows":
+            raise KeyError(name)
+        keys, klens = self["keys"], self["klens"].tolist()
+        vbuf, vlens = self["vbuf"], self["vlens"]
+        vends = np.cumsum(vlens).tolist()
+        return [
+            (s, keys[i, : klens[i]].tobytes(), vbuf[e - n : e], v)
+            for i, (s, e, n, v) in enumerate(
+                zip(
+                    self["shards"].tolist(), vends, vlens.tolist(),
+                    self["versions"].tolist(),
+                )
+            )
+        ]
+
+    @classmethod
+    def from_rows(cls, rows, shard_version, key_width: int) -> "TableDump":
+        """The arrays of ``rows`` (``d["rows"]``'s inverse)."""
+        n = len(rows)
+        keys = np.zeros((n, key_width), np.uint8)
+        for i, r in enumerate(rows):
+            keys[i, : len(r[1])] = np.frombuffer(r[1], np.uint8)
+        return cls(
+            shards=np.fromiter((r[0] for r in rows), np.int64, n),
+            keys=keys,
+            klens=np.fromiter((len(r[1]) for r in rows), np.int64, n),
+            vbuf=b"".join(r[2] for r in rows),
+            vlens=np.fromiter((len(r[2]) for r in rows), np.int64, n),
+            versions=np.fromiter((r[3] for r in rows), np.int64, n),
+            shard_version=shard_version,
+        )
+
+
 class DeviceKVTable:
     """Device twin of the vector store's SET lane (see module doc)."""
 
@@ -330,6 +375,8 @@ class DeviceKVTable:
         self.VW = _bucket(int(value_width))
         self.K4 = self.K // 4
         self.VW4 = self.VW // 4
+        # devices that every placed operand and the table are split over
+        self.n_devices = int(kernel.mesh.devices.size)
         S, Pc = self.S, self.P
         # host arrays go STRAIGHT to their shard-axis placement: the
         # table ([S, ...]) and every per-window operand ([W, S, ...])
@@ -361,6 +408,9 @@ class DeviceKVTable:
         # devkv_dict_attempts_total reads it): "rejected" is the full
         # path's D > max_dict or a failed verification
         self.dict_attempts = {"built": 0, "probe_rejected": 0, "rejected": 0}
+        # table rows materialized on the host by dump(), ever (the
+        # engine's devkv_sync_rows_total reads it)
+        self.sync_rows = 0
 
     # -- host-side packing -------------------------------------------------
 
@@ -791,7 +841,9 @@ class DeviceKVTable:
         ``upload_bytes``."""
         nbytes = sum(a.nbytes for a in operands)
         self.upload_bytes += nbytes
-        return device_annotation("rabia.dispatch.place", bytes=nbytes)
+        return device_annotation(
+            "rabia.dispatch.place", bytes=nbytes, devices=self.n_devices
+        )
 
     def _program(self, key: tuple, build):
         """The jitted program of signature ``key``, built by ``build()``
@@ -1569,30 +1621,50 @@ class DeviceKVTable:
 
     # -- sync down (demotion / checkpoint) -----------------------------------
 
-    def dump(self) -> dict:
-        """Materialize the table on host: per-entry rows + counters."""
-        used, keyw, klen, ver, valw, vlen, sver = (
-            # contiguous: a fetched sharded array can come back with a
-            # non-contiguous layout, which .view(uint8) rejects
-            np.ascontiguousarray(np.asarray(a)) for a in self.state
-        )
-        key_bytes = keyw.view(np.uint8).reshape(self.S, self.P, self.K)
-        val_bytes = valw.view(np.uint8).reshape(self.S, self.P, self.VW)
-        rows = []
-        s_idx, p_idx = np.nonzero(used[: self.n_shards])
-        for s, p in zip(s_idx.tolist(), p_idx.tolist()):
-            rows.append(
-                (
-                    s,
-                    key_bytes[s, p, : klen[s, p]].tobytes(),
-                    val_bytes[s, p, : vlen[s, p]].tobytes(),
-                    int(ver[s, p]),
-                )
+    def dump(self) -> "TableDump":
+        """Materialize the table on host: the live entries as arrays in
+        shard-major slot order, plus the per-shard counters. Each plane
+        is fetched once (gathered from every device of the mesh) and no
+        Python runs per row."""
+        # the flags first: they say how many rows the span is about
+        used = self._fetch(self.state[0])[: self.n_shards]
+        s_idx, p_idx = np.nonzero(used)
+        n = len(s_idx)
+        nbytes = sum(a.nbytes for a in self.state)
+        with device_annotation("rabia.sync.dump", rows=n, bytes=nbytes):
+            keyw, klen, ver, valw, vlen, sver = map(
+                self._fetch, self.state[1:]
             )
-        return {
-            "rows": rows,
-            "shard_version": sver[: self.n_shards].astype(np.int64),
-        }
+            klens = klen[s_idx, p_idx].astype(np.int64)
+            vlens = vlen[s_idx, p_idx].astype(np.int64)
+            keys = keyw.view(np.uint8).reshape(self.S, self.P, self.K)[
+                s_idx, p_idx
+            ]
+            # zero tails whatever a slot holds past its key: the host
+            # store hashes and compares whole zero-padded lanes
+            keys[np.arange(self.K) >= klens[:, None]] = 0
+            vals = valw.view(np.uint8).reshape(self.S, self.P, self.VW)[
+                s_idx, p_idx
+            ]
+            self.sync_rows += n
+            return TableDump(
+                shards=s_idx.astype(np.int64),
+                keys=keys,
+                klens=klens,
+                # the values back to back: one buffer that every replica
+                # store references by offset and length
+                vbuf=vals[np.arange(self.VW) < vlens[:, None]].tobytes(),
+                vlens=vlens,
+                versions=ver[s_idx, p_idx].astype(np.int64),
+                shard_version=sver[: self.n_shards].astype(np.int64),
+            )
+
+    @staticmethod
+    def _fetch(a) -> np.ndarray:
+        """One device array on the host, whole and contiguous: a fetched
+        sharded array can come back with a non-contiguous layout, which
+        ``.view(uint8)`` rejects."""
+        return np.ascontiguousarray(np.asarray(a))
 
     def upload_from(self, sm, seed_cache: Optional[dict] = None) -> bool:
         """Rebuild the device table from one host replica store
@@ -1693,26 +1765,38 @@ class DeviceKVTable:
         device table. The host store is reset first — in device mode the
         host replicas saw none of the device lane's applies. Pass a
         precomputed ``dump()`` when syncing several replicas: the table
-        materialization (a device->host transfer) then happens once."""
+        materialization (a device->host transfer) then happens once.
+        A dump that carries ``"rows"`` is rebuilt from those rows."""
         from rabia_tpu.apps.vector_kv import VectorKVStore
 
         d = dump if dump is not None else self.dump()
-        rows = d["rows"]
-        store = VectorKVStore(
-            self.n_shards, capacity=max(1 << 10, 2 * len(rows))
-        )
-        if rows:
-            # one bulk insert for the whole table (rows are distinct keys
-            # in shard-major order), then pin the real versions over the
-            # provisional ones bulk_set assigned
-            n = len(rows)
-            shards = np.fromiter((r[0] for r in rows), np.int64, n)
-            lanes, klens = store._lanes_from_keys([r[1] for r in rows])
-            store.bulk_set(shards, lanes, klens, [r[2] for r in rows])
-            slot = store._lookup(shards, lanes, klens)
-            store.version[slot] = np.fromiter(
-                (r[3] for r in rows), np.int64, n
+        if "rows" in d:
+            d = TableDump.from_rows(d["rows"], d["shard_version"], self.K)
+        n = len(d["shards"])
+        with device_annotation("rabia.sync.rebuild", rows=n):
+            store = VectorKVStore(
+                self.n_shards, capacity=max(1 << 10, 2 * n)
             )
-        store.shard_version[:] = 0
-        store.shard_version[: self.n_shards] = d["shard_version"]
-        sm.store = store
+            if n:
+                # one bulk insert for the whole table (distinct keys in
+                # shard-major order), then pin the real versions over
+                # the provisional ones bulk_set assigned
+                shards, klens, vlens = d["shards"], d["klens"], d["vlens"]
+                if int(klens.max()) > store.K:
+                    raise ValueError(
+                        f"a table key of {int(klens.max())} B does not "
+                        f"fit the host store's {store.K} B of lanes"
+                    )
+                mat = np.zeros((n, store.K), np.uint8)
+                w = min(self.K, store.K)
+                mat[:, :w] = d["keys"][:, :w]
+                lanes = mat.view(np.uint64)
+                voffs = np.cumsum(vlens) - vlens
+                store.bulk_set(
+                    shards, lanes, klens, (d["vbuf"], voffs, vlens)
+                )
+                slot = store._lookup(shards, lanes, klens)
+                store.version[slot] = d["versions"]
+            store.shard_version[:] = 0
+            store.shard_version[: self.n_shards] = d["shard_version"]
+            sm.store = store

@@ -524,6 +524,21 @@ class MeshEngine:
                 ),
             )
         m.counter(
+            "devkv_sync_rows_total",
+            "Device table rows materialized on the host by dump() (the "
+            "rows= of the rabia.sync.dump spans): a sync_to_host, a "
+            "demotion or a checkpoint each read the whole table once",
+            fn=lambda: self._dev.sync_rows if self._dev is not None else 0,
+        )
+        self._dev_value_download_bytes = 0
+        m.counter(
+            "devkv_value_download_bytes_total",
+            "Value-plane bytes the settle downloaded from the device "
+            "because a read's version had left the host segments (the "
+            "rabia.cycle.settle.download spans)",
+            fn=lambda: self._dev_value_download_bytes,
+        )
+        m.counter(
             "devkv_program_builds_total",
             "Window programs built, one per distinct signature (each "
             "one's first call is a rabia.jit.first_call span)",
@@ -1453,6 +1468,7 @@ class MeshEngine:
             with device_annotation("rabia.cycle.settle.download"):
                 vlen = np.asarray(vlen_d)
                 valw = np.asarray(valw_d)
+            self._dev_value_download_bytes += vlen.nbytes + valw.nbytes
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             if resolved:
@@ -1526,6 +1542,7 @@ class MeshEngine:
             else:
                 with device_annotation("rabia.cycle.settle.download"):
                     gval_h = np.asarray(rec["gval_dev"])
+                self._dev_value_download_bytes += gval_h.nbytes
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             row_kind = kind[t]
@@ -1947,7 +1964,7 @@ class MeshEngine:
             self._dev.sync_into(sm, dump=d)
         logger.info(
             "device KV lane demoted to host stores (%d entries)",
-            len(d["rows"]),
+            len(d["shards"]),
         )
 
     def _try_repromote_device_store(self) -> None:
