@@ -49,6 +49,7 @@ the host store but the observable key->(value, version) mapping cannot.
 
 from __future__ import annotations
 
+import sys
 from collections.abc import Sequence
 from typing import NamedTuple, Optional, Union
 
@@ -99,6 +100,64 @@ def _row_hash(klen, vlen, kwin, vwin) -> np.ndarray:
 
 # real shard columns the dictionary probe reads before the whole window
 _PROBE_SHARDS = 8
+
+
+# buffers the plane pool keeps, idle or handed out: five planes a window
+# times the windows that hold them (the pipe's three in flight, the
+# retained segments, the one being packed), with room for a second shape
+_PLANE_POOL_CAP = 40
+
+
+class _PlanePool:
+    """Byte buffers for the window planes, kept across windows so the
+    gather writes into memory the process has already touched (a fresh
+    plane of tens of MB is a new mapping, faulted in page by page under
+    the gather, every window).
+
+    A buffer is handed out again only when the pool's own reference to
+    it is the last one. Every plane is a view of its buffer, and so is
+    everything taken from a plane (``.view(np.uint32)`` operands, a
+    segment's ``vwin8``, a reply's row): a view keeps its base alive,
+    and a ``device_put`` keeps the array it was handed until the runtime
+    has stopped reading it (for the array's whole life where the CPU
+    backend aliases host memory). So the buffer's reference count sees
+    every holder, whoever it is and however long it holds, and nothing
+    has to tell the pool about a release. While anything can still read
+    a buffer the pool allocates instead: correctness never waits on it.
+    """
+
+    def __init__(self) -> None:
+        # least recently handed out first
+        self._bufs: list = [np.empty(0, np.uint8)]
+        # what _holders reads of a buffer that only the pool holds
+        self._idle = self._holders(0)
+        self._bufs.clear()
+        self.outcomes = {"reused": 0, "fresh": 0}
+
+    def _holders(self, i: int) -> int:
+        return sys.getrefcount(self._bufs[i])
+
+    def __len__(self) -> int:
+        return len(self._bufs)
+
+    def idle(self, nbytes: int) -> Optional[np.ndarray]:
+        """An idle buffer of exactly ``nbytes``, now the caller's, or
+        None (the caller then asks for a ``fresh`` one)."""
+        bufs = self._bufs
+        for i in range(len(bufs)):
+            if bufs[i].nbytes == nbytes and self._holders(i) == self._idle:
+                bufs.append(bufs.pop(i))
+                self.outcomes["reused"] += 1
+                return bufs[-1]
+        return None
+
+    def fresh(self, nbytes: int) -> np.ndarray:
+        """A new buffer, kept for later windows; over the cap the pool
+        forgets the buffer it handed out longest ago."""
+        self._bufs.append(np.empty(nbytes, np.uint8))
+        del self._bufs[:-_PLANE_POOL_CAP]
+        self.outcomes["fresh"] += 1
+        return self._bufs[-1]
 
 
 def _bucket(n: int, lo: int = 4) -> int:
@@ -411,6 +470,15 @@ class DeviceKVTable:
         # table rows materialized on the host by dump(), ever (the
         # engine's devkv_sync_rows_total reads it)
         self.sync_rows = 0
+        # the window planes' buffers, reused once nothing else holds them
+        self._planes = _PlanePool()
+        # planes taken from it by outcome, ever (the engine's
+        # devkv_pack_buffers_total reads it)
+        self.pack_buffers = self._planes.outcomes
+        # the gather's per-op columns by name, kept across windows and
+        # grown on demand; they die inside _gather_into, so nothing can
+        # hold them
+        self._scratch: dict = {}
 
     # -- host-side packing -------------------------------------------------
 
@@ -451,17 +519,26 @@ class DeviceKVTable:
         parsed, ku, vu = parsed
         W = len(blocks)
         S = self.S
-        with device_annotation("rabia.cycle.pack.alloc"):
-            kind_w = np.zeros((W, S), np.int8)
-            klen_w = np.zeros((W, S), np.int16)
-            vlen_w = np.zeros((W, S), np.int16)
-            kwin_w = np.zeros((W, S, ku), np.uint8)
-            vwin_w = np.zeros((W, S, vu), np.uint8)
-        with device_annotation("rabia.cycle.pack.gather"):
-            self._gather_into(
-                parsed, kind_w, klen_w, vlen_w, kwin_w, vwin_w
+        specs = (
+            ((W, S), np.int8),
+            ((W, S), np.int16),
+            ((W, S), np.int16),
+            ((W, S, ku), np.uint8),
+            ((W, S, vu), np.uint8),
+        )
+        sizes = [int(np.prod(sh)) * np.dtype(dt).itemsize for sh, dt in specs]
+        pool = self._planes
+        bufs = [pool.idle(nb) for nb in sizes]
+        with device_annotation(
+            "rabia.cycle.pack.alloc", reused=sum(b is not None for b in bufs)
+        ):
+            planes = tuple(
+                (pool.fresh(nb) if b is None else b).view(dt).reshape(sh)
+                for b, nb, (sh, dt) in zip(bufs, sizes, specs)
             )
-        return kind_w, klen_w, vlen_w, kwin_w, vwin_w
+        with device_annotation("rabia.cycle.pack.gather"):
+            self._gather_into(parsed, *planes)
+        return planes
 
     def _parse_window(self, blocks, allow: str) -> Optional[tuple]:
         """Parse and validate every block of a window against the
@@ -504,10 +581,39 @@ class DeviceKVTable:
     def _gather_into(
         self, parsed, kind_w, klen_w, vlen_w, kwin_w, vwin_w
     ) -> None:
-        """Fill the zeroed ``[W, S, ...]`` planes from the parsed blocks
-        (the native one-pass gather on the grid shape, else numpy)."""
-        W, _, ku = kwin_w.shape
-        vu = vwin_w.shape[2]
+        """Fill the ``[W, S, ...]`` planes from the parsed blocks (the
+        native one-pass gather on the grid shape, else numpy). The
+        planes come from the pool and may hold anything: every path
+        writes every byte of all five."""
+        counts = [len(p[2]) for p in parsed]
+        n_ops = sum(counts)
+
+        def column(name: str, parts: list, dtype) -> np.ndarray:
+            """The blocks' per-op arrays end to end, in scratch."""
+            a = self._scratch.get(name)
+            if a is None or len(a) < n_ops:
+                a = self._scratch[name] = np.empty(n_ops, dtype)
+            return np.concatenate(parts, out=a[:n_ops])
+
+        off_all = column("off", [p[2] for p in parsed], np.int64)  # in-block
+        klen_all = column("klen", [p[3] for p in parsed], np.int64)
+        vlen_all = column("vlen", [p[4] for p in parsed], np.int64)
+        op_all = column("op", [p[5] for p in parsed], np.uint8)
+        sh_all = column("sh", [p[0].shards for p in parsed], np.int64)
+        W = len(parsed)
+        n = self.n_shards
+        # full-width sorted blocks (the block lane's shape): op i of a
+        # block is shard i's, so the scatter is a contiguous assign —
+        # advanced-index scatters on 500k+ rows were ~half the gather cost
+        grid = n_ops == W * n and bool(
+            (sh_all.reshape(W, n) == np.arange(n)[None, :]).all()
+        )
+        if grid and self._native_pack_gather(
+            [p[1] for p in parsed], off_all, klen_all, vlen_all, op_all,
+            n, kind_w, klen_w, vlen_w, kwin_w, vwin_w,
+        ):
+            return
+        ku, vu = kwin_w.shape[2], vwin_w.shape[2]
         kcols = np.arange(ku)[None, :]
         vcols = np.arange(vu)[None, :]
         # batch the W per-block gathers into ONE: concatenate the block
@@ -520,28 +626,7 @@ class DeviceKVTable:
         bases = np.zeros(W, np.int64)
         bases[1:] = np.cumsum(sizes[:-1])
         dbuf_all = np.concatenate([p[1] for p in parsed])
-        off_all = np.concatenate(
-            [p[2] + bases[t] for t, p in enumerate(parsed)]
-        )
-        klen_all = np.concatenate([p[3] for p in parsed])
-        vlen_all = np.concatenate([p[4] for p in parsed])
-        op_all = np.concatenate([p[5] for p in parsed])
-        sh_all = np.concatenate([p[0].shards for p in parsed])
-        n = self.n_shards
-        grid = len(sh_all) == W * n and bool(
-            (sh_all.reshape(W, n) == np.arange(n)[None, :]).all()
-        )
-        if grid:
-            # full-width sorted blocks (the block lane's shape): the
-            # scatter is a contiguous reshape-assign — advanced-index
-            # scatters on 500k+ rows were ~half the gather cost
-            kind_w[:, :n] = op_all.reshape(W, n)
-            klen_w[:, :n] = klen_all.reshape(W, n)
-            vlen_w[:, :n] = vlen_all.reshape(W, n)
-            if self._native_pack_gather(
-                dbuf_all, off_all, klen_all, vlen_all, n, kwin_w, vwin_w
-            ):
-                return
+        off_all = off_all + np.repeat(bases, counts)
         kw = dbuf_all[(off_all + _SET_HDR)[:, None] + kcols]
         kw = np.where(kcols < klen_all[:, None], kw, 0)
         vidx = np.minimum(
@@ -551,12 +636,17 @@ class DeviceKVTable:
         vw = dbuf_all[vidx]
         vw = np.where(vcols < vlen_all[:, None], vw, 0)
         if grid:
-            kwin_w[:, :n] = kw.reshape(W, n, ku)
-            vwin_w[:, :n] = vw.reshape(W, n, vu)
+            # whole rows, whatever the native gather left behind
+            for plane, vals in (
+                (kind_w, op_all), (klen_w, klen_all), (vlen_w, vlen_all),
+                (kwin_w, kw), (vwin_w, vw),
+            ):
+                plane[:, :n] = vals.reshape((W, n) + plane.shape[2:])
+                plane[:, n:] = 0
         else:
-            t_all = np.repeat(
-                np.arange(W), [len(p[2]) for p in parsed]
-            )
+            for plane in (kind_w, klen_w, vlen_w, kwin_w, vwin_w):
+                plane.fill(0)  # the scatter covers only the ops' cells
+            t_all = np.repeat(np.arange(W), counts)
             kind_w[t_all, sh_all] = op_all
             klen_w[t_all, sh_all] = klen_all
             vlen_w[t_all, sh_all] = vlen_all
@@ -564,14 +654,19 @@ class DeviceKVTable:
             vwin_w[t_all, sh_all] = vw
 
     def _native_pack_gather(
-        self, dbuf_all, off_all, klen_all, vlen_all, n, kwin_w, vwin_w
+        self, dbufs, off_all, klen_all, vlen_all, op_all, n,
+        kind_w, klen_w, vlen_w, kwin_w, vwin_w,
     ) -> bool:
-        """One-pass C gather of key/value bytes into the zeroed padded
-        planes (GRID fast path only; op i = wave i//n, shard i%n). The
-        numpy gather stays the semantics owner — False (library
-        unavailable, ``RABIA_PY_DEVPACK=1``, or the C bounds check
-        tripping) routes the caller to it. Byte-equivalence with the
-        numpy path is pinned in tests/test_device_kv.py."""
+        """One-pass C gather into the five planes, every row written
+        whole (GRID fast path only: op ``t * n + i`` is wave t, shard
+        i). The blocks' bytes are read where they lie, ``dbufs[t]`` by
+        its own base pointer with ``off_all`` relative to it, so the
+        window's bytes are never concatenated. The numpy gather stays
+        the semantics owner — False (library unavailable,
+        ``RABIA_PY_DEVPACK=1``, or the C bounds check tripping, which
+        leaves the planes half written) routes the caller to it, and
+        it writes every row again. Byte-equivalence with the numpy
+        path is pinned in tests/test_device_kv.py."""
         import os
 
         # =1 opts out, matching the docstring/tests convention — a plain
@@ -584,25 +679,19 @@ class DeviceKVTable:
         lib = load_hostkernel()
         if lib is None:
             return False
-        W_, S_, ku = kwin_w.shape
-        vu = vwin_w.shape[2]
-        dbuf_all = np.ascontiguousarray(dbuf_all)
-        off64 = np.ascontiguousarray(off_all, np.int64)
-        klen64 = np.ascontiguousarray(klen_all, np.int64)
-        vlen64 = np.ascontiguousarray(vlen_all, np.int64)
+        dbufs = [np.ascontiguousarray(d, np.uint8) for d in dbufs]
+        bases = np.array([d.ctypes.data for d in dbufs], np.uintp)
+        lens = np.array([len(d) for d in dbufs], np.int64)
+        W, S, ku = kwin_w.shape
         rc = lib.rk_pack_gather(
-            dbuf_all.ctypes.data, len(dbuf_all),
-            off64.ctypes.data, klen64.ctypes.data, vlen64.ctypes.data,
-            len(off64), n, S_, _SET_HDR, ku, vu,
+            W, n, S, _SET_HDR, ku, vwin_w.shape[2],
+            bases.ctypes.data, lens.ctypes.data,
+            off_all.ctypes.data, klen_all.ctypes.data,
+            vlen_all.ctypes.data, op_all.ctypes.data,
+            kind_w.ctypes.data, klen_w.ctypes.data, vlen_w.ctypes.data,
             kwin_w.ctypes.data, vwin_w.ctypes.data,
         )
-        if rc != 0:
-            # defensive bounds trip: rezero the partially-written
-            # planes before the numpy path repopulates them
-            kwin_w[...] = 0
-            vwin_w[...] = 0
-            return False
-        return True
+        return rc == 0
 
     def pack_window(self, blocks) -> Optional[DeviceWindowOps]:
         """Pack SET-only ``blocks`` (one per wave, FIFO order) into

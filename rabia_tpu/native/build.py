@@ -246,11 +246,10 @@ def load_hostkernel() -> ctypes.CDLL | None:
         ]
         lib.rk_pack_gather.restype = ctypes.c_int32
         lib.rk_pack_gather.argtypes = [
-            p, ctypes.c_int64,
-            p, p, p,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            p, p,
+            p, p, p, p, p, p,
+            p, p, p, p, p,
         ]
         lib.rk_stall_scan.restype = ctypes.c_int32
         lib.rk_stall_scan.argtypes = [

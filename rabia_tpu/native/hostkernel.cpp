@@ -229,31 +229,58 @@ void rk_start_slots(
 // instead of ~9 numpy dispatches per tick. Fills head[s] =
 // max(next_slot, applied) and cand[s]; returns the candidate count so an
 // idle tick exits on a single int.
-// Device-KV window pack gather (the GRID fast path: full-width sorted
-// blocks, op i covers wave i/n, shard i%n). One pass copies each op's
-// key/value bytes into the zeroed padded planes — replacing numpy's
-// materialize-gather + where-mask + reshape-scatter chain (~4 full
-// passes over the op bytes) with a single read+write. Validation
-// stays in Python (the numpy path remains the semantics owner and
-// fallback); this function only trusts its own bounds check and
-// returns nonzero on any out-of-range op so the caller can fall back.
+// Device-KV window pack gather (the GRID fast path: W full-width sorted
+// blocks, op t * n + s covers wave t, shard s). One pass reads each
+// block's padded bytes where they lie (W base pointers; an op's offset
+// is relative to its own block) and, per op, its offset, key length,
+// value length and opcode, and writes the five padded planes —
+// replacing numpy's concatenate + materialize-gather + where-mask +
+// reshape-scatter chain (~4 full passes over the op bytes) with a
+// single read+write. The planes may hold anything on entry (they are
+// reused across windows): every row is written whole, its bytes then
+// zeros to the row's width, and so are the columns n..S that no op
+// covers. Validation stays in Python (the numpy path remains the
+// semantics owner and fallback); this function only trusts its own
+// bounds check and returns nonzero on any out-of-range op so the
+// caller can fall back.
 int32_t rk_pack_gather(
-    const uint8_t* dbuf, int64_t dbuf_len,
+    int64_t W, int64_t n, int64_t S, int64_t hdr, int64_t ku, int64_t vu,
+    const uint8_t* const* dbuf, const int64_t* dbuf_len,
     const int64_t* off, const int64_t* klen, const int64_t* vlen,
-    int64_t n_ops, int64_t n, int64_t S, int64_t hdr,
-    int64_t ku, int64_t vu,
+    const uint8_t* op,
+    int8_t* kind_w, int16_t* klen_w, int16_t* vlen_w,
     uint8_t* kwin, uint8_t* vwin) {
-  for (int64_t i = 0; i < n_ops; i++) {
-    const int64_t kl = klen[i];
-    const int64_t vl = vlen[i];
-    const int64_t o = off[i] + hdr;
-    if (kl < 0 || vl < 0 || kl > ku || vl > vu || o < 0 ||
-        o + kl + vl > dbuf_len) {
-      return 1;  // out of envelope/bounds: caller uses the numpy path
+  for (int64_t t = 0; t < W; t++) {
+    const uint8_t* d = dbuf[t];
+    for (int64_t s = 0; s < n; s++) {
+      const int64_t i = t * n + s;
+      const int64_t kl = klen[i];
+      const int64_t vl = vlen[i];
+      const int64_t o = off[i] + hdr;
+      if (kl < 0 || vl < 0 || kl > ku || vl > vu || o < 0 ||
+          o + kl + vl > dbuf_len[t]) {
+        return 1;  // out of envelope/bounds: caller uses the numpy path
+      }
+      const int64_t row = t * S + s;
+      kind_w[row] = (int8_t)op[i];
+      klen_w[row] = (int16_t)kl;
+      vlen_w[row] = (int16_t)vl;
+      uint8_t* k = kwin + row * ku;
+      uint8_t* v = vwin + row * vu;
+      // zeros first, the bytes over them: one memset of the row's
+      // width costs less than one of each tail's own length
+      std::memset(k, 0, (size_t)ku);
+      std::memcpy(k, d + o, (size_t)kl);
+      std::memset(v, 0, (size_t)vu);
+      if (vl) std::memcpy(v, d + o + kl, (size_t)vl);
     }
-    const int64_t row = (i / n) * S + (i % n);
-    std::memcpy(kwin + row * ku, dbuf + o, (size_t)kl);
-    std::memcpy(vwin + row * vu, dbuf + o + kl, (size_t)vl);
+    const int64_t row = t * S + n;  // the wave's uncovered columns
+    const size_t pad = (size_t)(S - n);
+    std::memset(kind_w + row, 0, pad * sizeof(int8_t));
+    std::memset(klen_w + row, 0, pad * sizeof(int16_t));
+    std::memset(vlen_w + row, 0, pad * sizeof(int16_t));
+    std::memset(kwin + row * ku, 0, pad * (size_t)ku);
+    std::memset(vwin + row * vu, 0, pad * (size_t)vu);
   }
   return 0;
 }

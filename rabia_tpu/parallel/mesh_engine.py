@@ -523,6 +523,22 @@ class MeshEngine:
                     else 0
                 ),
             )
+        for _outcome in ("reused", "fresh"):
+            m.counter(
+                "devkv_pack_buffers_total",
+                "Window-plane buffers the pack took from the table's "
+                "pool by outcome (the reused= of the "
+                "rabia.cycle.pack.alloc spans): reused = a buffer of an "
+                "earlier window that nothing else still referenced, "
+                "fresh = a new allocation because every pooled buffer "
+                "of that size was still held",
+                {"outcome": _outcome},
+                fn=(
+                    lambda o=_outcome: self._dev.pack_buffers[o]
+                    if self._dev is not None
+                    else 0
+                ),
+            )
         m.counter(
             "devkv_sync_rows_total",
             "Device table rows materialized on the host by dump() (the "
@@ -1565,8 +1581,11 @@ class MeshEngine:
             elif not bool(((row_kind == 1) | (row_kind >= 3)).any()):
                 bfut._settle_bulk(gf)  # pure-GET wave (GET framing only)
             else:
+                # the reply owns its kind row: a view would hold the
+                # window's whole kind plane out of the pack's pool for
+                # as long as a client keeps the reply unread
                 bfut._settle_bulk(
-                    MixedFrameGroups(sh, row_kind, svers[t], gf)
+                    MixedFrameGroups(sh, row_kind.copy(), svers[t], gf)
                 )
 
     def _dev_drain_pipe(self) -> int:

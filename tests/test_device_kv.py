@@ -1311,3 +1311,379 @@ class TestDictProbe:
         # the same rows in a real, probed column are seen
         klen_w[:, 0] = np.arange(1, _PROBE_W + 1, dtype=klen_w.dtype)
         assert dev._dict_probe_rejects(g, _MAX_DICT)
+
+
+# -- the pack's plane pool: warm buffers, handed out again only when idle --
+
+_WIDTHS = ((2, 3), (13, 40), (2, 3))  # (key bytes, largest value) a window
+
+
+def _width_blocks(n: int, allow: str, klen: int, vmax: int, W: int = 5):
+    """One window whose keys are ``klen`` bytes and whose values run up
+    to ``vmax`` bytes: the pair sets the window's ku / vu buckets."""
+
+    def cmd(s: int, w: int) -> bytes:
+        key = f"{(s + w) % 7}".rjust(klen, "k")
+        if allow == "set" or (allow == "mixed" and (s + w) % 2 == 0):
+            return encode_set_bin(key, "v" * ((s * 5 + w) % (vmax + 1)))
+        return TestDeviceGetWindows._enc_get(key)
+
+    return [
+        build_block(list(range(n)), [[cmd(s, w)] for s in range(n)])
+        for w in range(W)
+    ]
+
+
+def _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch) -> tuple:
+    """The semantics owner's planes: the numpy gather into zeroed ones."""
+    parsed, ku, vu = dev._parse_window(blocks, allow)
+    W, S = len(blocks), dev.S
+    planes = (
+        np.zeros((W, S), np.int8),
+        np.zeros((W, S), np.int16),
+        np.zeros((W, S), np.int16),
+        np.zeros((W, S, ku), np.uint8),
+        np.zeros((W, S, vu), np.uint8),
+    )
+    monkeypatch.setenv("RABIA_PY_DEVPACK", "1")
+    dev._gather_into(parsed, *planes)
+    monkeypatch.delenv("RABIA_PY_DEVPACK")
+    return planes
+
+
+def _gather_into_dirty_pool(dev, blocks, allow) -> tuple:
+    """``_gather_window`` with every plane a pooled buffer full of 0xFF."""
+    assert dev._gather_window(blocks, allow) is not None  # stocks the pool
+    for i in range(len(dev._planes)):
+        dev._planes._bufs[i].fill(0xFF)  # (a loop variable would hold one)
+    before = dict(dev.pack_buffers)
+    got = dev._gather_window(blocks, allow)
+    assert dev.pack_buffers == {
+        "reused": before["reused"] + 5, "fresh": before["fresh"]
+    }
+    return got
+
+
+def _assert_same_planes(got, want) -> None:
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+@pytest.fixture
+def native_gather_calls(monkeypatch):
+    """What each ``_native_pack_gather`` call returned, in order."""
+    from rabia_tpu.apps.device_kv import DeviceKVTable
+    from rabia_tpu.native.build import load_hostkernel
+
+    if load_hostkernel() is None:
+        pytest.skip("native host kernel unavailable")
+    monkeypatch.delenv("RABIA_PY_DEVPACK", raising=False)
+    calls = []
+    orig = DeviceKVTable._native_pack_gather
+
+    def spy(self_, *a):
+        calls.append(orig(self_, *a))
+        return calls[-1]
+
+    monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", spy)
+    return calls
+
+
+@pytest.mark.parametrize("n", [12, 16], ids=["padded", "full"])
+@pytest.mark.parametrize("allow", ["set", "get", "mixed"])
+class TestGatherIntoDirtyPlanes:
+    """A reused plane is not zeroed first: every path writes every byte,
+    so a plane full of 0xFF ends up the numpy path's on a zeroed one."""
+
+    def test_native_gather_over_changing_widths(
+        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+    ):
+        dev = probe_tables[n]
+        shapes = set()
+        for klen, vmax in _WIDTHS:  # consecutive windows, other buckets
+            blocks = _width_blocks(n, allow, klen, vmax)
+            want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+            native_gather_calls.clear()
+            got = _gather_into_dirty_pool(dev, blocks, allow)
+            assert native_gather_calls == [True, True]
+            _assert_same_planes(got, want)
+            shapes.add(got[3].shape[2:] + got[4].shape[2:])
+        assert len(shapes) == 2
+
+    def test_bounds_trip_falls_back_to_numpy(
+        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+    ):
+        from rabia_tpu.apps.device_kv import DeviceKVTable
+
+        spy = DeviceKVTable._native_pack_gather
+
+        def short_buffer(self_, dbufs, *a):
+            # the C loop writes the first waves' rows, then meets an op
+            # that ends past the bytes of its block
+            last = dbufs[-1]
+            return spy(self_, [*dbufs[:-1], last[: len(last) // 2]], *a)
+
+        dev = probe_tables[n]
+        blocks = _width_blocks(n, allow, 13, 40)
+        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+        monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", short_buffer)
+        native_gather_calls.clear()
+        got = _gather_into_dirty_pool(dev, blocks, allow)
+        assert native_gather_calls == [False, False]
+        _assert_same_planes(got, want)
+
+    def test_block_bytes_of_another_layout_are_copied_for_c(
+        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+    ):
+        from rabia_tpu.apps.device_kv import DeviceKVTable
+
+        spy = DeviceKVTable._native_pack_gather
+
+        def strided(self_, dbufs, *a):
+            # every other byte of a buffer twice as long: the same bytes,
+            # not contiguous
+            wide = np.repeat(dbufs[0], 2)
+            return spy(self_, [wide[::2], *dbufs[1:]], *a)
+
+        dev = probe_tables[n]
+        blocks = _width_blocks(n, allow, 13, 40)
+        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+        monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", strided)
+        native_gather_calls.clear()
+        got = _gather_into_dirty_pool(dev, blocks, allow)
+        assert native_gather_calls == [True, True]
+        _assert_same_planes(got, want)
+
+    def test_scattered_blocks_take_the_numpy_path(
+        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+    ):
+        # blocks that are not the full sorted grid: the scatter covers
+        # only the ops' cells, so the planes are cleared first
+        dev = probe_tables[n]
+        full = _width_blocks(n, allow, 13, 40)
+        shards = list(range(n - 1, 0, -2))
+        blocks = [
+            build_block(
+                shards,
+                [
+                    [bytes(b.data[b.cmd_offsets[s] : b.cmd_offsets[s + 1]])]
+                    for s in shards
+                ],
+            )
+            for b in full
+        ]
+        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+        got = _gather_into_dirty_pool(dev, blocks, allow)
+        assert native_gather_calls == []
+        _assert_same_planes(got, want)
+        covered = np.zeros(dev.S, bool)
+        covered[shards] = True
+        assert got[1][:, covered].all() and not got[1][:, ~covered].any()
+
+
+def _same_window_read_blocks(n: int, W: int, k: int) -> list:
+    """Window ``k`` of the holder tests: wave 0 sets one key in every
+    shard, the later waves read it on the odd shards and overwrite it on
+    the even ones. Every read finds a version of its own window, so the
+    window settles through a resolver snapshot over the live segments."""
+
+    def cmd(s: int, w: int) -> bytes:
+        if w == 0 or s % 2 == 0:
+            return encode_set_bin("key", f"window{k}-wave{w}-shard{s}")
+        return TestDeviceGetWindows._enc_get("key")
+
+    return [
+        build_block(list(range(n)), [[cmd(s, w)] for s in range(n)])
+        for w in range(W)
+    ]
+
+
+def _run_same_window_reads(dev, host, n, W, windows, after_window=None):
+    """``windows`` windows of ``_same_window_read_blocks`` through both
+    engines, one ``run_cycle`` of ``dev`` a window; returns the (dev,
+    host) future pairs."""
+    pairs = []
+    for k in range(windows):
+        for b, bh in zip(
+            _same_window_read_blocks(n, W, k), _same_window_read_blocks(n, W, k)
+        ):
+            pairs.append((dev.submit_block(b), host.submit_block(bh)))
+        dev.run_cycle()
+        if after_window is not None:
+            after_window(k)
+    return pairs
+
+
+def _assert_same_replies(pairs) -> None:
+    for fd, fh in pairs:
+        assert [list(map(bytes, g)) for g in fd.result()] == [
+            list(map(bytes, g)) for g in fh.result()
+        ]
+
+
+class TestPlanePoolHolders:
+    """The pool hands a buffer out again only when nothing can still
+    read it. Each test keeps one kind of holder over many windows, with
+    a segment cap of four windows so that segments do leave the engine,
+    then reads through the holder and finds the bytes it was given."""
+
+    N, W, WINDOWS = 8, 4, 12
+
+    def _engines(self):
+        dev = _mk(self.N, device=True, window=self.W)
+        host = _mk(self.N, device=False, window=self.W)
+        return dev, host
+
+    def _drive(self, dev, host, after_window=lambda k: None) -> list:
+        def after(k):
+            if k == 0:  # segments of one size: keep four windows of them
+                dev._dev_vseg_cap = 4 * dev._dev_vseg[-1].nbytes + 1
+            after_window(k)
+
+        return _run_same_window_reads(
+            dev, host, self.N, self.W, self.WINDOWS, after
+        )
+
+    @staticmethod
+    def _guard_pool(dev, held_ids) -> None:
+        """Fail the moment the pool hands out a buffer in ``held_ids()``."""
+        pool = dev._dev._planes
+        idle = pool.idle
+
+        def guarded(nbytes):
+            buf = idle(nbytes)
+            assert buf is None or id(buf) not in held_ids()
+            return buf
+
+        pool.idle = guarded
+
+    @staticmethod
+    def _segment_buffers(resolvers) -> set:
+        return {
+            id(plane.base)
+            for r in resolvers
+            for seg in r.segs
+            for plane in (seg.vwin8, seg.vlen, seg.kind)
+        }
+
+    def test_unread_replies_keep_their_segments(self):
+        dev, host = self._engines()
+        pairs = []
+
+        def resolvers():
+            out = []
+            for fd, _ in pairs:
+                if fd.done():
+                    for view in (fd._results, getattr(fd._results, "_get", None)):
+                        if hasattr(view, "resolver"):
+                            out.append(view.resolver)
+            return out
+
+        self._guard_pool(dev, lambda: self._segment_buffers(resolvers()))
+        pairs.extend(self._drive(dev, host))
+        dev.flush()
+        host.flush()
+        assert dev._dev_active and dev._dev_floor[: self.N].all()
+        assert len(resolvers()) == len(pairs) - self.WINDOWS  # wave 0: SETs
+        assert dev._dev.pack_buffers["reused"] > 0
+        _assert_same_replies(pairs)  # read only now, windows after they settled
+
+    def test_a_resolver_snapshot_keeps_its_segments(self):
+        dev, host = self._engines()
+        snap, want = [], {}
+
+        def after_window(k):
+            if k != 5:
+                return
+            snap.append(dev._dev_make_resolver())
+            for seg in snap[0].segs:
+                if seg.provisional:
+                    continue
+                for s in range(self.N):
+                    for v in range(int(seg.start[s]) + 1, int(seg.end[s]) + 1):
+                        want[(s, v)] = snap[0](s, v)
+
+        self._guard_pool(dev, lambda: self._segment_buffers(snap))
+        pairs = self._drive(dev, host, after_window)
+        dev.flush()
+        host.flush()
+        del pairs  # the snapshot alone holds the segments now
+        assert len(want) >= self.N * self.W  # whole windows of versions
+        assert not set(snap[0].segs) & set(dev._dev_vseg)  # all evicted
+        assert dev._dev.pack_buffers["reused"] > 0
+        assert {sv: snap[0](*sv) for sv in want} == want
+        assert all(b"window" in v for v in want.values())
+
+    def test_placed_operands_keep_their_planes(self, monkeypatch):
+        from rabia_tpu.apps.device_kv import DeviceKVTable
+
+        dev, host = self._engines()
+        kept = []
+        place = DeviceKVTable._place_ops
+
+        def keeping(self_, ops):
+            placed = place(self_, ops)
+            if len(kept) < 3:  # three windows' operands, and what they held
+                kept.append((placed, [np.array(a) for a in ops]))
+            return placed
+
+        monkeypatch.setattr(DeviceKVTable, "_place_ops", keeping)
+        pairs = self._drive(dev, host)
+        dev.flush()
+        host.flush()
+        assert len(kept) == 3 and dev._dev.pack_buffers["reused"] > 0
+        for placed, copies in kept:
+            for on_device, copy in zip(placed, copies):
+                assert np.array_equal(np.asarray(on_device), copy)
+        _assert_same_replies(pairs)
+
+
+class TestPlanePoolBounds:
+    def test_steady_windows_stop_allocating(self):
+        # same-shape windows whose replies are read and dropped, a
+        # segment cap of one window: once the pipe is full every plane
+        # of every window is a reused buffer
+        n, W = 8, 4
+        dev = _mk(n, device=True, window=W)
+        dev._dev_vseg_cap = 1
+        pool = dev._dev._planes
+        counter = lambda o: dev.metrics.counter(
+            "devkv_pack_buffers_total", "", {"outcome": o}
+        ).value()
+        fresh = []
+        for k in range(24):
+            futs = [
+                dev.submit_block(b) for b in _same_window_read_blocks(n, W, k)
+            ]
+            dev.run_cycle()
+            del futs
+            fresh.append(pool.outcomes["fresh"])
+        dev.flush()
+        assert dev._dev_active
+        assert fresh[-1] == fresh[8] <= 5 * 6  # six windows' worth, no more
+        assert pool.outcomes["reused"] == 5 * 24 - fresh[-1]
+        assert counter("fresh") == fresh[-1]
+        assert counter("reused") == pool.outcomes["reused"]
+        assert len(pool) == fresh[-1]
+
+    def test_pool_keeps_no_more_than_its_cap(self):
+        # every reply kept unread under the default segment cap: no
+        # buffer of a value plane ever comes back, the pool allocates
+        # for them every window and forgets the oldest over its cap
+        from rabia_tpu.apps.device_kv import _PLANE_POOL_CAP
+
+        n, W = 8, 4
+        dev = _mk(n, device=True, window=W)
+        host = _mk(n, device=False, window=W)
+        pool = dev._dev._planes
+        sizes = []
+        pairs = _run_same_window_reads(
+            dev, host, n, W, _PLANE_POOL_CAP, lambda k: sizes.append(len(pool))
+        )
+        assert max(sizes) <= _PLANE_POOL_CAP
+        dev.flush()
+        host.flush()
+        assert len(pool) == _PLANE_POOL_CAP
+        assert pool.outcomes["fresh"] >= 3 * _PLANE_POOL_CAP
+        _assert_same_replies(pairs)

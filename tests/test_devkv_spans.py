@@ -398,3 +398,43 @@ class TestNamedScopes:
         for a, b in zip(with_scopes, bare):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         eng.close()
+
+
+class TestPackBuffers:
+    OUTCOMES = ("reused", "fresh")
+
+    def test_alloc_span_and_counter_say_how_many_planes_were_reused(
+        self, monkeypatch
+    ):
+        from rabia_tpu.apps import device_kv
+
+        seen = []
+        annotate = device_kv.device_annotation
+
+        def recording(name, **stats):
+            if name == "rabia.cycle.pack.alloc":
+                seen.append(stats)
+            return annotate(name, **stats)
+
+        monkeypatch.setattr(device_kv, "device_annotation", recording)
+        eng = _engine()
+        eng._dev_vseg_cap = 1  # a window's segment leaves with the next
+        rng = np.random.default_rng(31)
+        for _ in range(10):
+            _window(eng, "mixed", rng)  # its replies are dropped unread
+        eng.flush()
+        assert eng.device_lane_active
+        # five planes a window: none of the first can be a reused buffer,
+        # all of the last are, and the counter sums what the spans said
+        assert seen[0] == {"reused": 0} and seen[-1] == {"reused": 5}
+        snap = eng.metrics.snapshot()
+        got = {
+            o: snap[f'rabia_devkv_pack_buffers_total{{outcome="{o}"}}']
+            for o in self.OUTCOMES
+        }
+        reused = sum(s["reused"] for s in seen)
+        assert got == {"reused": reused, "fresh": 5 * len(seen) - reused}
+        text = eng.metrics.render_prometheus()
+        for o in self.OUTCOMES:
+            assert f'rabia_devkv_pack_buffers_total{{outcome="{o}"}}' in text
+        eng.close()
