@@ -51,7 +51,7 @@ from __future__ import annotations
 
 import sys
 from collections.abc import Sequence
-from typing import NamedTuple, Optional, Union
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -62,45 +62,6 @@ from rabia_tpu.apps.vector_kv import _RESP_DT
 __all__ = ["DeviceKVTable", "DeviceWindowOps", "MixedFrameGroups"]
 
 _SET_HDR = 3  # binary SET op: u8 opcode(1) + u16 klen + key + value
-
-# fixed odd multipliers for the dictionary packer's 64-bit row hash
-# (collisions are VERIFIED against, never trusted — see pack_window_dict)
-_HASH_W = (
-    np.random.default_rng(0x5EED).integers(1, 2**62, 4, dtype=np.uint64)
-    * 2
-    + 1
-)
-
-
-def _fold_words(a: np.ndarray) -> np.ndarray:
-    """Polynomial-fold a u8[..., B] byte plane into u64[...] — viewed
-    as native u32/u64 words (B is a power of two >= 4), mod-2^64."""
-    mul = np.uint64(0x9E3779B97F4A7C15)
-    if a.shape[-1] % 8 == 0:
-        w = a.view(np.uint64)
-    else:
-        w = a.view(np.uint32)
-    h = np.zeros(a.shape[:-1], np.uint64)
-    for j in range(w.shape[-1]):
-        h *= mul
-        h += w[..., j]
-    return h
-
-
-def _row_hash(klen, vlen, kwin, vwin) -> np.ndarray:
-    """The dictionary packer's 64-bit hash of each (key, value) row of
-    ``[..., B]`` byte planes: equal rows hash equal, the converse is
-    verified and never trusted."""
-    h = klen.astype(np.uint64) * _HASH_W[0]
-    h += vlen.astype(np.uint64) * _HASH_W[1]
-    h += _fold_words(kwin) * _HASH_W[2]
-    h += _fold_words(vwin) * _HASH_W[3]
-    return h
-
-
-# real shard columns the dictionary probe reads before the whole window
-_PROBE_SHARDS = 8
-
 
 # buffers the plane pool keeps, idle or handed out: five planes a window
 # times the windows that hold them (the pipe's three in flight, the
@@ -180,45 +141,6 @@ class DeviceWindowOps(NamedTuple):
     vlen: np.ndarray  # i16[W, S]
     kwin: np.ndarray  # u32[W, S, Ku/4]
     vwin: np.ndarray  # u32[W, S, VWu/4]
-
-
-class DeviceDictOps(NamedTuple):
-    """One SET window dictionary-compressed for upload (host numpy).
-
-    Zipf-skewed op streams repeat (key, value) rows heavily within a
-    window; the upload then carries each shard's DISTINCT rows once
-    (``dk``/``dv``/lens, D rows per shard) plus a byte-wide rank per
-    (wave, shard) (``idx``) — for the BASELINE config-5 workload this
-    is ~10x fewer upload bytes than the row-packed form (whether the
-    host-side dictionary build pays for itself at the attached chip's
-    upload rate is not yet measured). Reference idea being extended: the serialization layer's
-    compression threshold (rabia-core/src/serialization.rs:100-114),
-    applied semantically to the device plane.
-    """
-
-    idx: np.ndarray  # u8[W, S] within-shard dictionary rank
-    dkl: np.ndarray  # i16[S, D] key lengths
-    dvl: np.ndarray  # i16[S, D] value lengths
-    dk: np.ndarray  # u32[S, D, Ku/4] key words
-    dv: np.ndarray  # u32[S, D, VWu/4] value words
-
-
-def _pad_dict_idx(ops: DeviceDictOps, W: int) -> DeviceDictOps:
-    """Pad the per-(wave, shard) rank plane to the static window size.
-
-    Pad waves carry rank 0; that aliases a real dictionary row, but
-    every consumer gates on the in-program depth mask (pad waves are
-    not ``present``), so the expanded row is never applied or matched.
-    Shared by all three dict dispatch paths so the pad semantics cannot
-    diverge."""
-    if ops.idx.shape[0] < W:
-        pad = W - ops.idx.shape[0]
-        ops = ops._replace(
-            idx=np.concatenate(
-                [ops.idx, np.zeros((pad, ops.idx.shape[1]), np.uint8)]
-            )
-        )
-    return ops
 
 
 def _get_frame(found: bool, ver: int, val: bytes) -> bytes:
@@ -463,10 +385,6 @@ class DeviceKVTable:
         # host bytes device_put for window dispatches, ever (the engine's
         # devkv_upload_bytes_total reads it)
         self.upload_bytes = 0
-        # dictionary attempts by outcome, ever (the engine's
-        # devkv_dict_attempts_total reads it): "rejected" is the full
-        # path's D > max_dict or a failed verification
-        self.dict_attempts = {"built": 0, "probe_rejected": 0, "rejected": 0}
         # table rows materialized on the host by dump(), ever (the
         # engine's devkv_sync_rows_total reads it)
         self.sync_rows = 0
@@ -700,162 +618,17 @@ class DeviceKVTable:
         g = self._gather_window(blocks, "set")
         if g is None:
             return None
-        return self._rows_from_gathered(g)
+        return self._window_ops(g)
 
-    @staticmethod
-    def _rows_from_gathered(g: tuple) -> DeviceWindowOps:
-        _kind, klen_w, vlen_w, kwin_w, vwin_w = g
-        return DeviceWindowOps(
-            klen_w,
-            vlen_w,
-            np.ascontiguousarray(kwin_w).view(np.uint32),
-            np.ascontiguousarray(vwin_w).view(np.uint32),
-        )
-
-    def pack_window_dict(
-        self, blocks, max_dict: int = 32
-    ) -> Optional[DeviceDictOps]:
-        """Dictionary-compress a SET window: per-shard distinct
-        (key, value) rows + a rank per (wave, shard).
-
-        Vectorized per-shard uniqueness via a 64-bit universal hash
-        with FULL verification — every op row is compared bytewise
-        against the dictionary row its rank points to, so a hash
-        collision (or more than ``max_dict`` distinct rows per shard)
-        returns None and the caller falls back to the row-packed
-        upload. Correctness never rides on the hash."""
-        g = self._gather_window(blocks, "set")
+    def pack_get_window(self, blocks) -> Optional[tuple]:
+        """Pack GET-only ``blocks`` into the lookup programs' inputs,
+        ``(klen i16[W, S], kwin u32[W, S, Ku/4])``; None when outside
+        the read envelope — the caller demotes."""
+        g = self._gather_window(blocks, "get")
         if g is None:
             return None
-        return self._dict_from_gathered(g, max_dict)
-
-    def _dict_from_gathered(
-        self, g: tuple, max_dict: int = 32
-    ) -> Optional[DeviceDictOps]:
-        with device_annotation("rabia.cycle.pack.dict"):
-            return self._dict_rows(g, max_dict)
-
-    def _dict_rows(self, g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
-        """The window's dictionary form, or None when it has none:
-        the probe first, then the full path, counted by outcome."""
-        if self._dict_probe_rejects(g, max_dict):
-            self.dict_attempts["probe_rejected"] += 1
-            return None
-        d = self._dict_full(g, max_dict)
-        self.dict_attempts["rejected" if d is None else "built"] += 1
-        return d
-
-    def _dict_probe_rejects(self, g: tuple, max_dict: int) -> bool:
-        """Exact early rejection, before any pass over the whole
-        window: D is the maximum over shards of a shard's distinct
-        rows, and a shard's distinct-hash count is what the full path
-        would read for it, so one probed shard over ``max_dict`` settles
-        that the full path would return None. Reads a few real shard
-        columns, evenly strided; never the zero padding of the S axis."""
-        _kind, klen_w, vlen_w, kwin_w, vwin_w = g
-        if klen_w.shape[0] <= max_dict:
-            return False  # a shard has at most W distinct rows
-        k = min(self.n_shards, _PROBE_SHARDS)
-        cols = np.arange(k) * self.n_shards // k
-        h = _row_hash(
-            klen_w[:, cols], vlen_w[:, cols], kwin_w[:, cols], vwin_w[:, cols]
-        )  # [W, k]
-        h.sort(axis=0)
-        distinct = 1 + (h[1:] != h[:-1]).sum(axis=0)
-        return bool((distinct > max_dict).any())
-
-    @staticmethod
-    def _dict_full(g: tuple, max_dict: int) -> Optional[DeviceDictOps]:
-        _kind, klen_w, vlen_w, kwin_w, vwin_w = g
-        W, S = klen_w.shape
-        ku = kwin_w.shape[2]
-        vu = vwin_w.shape[2]
-        # 64-bit row hash: fold the byte planes as native u32/u64 WORDS
-        # (widths are powers of two, so the views are exact) — no
-        # [W,S,B]->u64 astype, no matmul; per-SHARD uniqueness via
-        # axis-1 sorts over the W window positions — O(S * W log W) on
-        # short rows instead of a global (S*W)-row lexsort. Both were
-        # the dominant pack costs at W=128.
-        h = _row_hash(klen_w, vlen_w, kwin_w, vwin_w)
-        if bool((h == h[:1]).all()):
-            # every wave repeats its shard's single row (the steady
-            # state of uniform workloads): D=1 with wave 0 as the
-            # representative, no per-shard sort — the argsort was the
-            # dominant dict-build cost once the gather went native.
-            # Verification below is the same full byte compare the
-            # sorted path runs; the hash is still never trusted.
-            ok = (
-                (klen_w == klen_w[:1]).all()
-                and (vlen_w == vlen_w[:1]).all()
-                and (kwin_w == kwin_w[:1]).all()
-                and (vwin_w == vwin_w[:1]).all()
-            )
-            if not bool(ok):
-                return None
-            # explicit copies: contiguous row views would alias (and
-            # pin) the full [W, S, *] gather planes for as long as the
-            # window is in flight — W times the bytes actually needed
-            return DeviceDictOps(
-                np.zeros((W, S), np.uint8),
-                klen_w[:1].T.copy(),
-                vlen_w[:1].T.copy(),
-                kwin_w[0][:, None].copy().view(np.uint32),
-                vwin_w[0][:, None].copy().view(np.uint32),
-            )
-        h = np.ascontiguousarray(h.T)  # [S, W]
-        o = np.argsort(h, axis=1, kind="stable")
-        hs = np.take_along_axis(h, o, axis=1)
-        new = np.ones((S, W), bool)
-        new[:, 1:] = hs[:, 1:] != hs[:, :-1]
-        rank_sorted = np.cumsum(new, axis=1) - 1  # [S, W]
-        D = int(rank_sorted[:, -1].max()) + 1
-        if D > max_dict:
-            return None
-        rank = np.empty((S, W), np.int64)
-        np.put_along_axis(rank, o, rank_sorted, axis=1)
-        # representative wave per (shard, dict row): first occurrence
-        rep_t = np.zeros((S, D), np.int64)
-        s_new, pos_new = np.nonzero(new)
-        rep_t[s_new, rank_sorted[s_new, pos_new]] = o[s_new, pos_new]
-        s_cols = np.arange(S)[:, None]
-        dkl = klen_w[rep_t, s_cols]  # [S, D]
-        dvl = vlen_w[rep_t, s_cols]
-        dkb = kwin_w[rep_t, s_cols]  # [S, D, ku]
-        dvb = vwin_w[rep_t, s_cols]
-        # hash verification: every op's bytes must equal its dictionary
-        # row's bytes — a collision (2^-64, or adversarial) falls back
-        # to the row-packed upload; correctness never rides on the hash
-        rank_ts = rank.T  # [W, S]
-        # (D == 1 can't reach here: the all-equal pre-check above
-        # returned before the argsort in that case)
-        sc = np.arange(S)[None, :]
-        ok = (
-            (klen_w == dkl[sc, rank_ts]).all()
-            and (vlen_w == dvl[sc, rank_ts]).all()
-            and (kwin_w == dkb[sc, rank_ts]).all()
-            and (vwin_w == dvb[sc, rank_ts]).all()
-        )
-        if not bool(ok):
-            return None
-        return DeviceDictOps(
-            np.ascontiguousarray(rank_ts.astype(np.uint8)),
-            np.ascontiguousarray(dkl),
-            np.ascontiguousarray(dvl),
-            np.ascontiguousarray(dkb).view(np.uint32),
-            np.ascontiguousarray(dvb).view(np.uint32),
-        )
-
-    def pack_window_auto(self, blocks):
-        """Dictionary-compressed SET window when the stream repeats
-        enough to pay off, else the row-packed form; None demotes.
-        One gather pass serves both attempts."""
-        g = self._gather_window(blocks, "set")
-        if g is None:
-            return None
-        d = self._dict_from_gathered(g)
-        if d is not None:
-            return d
-        return self._rows_from_gathered(g)
+        ops = self._window_ops(g)
+        return ops.klen, ops.kwin
 
     def pack_mixed_window(self, blocks) -> Optional[tuple]:
         """Pack blocks whose ops are ANY interleaving of binary SET and
@@ -867,61 +640,29 @@ class DeviceKVTable:
         This removes the FIFO kind-boundary splits: an interleaved
         SET/GET workload runs full windows instead of
         window-per-kind-run (reference applies a mixed batch in one
-        pass too: rabia-kvstore/src/store.rs:313-348)."""
+        pass too: rabia-kvstore/src/store.rs:313-348). ``ops.vlen`` and
+        ``ops.vwin`` are the per-wave value planes the engine's host
+        value segments keep."""
         g = self._gather_window(blocks, "mixed")
         if g is None:
             return None
-        kind_w, klen_w, vlen_w, kwin_w, vwin_w = g
-        return kind_w, DeviceWindowOps(
+        return g[0], self._window_ops(g)
+
+    @staticmethod
+    def _window_ops(g: tuple) -> DeviceWindowOps:
+        _kind, klen_w, vlen_w, kwin_w, vwin_w = g
+        return DeviceWindowOps(
             klen_w,
             vlen_w,
             np.ascontiguousarray(kwin_w).view(np.uint32),
             np.ascontiguousarray(vwin_w).view(np.uint32),
         )
 
-    def pack_mixed_window_auto(self, blocks) -> Optional[tuple]:
-        """Mixed window with the dictionary-compressed upload when the
-        stream repeats enough to pay off, else row-packed; None demotes.
-
-        Returns ``(kind, ops, vlen_plane, vwin_plane)`` where ``ops``
-        is :class:`DeviceDictOps` or :class:`DeviceWindowOps` and the
-        two planes are the FULL per-wave value planes — the engine's
-        host-side value segments need them regardless of how the ops
-        were uploaded (a GET answers from (shard, version) →
-        bytes, which only the uncompressed planes provide). One gather
-        pass serves the dict attempt, the row fallback, and the
-        segment planes."""
-        g = self._gather_window(blocks, "mixed")
-        if g is None:
-            return None
-        kind_w, klen_w, vlen_w, kwin_w, vwin_w = g
-        vwin_u32 = np.ascontiguousarray(vwin_w).view(np.uint32)
-        d = self._dict_from_gathered(g)
-        if d is not None:
-            return kind_w, d, vlen_w, vwin_u32
-        return (
-            kind_w,
-            DeviceWindowOps(
-                klen_w,
-                vlen_w,
-                np.ascontiguousarray(kwin_w).view(np.uint32),
-                vwin_u32,
-            ),
-            vlen_w,
-            vwin_u32,
-        )
-
     # -- the fused programs --------------------------------------------------
 
     def _place_ops(self, ops):
-        """Upload one packed window (either op form) with the shard-axis
-        placement: per-wave planes ``[W, S, ...]`` and per-shard
-        dictionary planes ``[S, D, ...]`` each land split over S."""
-        if isinstance(ops, DeviceDictOps):
-            return DeviceDictOps(
-                self._put_waves(ops.idx),
-                *(self._put_shards(a) for a in ops[1:]),
-            )
+        """Upload one packed window with the shard-axis placement: the
+        per-wave planes ``[W, S, ...]`` each land split over S."""
         return DeviceWindowOps(*(self._put_waves(a) for a in ops))
 
     def _placing(self, *operands):
@@ -951,11 +692,9 @@ class DeviceKVTable:
             return device_annotation("rabia.jit.first_call", sig=str(key))
         return device_annotation("rabia.dispatch.call")
 
-    def _build_lookup(self, Ku4: int, D: Optional[int] = None):
+    def _build_lookup(self, Ku4: int):
         """Jitted GET window: consensus slot window + a read-only match
-        over the table (no state mutation, no version advance). ``D``
-        selects the dictionary-upload variant (per-shard distinct keys
-        + a rank per (wave, shard), expanded on device)."""
+        over the table (no state mutation, no version advance)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -981,7 +720,8 @@ class DeviceKVTable:
                 )
                 all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
-            def match_body(klen_w, kwin_w):
+            def wave_match(_, inp):
+                klen_w, kwin_w = inp
                 with jax.named_scope("key_match"):
                     klen_w = klen_w.astype(jnp.int32)
                     eq = (
@@ -995,97 +735,29 @@ class DeviceKVTable:
                     rver = (ver * oh).sum(1)
                     rvlen = (vlen * oh).sum(1)
                     rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
-                return found, rver, rvlen, rval
+                return None, (found, rver, rvlen, rval)
 
-            if D is None:
-                kwin_full = jnp.pad(
-                    kwin_t, ((0, 0), (0, 0), (0, K4 - Ku4))
-                )
-                xs = (klen_t, kwin_full)
-
-                def wave_match(_, inp):
-                    return None, match_body(*inp)
-            else:
-                # dictionary upload: klen_t is (idx, dkl, dk) — the key
-                # dictionary only, value planes are never uploaded
-                # here; expand each wave's per-shard rank into the
-                # shard's distinct key row (GET streams repeat keys
-                # like SET streams repeat rows)
-                idx, dkl_raw, dk_raw = klen_t
-                dk_full = jnp.pad(dk_raw, ((0, 0), (0, 0), (0, K4 - Ku4)))
-                dkl = dkl_raw.astype(I32)
-                dr = jnp.arange(D, dtype=I32)[None, :]
-                xs = (idx,)
-
-                def wave_match(_, inp):
-                    (idx_w,) = inp
-                    oh = idx_w.astype(I32)[:, None] == dr  # [S, D]
-                    ohu = oh.astype(jnp.uint32)[:, :, None]
-                    return None, match_body(
-                        (dkl * oh).sum(1), (dk_full * ohu).sum(1)
-                    )
-
-            _, (found, rver, rvlen, rval) = lax.scan(wave_match, None, xs)
+            kwin_full = jnp.pad(kwin_t, ((0, 0), (0, 0), (0, K4 - Ku4)))
+            _, (found, rver, rvlen, rval) = lax.scan(
+                wave_match, None, (klen_t, kwin_full)
+            )
             return all_v1.astype(I32), found, rver, rvlen, rval
 
         return jax.jit(lookup, static_argnames=("W", "max_phases"))
-
-    def pack_get_window_auto(self, blocks):
-        """GET window with the dictionary-compressed upload when the key
-        stream repeats enough, else the row-packed ``(klen, kwin)``
-        pair; None demotes. One gather pass serves both attempts."""
-        g = self._gather_window(blocks, "get")
-        if g is None:
-            return None
-        d = self._dict_from_gathered(g)
-        if d is not None:
-            return d
-        _kind, klen_w, _vlen, kwin_w, _vwin = g
-        return klen_w, np.ascontiguousarray(kwin_w).view(np.uint32)
 
     def lookup_window(self, alive, base, depth: int, ops, W: int,
                       max_phases: int = 4, state=None):
         """Dispatch one consensus+lookup window against the CURRENT
         table (read-only; ``state`` overrides it so the pipelined lane
-        can chain on an in-flight window's output). ``ops`` is either a
-        row-packed ``(klen i16[W,S], kwin u32[W,S,Ku4])`` pair or a
-        :class:`DeviceDictOps` (key dictionary; value planes unused).
-        Returns DEVICE handles
+        can chain on an in-flight window's output). ``ops`` is
+        :meth:`pack_get_window`'s ``(klen i16[W,S], kwin u32[W,S,Ku4])``
+        pair. Returns DEVICE handles
         ``(all_v1, found[W,S], ver[W,S], vlen[W,S], val_words[W,S,VW4])``
         — the caller fetches selectively: found+ver are ~5 bytes/op;
         the value planes (~70 bytes/op) only need to be downloaded
         when a version cannot be resolved from the host-side value
         segments (see mesh_engine._dev_resolve), which is the eviction
         edge case, not the steady state."""
-        if isinstance(ops, DeviceDictOps):
-            ops = _pad_dict_idx(ops, W)
-            D = ops.dkl.shape[1]
-            key = ("getdict", W, ops.dk.shape[2], D)
-            fn = self._program(
-                key, lambda: self._build_lookup(key[2], D)
-            )
-            # only the key dictionary is uploaded: the lookup
-            # never reads values, and uploading the dead dv plane would
-            # cost as much as the keys themselves at D=32
-            with self._placing(alive, base, ops.idx, ops.dkl, ops.dk):
-                alive_d = self.kernel.place(alive)
-                base_d = self._put_shards(base)
-                kdict = (
-                    self._put_waves(ops.idx),
-                    self._put_shards(ops.dkl),
-                    self._put_shards(ops.dk),
-                )
-            with self._calling(key):
-                return fn(
-                    self.state if state is None else state,
-                    alive_d,
-                    base_d,
-                    np.int32(depth),
-                    kdict,
-                    None,
-                    W=W,
-                    max_phases=max_phases,
-                )
         klen, kwin = ops
         if klen.shape[0] < W:
             pad = W - klen.shape[0]
@@ -1114,7 +786,7 @@ class DeviceKVTable:
                 max_phases=max_phases,
             )
 
-    def _build_lookup_only(self, Ku4: int, D: Optional[int] = None):
+    def _build_lookup_only(self, Ku4: int):
         """Jitted CONSENSUS-FREE read window: the same read-only match
         scan as :meth:`_build_lookup`, with the slot window removed
         entirely — no votes, no phases, no collective. The read-index
@@ -1129,12 +801,12 @@ class DeviceKVTable:
         from jax import lax
 
         K4 = self.K4
-        I32 = jnp.int32
 
         def lookup_only(state, klen_t, kwin_t, *, W):
             used, keyw, klen, ver, valw, vlen, _sver = state
 
-            def match_body(klen_w, kwin_w):
+            def wave_match(_, inp):
+                klen_w, kwin_w = inp
                 with jax.named_scope("key_match"):
                     klen_w = klen_w.astype(jnp.int32)
                     eq = (
@@ -1148,32 +820,12 @@ class DeviceKVTable:
                     rver = (ver * oh).sum(1)
                     rvlen = (vlen * oh).sum(1)
                     rval = (valw * oh[:, :, None]).sum(1)  # [S, VW4] u32
-                return found, rver, rvlen, rval
+                return None, (found, rver, rvlen, rval)
 
-            if D is None:
-                kwin_full = jnp.pad(
-                    kwin_t, ((0, 0), (0, 0), (0, K4 - Ku4))
-                )
-                xs = (klen_t, kwin_full)
-
-                def wave_match(_, inp):
-                    return None, match_body(*inp)
-            else:
-                idx, dkl_raw, dk_raw = klen_t
-                dk_full = jnp.pad(dk_raw, ((0, 0), (0, 0), (0, K4 - Ku4)))
-                dkl = dkl_raw.astype(I32)
-                dr = jnp.arange(D, dtype=I32)[None, :]
-                xs = (idx,)
-
-                def wave_match(_, inp):
-                    (idx_w,) = inp
-                    oh = idx_w.astype(I32)[:, None] == dr  # [S, D]
-                    ohu = oh.astype(jnp.uint32)[:, :, None]
-                    return None, match_body(
-                        (dkl * oh).sum(1), (dk_full * ohu).sum(1)
-                    )
-
-            _, (found, rver, rvlen, rval) = lax.scan(wave_match, None, xs)
+            kwin_full = jnp.pad(kwin_t, ((0, 0), (0, 0), (0, K4 - Ku4)))
+            _, (found, rver, rvlen, rval) = lax.scan(
+                wave_match, None, (klen_t, kwin_full)
+            )
             return found, rver, rvlen, rval
 
         return jax.jit(lookup_only, static_argnames=("W",))
@@ -1181,34 +833,12 @@ class DeviceKVTable:
     def lookup_only(self, ops, W: int, state=None):
         """Dispatch one consensus-free read window (the read-index
         lane's probe serve): ``ops`` exactly as :meth:`lookup_window`
-        takes them (row-packed ``(klen, kwin)`` or a
-        :class:`DeviceDictOps`), padded to the static window size ``W``
+        takes them, padded to the static window size ``W``
         (padding waves carry klen 0 and match nothing). Returns DEVICE
         handles ``(found[W,S], ver[W,S], vlen[W,S], val_words)`` — no
         all_v1 scalar, because nothing was decided. The caller fetches
         meta-only in the steady state, exactly like the slot-consuming
         GET window."""
-
-        if isinstance(ops, DeviceDictOps):
-            ops = _pad_dict_idx(ops, W)
-            D = ops.dkl.shape[1]
-            key = ("rodict", W, ops.dk.shape[2], D)
-            fn = self._program(
-                key, lambda: self._build_lookup_only(key[2], D)
-            )
-            with self._placing(ops.idx, ops.dkl, ops.dk):
-                kdict = (
-                    self._put_waves(ops.idx),
-                    self._put_shards(ops.dkl),
-                    self._put_shards(ops.dk),
-                )
-            with self._calling(key):
-                return fn(
-                    self.state if state is None else state,
-                    kdict,
-                    None,
-                    W=W,
-                )
         klen, kwin = ops
         if klen.shape[0] < W:
             pad = W - klen.shape[0]
@@ -1235,8 +865,7 @@ class DeviceKVTable:
 
     @staticmethod
     def _apply_set_wave(carry, ok_w, klen_t, vlen_t, kwin_t, vwin_t, Pc):
-        """One SET wave over the table state — shared by the row-packed
-        and dictionary-packed fused programs.
+        """One SET wave over the table state.
 
         Match: word compare against all P slots of the shard; stored
         tails beyond the op key are zero, as are the padded op words,
@@ -1274,70 +903,6 @@ class DeviceKVTable:
             vlen = jnp.where(onehot, vlen_t[:, None], vlen)
             sver = jnp.where(apply, new_ver, sver)
         return (used, keyw, klen, ver, valw, vlen, sver), overflow
-
-    def _build_fused_dict(self, Ku4: int, VWu4: int, D: int):
-        """Jitted SET window on DICTIONARY-compressed ops: each wave
-        expands its (wave, shard) rank into the shard's dictionary row
-        with a one-hot select (D is small), then applies the shared
-        SET wave body — upload bytes shrink ~10x on repetitive
-        (Zipf) streams, the table math is unchanged."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        kernel = self.kernel
-        S, Pc = self.S, self.P
-        K4, VW4 = self.K4, self.VW4
-        n = self.n_shards
-        I8, I32 = jnp.int8, jnp.int32
-        col = jnp.arange(S) < n
-
-        def fused(state, alive, base, depth, ops, *, W, max_phases):
-            with jax.named_scope("consensus"):
-                wave = jnp.arange(W, dtype=I32)[:, None] < depth
-                present = wave & col[None, :]
-                votes = jnp.where(
-                    present[:, :, None], I8(V1), I8(V0)
-                ) * jnp.ones((1, 1, kernel.R), I8)
-                decided = kernel.slot_window(
-                    votes, alive, base, n_slots=W, max_phases=max_phases
-                )
-                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
-
-            dk_full = jnp.pad(ops.dk, ((0, 0), (0, 0), (0, K4 - Ku4)))
-            dv_full = jnp.pad(ops.dv, ((0, 0), (0, 0), (0, VW4 - VWu4)))
-            dkl = ops.dkl.astype(I32)
-            dvl = ops.dvl.astype(I32)
-            dr = jnp.arange(D, dtype=I32)[None, :]
-
-            def wave_step(carry, inp):
-                ok_w, idx_w = inp
-                oh = idx_w.astype(I32)[:, None] == dr  # [S, D]
-                ohu = oh.astype(jnp.uint32)[:, :, None]
-                klen_t = (dkl * oh).sum(1)
-                vlen_t = (dvl * oh).sum(1)
-                kwin_t = (dk_full * ohu).sum(1)
-                vwin_t = (dv_full * ohu).sum(1)
-                return DeviceKVTable._apply_set_wave(
-                    carry, ok_w, klen_t, vlen_t, kwin_t, vwin_t, Pc
-                )
-
-            new_state, over_w = lax.scan(
-                wave_step, state, (present, ops.idx)
-            )
-            with jax.named_scope("flags"):
-                flags = jnp.stack(
-                    [
-                        all_v1.astype(I32),
-                        jnp.any(over_w).astype(I32),
-                        jnp.any(
-                            new_state[6] >= jnp.int32(2**31 - 2)
-                        ).astype(I32),
-                    ]
-                )
-            return new_state, flags
-
-        return jax.jit(fused, static_argnames=("W", "max_phases"))
 
     def _build_fused(self, Ku4: int, VWu4: int):
         import jax
@@ -1411,14 +976,7 @@ class DeviceKVTable:
         The caller ADOPTS ``new_state`` only when the flags are clean
         (and then derives version responses from its host-side counter
         mirror); otherwise it keeps the old state object (purely
-        functional program — nothing was donated) and demotes.
-        Accepts row-packed (:class:`DeviceWindowOps`) or
-        dictionary-packed (:class:`DeviceDictOps`) windows."""
-
-        if isinstance(ops, DeviceDictOps):
-            return self._decide_apply_dict(
-                alive, base, depth, ops, W, max_phases, state
-            )
+        functional program — nothing was donated) and demotes."""
         if ops.klen.shape[0] < W:
             # pack_window covers only the depth in-flight waves; pad to
             # the static window size (filler waves are masked out by the
@@ -1433,15 +991,7 @@ class DeviceKVTable:
                 )
             )
         key = (W, ops.kwin.shape[2], ops.vwin.shape[2])
-        return self._dispatch_set(
-            key, lambda: self._build_fused(key[1], key[2]),
-            alive, base, depth, ops, W, max_phases, state,
-        )
-
-    def _dispatch_set(self, key, build, alive, base, depth, ops, W,
-                      max_phases, state):
-        """Place and call one SET window program (either op form)."""
-        fn = self._program(key, build)
+        fn = self._program(key, lambda: self._build_fused(key[1], key[2]))
         with self._placing(alive, base, *ops):
             alive_d = self.kernel.place(alive)
             base_d = self._put_shards(base)
@@ -1457,8 +1007,7 @@ class DeviceKVTable:
                 max_phases=max_phases,
             )
 
-    def _build_mixed(self, Ku4: int, VWu4: int, Gp: int,
-                     D: Optional[int] = None):
+    def _build_mixed(self, Ku4: int, VWu4: int, Gp: int):
         """Jitted MIXED window: consensus + per-op kind mask over the
         same table — SET ops mutate (identical update rules to
         :meth:`_build_fused`), GET ops read the wave-entry state (reads
@@ -1469,13 +1018,7 @@ class DeviceKVTable:
         program gathers those waves' lookup outputs ON DEVICE (the host
         knows the wave indices at pack time) and packs found/ver/vlen
         into one two-plane i32 tensor, so the readback is two transfers,
-        not four take-dispatch round trips.
-
-        ``D`` selects the DICTIONARY-compressed upload variant: ops
-        arrive as per-shard distinct rows + a rank per (wave, shard)
-        (:class:`DeviceDictOps` — GET ops are (key, empty value) rows),
-        expanded on device exactly like the pure-SET dict program. Same
-        table math either way; only the upload shape differs."""
+        not four take-dispatch round trips."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -1500,8 +1043,8 @@ class DeviceKVTable:
                 )
                 all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
-            def step_body(carry, ok_w, kind_t, klen_t, vlen_t, kwin_t,
-                          vwin_t):
+            def wave_step(carry, inp):
+                ok_w, kind_t, klen_t, vlen_t, kwin_t, vwin_t = inp
                 used, keyw, klen, ver, valw, vlen, sver = carry
                 klen_t = klen_t.astype(jnp.int32)
                 vlen_t = vlen_t.astype(jnp.int32)
@@ -1562,52 +1105,9 @@ class DeviceKVTable:
                     gval,
                 )
 
-            if D is None:
-                # row-packed: per-wave planes uploaded directly
-                kwin_full = jnp.pad(
-                    ops.kwin, ((0, 0), (0, 0), (0, K4 - Ku4))
-                )
-                vwin_full = jnp.pad(
-                    ops.vwin, ((0, 0), (0, 0), (0, VW4 - VWu4))
-                )
-                xs = (
-                    present, kind_w, ops.klen, ops.vlen, kwin_full,
-                    vwin_full,
-                )
-
-                def wave_step(carry, inp):
-                    ok_w, kind_t, klen_t, vlen_t, kwin_t, vwin_t = inp
-                    return step_body(
-                        carry, ok_w, kind_t, klen_t, vlen_t, kwin_t, vwin_t
-                    )
-            else:
-                # dictionary-packed: expand each wave's per-shard rank
-                # into the shard's dictionary row (same one-hot select
-                # as the pure-SET dict program — GET rows are just
-                # (key, empty value) dictionary entries)
-                dk_full = jnp.pad(ops.dk, ((0, 0), (0, 0), (0, K4 - Ku4)))
-                dv_full = jnp.pad(
-                    ops.dv, ((0, 0), (0, 0), (0, VW4 - VWu4))
-                )
-                dkl = ops.dkl.astype(I32)
-                dvl = ops.dvl.astype(I32)
-                dr = jnp.arange(D, dtype=I32)[None, :]
-                xs = (present, kind_w, ops.idx)
-
-                def wave_step(carry, inp):
-                    ok_w, kind_t, idx_w = inp
-                    oh = idx_w.astype(I32)[:, None] == dr  # [S, D]
-                    ohu = oh.astype(jnp.uint32)[:, :, None]
-                    return step_body(
-                        carry,
-                        ok_w,
-                        kind_t,
-                        (dkl * oh).sum(1),
-                        (dvl * oh).sum(1),
-                        (dk_full * ohu).sum(1),
-                        (dv_full * ohu).sum(1),
-                    )
-
+            kwin_full = jnp.pad(ops.kwin, ((0, 0), (0, 0), (0, K4 - Ku4)))
+            vwin_full = jnp.pad(ops.vwin, ((0, 0), (0, 0), (0, VW4 - VWu4)))
+            xs = (present, kind_w, ops.klen, ops.vlen, kwin_full, vwin_full)
             new_state, (over_w, gfound, gver, gvlen, gval) = lax.scan(
                 wave_step, state, xs
             )
@@ -1634,8 +1134,7 @@ class DeviceKVTable:
         return jax.jit(mixed, static_argnames=("W", "max_phases"))
 
     def mixed_apply(self, alive, base, depth: int, kind: np.ndarray,
-                    get_waves: np.ndarray,
-                    ops: Union[DeviceWindowOps, DeviceDictOps], W: int,
+                    get_waves: np.ndarray, ops: DeviceWindowOps, W: int,
                     max_phases: int = 4, state=None):
         """Dispatch one mixed decide+apply+lookup window. Returns device
         handles ``(new_state, flags, meta, gval)`` where ``meta`` is
@@ -1646,11 +1145,7 @@ class DeviceKVTable:
         window. ``state`` overrides the table state to run against (the
         pipelined lane chains on the previous in-flight window's
         unresolved output, same as :meth:`decide_apply`)."""
-
-        is_dict = isinstance(ops, DeviceDictOps)
-        if is_dict:
-            ops = _pad_dict_idx(ops, W)
-        elif ops.klen.shape[0] < W:
+        if ops.klen.shape[0] < W:
             pad = W - ops.klen.shape[0]
             ops = DeviceWindowOps(
                 *(
@@ -1669,14 +1164,10 @@ class DeviceKVTable:
             Gp <<= 1
         gidx = np.zeros(Gp, np.int32)
         gidx[: len(get_waves)] = get_waves
-        if is_dict:
-            D = ops.dkl.shape[1]
-            key = ("mixdict", W, ops.dk.shape[2], ops.dv.shape[2], Gp, D)
-            build = lambda: self._build_mixed(key[2], key[3], Gp, D)
-        else:
-            key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
-            build = lambda: self._build_mixed(key[2], key[3], Gp)
-        fn = self._program(key, build)
+        key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
+        fn = self._program(
+            key, lambda: self._build_mixed(key[2], key[3], Gp)
+        )
         with self._placing(alive, base, kind, *ops):
             alive_d = self.kernel.place(alive)
             base_d = self._put_shards(base)
@@ -1694,16 +1185,6 @@ class DeviceKVTable:
                 W=W,
                 max_phases=max_phases,
             )
-
-    def _decide_apply_dict(self, alive, base, depth, ops: DeviceDictOps,
-                           W: int, max_phases: int, state=None):
-        ops = _pad_dict_idx(ops, W)
-        D = ops.dkl.shape[1]
-        key = ("dictset", W, ops.dk.shape[2], ops.dv.shape[2], D)
-        return self._dispatch_set(
-            key, lambda: self._build_fused_dict(key[2], key[3], D),
-            alive, base, depth, ops, W, max_phases, state,
-        )
 
     def adopt(self, new_state) -> None:
         self.state = new_state

@@ -96,27 +96,6 @@ class _RowSeg:
         return self.vwin8[t, s, : int(self.vlen[t, s])].tobytes()
 
 
-class _DictSeg:
-    """Value segment for a dict-packed SET window: the op's value is
-    the dictionary row its wave indexed."""
-
-    __slots__ = ("start", "end", "idx", "dvl", "dv8", "nbytes", "provisional")
-
-    def __init__(self, start, end, idx, dvl, dv) -> None:
-        self.start = start
-        self.end = end
-        self.idx = idx  # [W, S] within-shard dictionary rank
-        self.dvl = dvl  # i16[S, D]
-        self.dv8 = dv.view(np.uint8)  # u8[S, D, vu]
-        self.nbytes = idx.nbytes + dvl.nbytes + self.dv8.nbytes
-        self.provisional = False
-
-    def value(self, s: int, ver: int) -> Optional[bytes]:
-        t = ver - int(self.start[s]) - 1
-        j = int(self.idx[t, s])
-        return self.dv8[s, j, : int(self.dvl[s, j])].tobytes()
-
-
 class _MixedSeg:
     """Value segment for a mixed window: per-(wave, shard) derived
     versions locate the SET wave by binary search (``svers`` columns
@@ -508,21 +487,6 @@ class MeshEngine:
             "bytes= of the rabia.dispatch.place spans)",
             fn=lambda: self._dev.upload_bytes if self._dev is not None else 0,
         )
-        for _outcome in ("built", "probe_rejected", "rejected"):
-            m.counter(
-                "devkv_dict_attempts_total",
-                "Dictionary-upload attempts of the window packers by "
-                "outcome: built, probe_rejected = a probed shard holds "
-                "over max_dict distinct rows (decided before the whole "
-                "window is hashed), rejected = the full path's count or "
-                "its byte verification",
-                {"outcome": _outcome},
-                fn=(
-                    lambda o=_outcome: self._dev.dict_attempts[o]
-                    if self._dev is not None
-                    else 0
-                ),
-            )
         for _outcome in ("reused", "fresh"):
             m.counter(
                 "devkv_pack_buffers_total",
@@ -1116,7 +1080,6 @@ class MeshEngine:
         overflow, a fault) demotes to the host path — state is adopted
         only on a clean all-V1 window, so demotion always re-runs from a
         consistent table."""
-        from rabia_tpu.apps.device_kv import DeviceDictOps
         from rabia_tpu.apps.vector_kv import FrameGroups, VectorShardedKV
 
         W = self.window
@@ -1153,7 +1116,7 @@ class MeshEngine:
             return self._run_cycle_fullwidth_device_get(depth)
         entries = [self._full_blocks[i] for i in range(depth)]  # peek
         with device_annotation("rabia.cycle.pack"):
-            ops = self._dev.pack_window_auto([e[0] for e in entries])
+            ops = self._dev.pack_window([e[0] for e in entries])
         if ops is None:
             applied = self._dev_drain_pipe()
             self._demote_device_store()
@@ -1206,10 +1169,7 @@ class MeshEngine:
                 seg_start = self._dev_sver.copy()
                 seg_end = seg_start.copy()
                 seg_end[:n] += depth
-            if isinstance(ops, DeviceDictOps):
-                seg = _DictSeg(seg_start, seg_end, ops.idx, ops.dvl, ops.dv)
-            else:
-                seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
+            seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
             if deferred:
                 seg.provisional = True
                 self._dev_defer += 1
@@ -1627,7 +1587,7 @@ class MeshEngine:
         if not batch:
             return 0
         with device_annotation("rabia.cycle.pack"):
-            packed = self._dev.pack_get_window_auto([e[0] for e in batch])
+            packed = self._dev.pack_get_window([e[0] for e in batch])
         if packed is None:
             # outside the read envelope (long key, malformed op): put
             # the batch back and demote — the flush below hands every
@@ -1695,7 +1655,7 @@ class MeshEngine:
         n = self.n_shards
         entries = [self._full_blocks[i] for i in range(depth)]
         with device_annotation("rabia.cycle.pack"):
-            packed = self._dev.pack_get_window_auto([e[0] for e in entries])
+            packed = self._dev.pack_get_window([e[0] for e in entries])
         if packed is None:
             # drain BEFORE demoting so in-flight windows' applied counts
             # reach the caller (demote's internal drain discards them)
@@ -1758,16 +1718,14 @@ class MeshEngine:
         n = self.n_shards
         entries = [self._full_blocks[i] for i in range(count)]
         with device_annotation("rabia.cycle.pack"):
-            packed = self._dev.pack_mixed_window_auto(
-                [e[0] for e in entries]
-            )
+            packed = self._dev.pack_mixed_window([e[0] for e in entries])
         if packed is None:
             # drain BEFORE demoting so in-flight windows' applied counts
             # reach the caller (demote's internal drain discards them)
             applied = self._dev_drain_pipe()
             self._demote_device_store()
             return applied + self._run_cycle_inner()
-        kind, ops, vlen_plane, vwin_plane = packed
+        kind, ops = packed
         # DEL bumps the shard version only when the key is FOUND — a
         # data-dependent bump the host mirror can't derive until the
         # meta readback (which DEL waves already ride: kind >= 2). Such
@@ -1812,7 +1770,7 @@ class MeshEngine:
                 seg = _MixedSeg(
                     np.zeros_like(self._dev_sver),
                     np.zeros_like(self._dev_sver),
-                    vlen_plane, vwin_plane, set_cum, kind,
+                    ops.vlen, ops.vwin, set_cum, kind,
                 )
                 seg.provisional = True
                 self._dev_push_segment(seg)
@@ -1821,7 +1779,7 @@ class MeshEngine:
                 svers = self._dev_sver[None, : self.S] + set_cum
                 seg_start = self._dev_sver.copy()
                 seg = _MixedSeg(
-                    seg_start, seg_start + set_cum[-1], vlen_plane, vwin_plane,
+                    seg_start, seg_start + set_cum[-1], ops.vlen, ops.vwin,
                     svers, kind,
                 )
                 self._dev_push_segment(seg)
@@ -1858,7 +1816,7 @@ class MeshEngine:
 
     def _dev_push_segment(self, seg) -> None:
         """Retain one committed device window's value bytes (a
-        :class:`_RowSeg` / :class:`_DictSeg` / :class:`_MixedSeg`).
+        :class:`_RowSeg` / :class:`_MixedSeg`).
 
         ``seg.start``/``seg.end`` bound the shard versions the window
         assigned (start[s] < v <= end[s]). Eviction (byte cap) raises

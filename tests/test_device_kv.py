@@ -788,12 +788,9 @@ class TestDeviceGetWindows:
             list(map(bytes, g)) for g in fh.result()
         ]
 
-    def test_dict_upload_engages_and_conforms(self):
-        # repetitive SET streams take the dictionary-compressed upload
-        # (a _DictSeg lands in the value segments); responses and final
-        # content stay identical to the host path
-        from rabia_tpu.parallel.mesh_engine import _DictSeg
-
+    def test_repetitive_set_stream_conforms(self):
+        # a SET stream of two rows a shard, then a read of what it
+        # wrote: responses and final content identical to the host path
         n = 4
         dev = _mk(n, device=True)
         host = _mk(n, device=False)
@@ -808,7 +805,7 @@ class TestDeviceGetWindows:
                         ],
                     )
                 )
-            e.flush()  # pure-SET window: the dict upload path
+            e.flush()  # a pure-SET window
             fd = e.submit_block(
                 build_block(
                     list(range(n)),
@@ -821,7 +818,6 @@ class TestDeviceGetWindows:
             else:
                 host_get = fd
         assert dev._dev_active
-        assert any(isinstance(sg, _DictSeg) for sg in dev._dev_vseg)
         assert [list(map(bytes, g)) for g in dev_get.result()] == [
             list(map(bytes, g)) for g in host_get.result()
         ]
@@ -830,9 +826,8 @@ class TestDeviceGetWindows:
         for sm in dev.sms:
             assert _store_content(sm, n) == want
 
-    def test_high_cardinality_window_falls_back_to_rows(self):
-        # >32 distinct (key, value) rows per shard in one window: the
-        # dictionary declines (max_dict) and the row-packed path runs
+    def test_forty_distinct_rows_a_shard_conform(self):
+        # a window in which every wave writes another (key, value) row
         from rabia_tpu.parallel.mesh_engine import _RowSeg
 
         n = 2
@@ -1042,14 +1037,9 @@ class TestDeviceGetWindows:
             assert _store_content(sm, n) == want
         assert np.array_equal(dev.next_slot, host.next_slot)
 
-    def test_get_window_dict_upload_engages_and_conforms(self):
-        # a repetitive GET stream takes the dictionary-compressed key
-        # upload (keys repeat like SET rows repeat); responses stay
-        # byte-identical to the host path. Pins that
-        # pack_get_window_auto actually chooses the dict form.
-        from rabia_tpu.apps.device_kv import DeviceDictOps
-        from rabia_tpu.apps.kvstore import encode_set_bin
-
+    def test_repetitive_get_stream_conforms(self):
+        # a GET stream that repeats two keys a shard: responses stay
+        # byte-identical to the host path
         n = 4
         dev = _mk(n, device=True, window=4)
         host = _mk(n, device=False, window=4)
@@ -1071,26 +1061,17 @@ class TestDeviceGetWindows:
                 for _ in range(8)
             ]
 
-        packed = dev._dev.pack_get_window_auto(gets()[:4])
-        assert isinstance(packed, DeviceDictOps)
         fd = [dev.submit_block(b) for b in gets()]
         fh = [host.submit_block(b) for b in gets()]
         dev.flush()
         host.flush()
-        assert dev._dev_active, "dict-GET window demoted the lane"
+        assert dev._dev_active, "a GET window demoted the lane"
         for a, b in zip(fd, fh):
             assert _frames(a) == _frames(b)
 
-    def test_mixed_window_dict_upload_engages_and_conforms(self):
-        # a repetitive INTERLEAVED stream takes the dictionary upload
-        # through the MIXED program (GET ops become (key, empty value)
-        # dictionary rows); responses stay byte-identical to the host
-        # path. Pins that pack_mixed_window_auto actually chooses the
-        # dict form — a silent permanent row fallback would pass every
-        # conformance test while giving up the 10x upload compression.
-        from rabia_tpu.apps.device_kv import DeviceDictOps
-        from rabia_tpu.apps.kvstore import encode_set_bin
-
+    def test_repetitive_mixed_stream_conforms(self):
+        # a repetitive INTERLEAVED stream through the MIXED program:
+        # responses and final content byte-identical to the host path
         n = 4
         dev = _mk(n, device=True, window=6)
         host = _mk(n, device=False, window=6)
@@ -1115,17 +1096,11 @@ class TestDeviceGetWindows:
                 )
             return out
 
-        # the packer must choose the dictionary form for this window
-        blocks = stream()[:6]
-        packed = dev._dev.pack_mixed_window_auto(blocks)
-        assert packed is not None
-        assert isinstance(packed[1], DeviceDictOps)
-
         fd = [dev.submit_block(b) for b in stream()]
         fh = [host.submit_block(b) for b in stream()]
         dev.flush()
         host.flush()
-        assert dev._dev_active, "dict-mixed window demoted the lane"
+        assert dev._dev_active, "a mixed window demoted the lane"
         for a, b in zip(fd, fh):
             assert _frames(a) == _frames(b)
         dev._demote_device_store()
@@ -1163,66 +1138,49 @@ class TestDeviceGetWindows:
         ]
 
 
-# -- the dictionary probe: an exact early exit of _dict_rows ---------------
+# -- windows by their distinct rows a shard: one upload form for all -------
 
-_PROBE_W = 40  # waves a window: more than max_dict, so a shard can overflow
-_MAX_DICT = 32
-_UNPROBED = 5  # a shard the eight strided probe columns skip at 12 and 16
+_ROWS_W = 40  # waves a window
+_ROWS = 32  # distinct (key, value) rows a shard in the "at_max" window
+_ODD_SHARD = 5  # the one shard of "one_over" that holds a row more
 
 
-def _probe_row(allow: str, key: str, r: int) -> bytes:
+def _row_op(allow: str, key: str, r: int) -> bytes:
     """Row ``r``'s op under ``allow``; a mixed window interleaves kinds."""
     if allow == "set" or (allow == "mixed" and r % 2 == 0):
         return encode_set_bin(key, f"v{r}")
     return TestDeviceGetWindows._enc_get(key)
 
 
-def _probe_blocks(n: int, allow: str, case: str) -> list:
-    """One window of ``_PROBE_W`` full-width blocks whose shards hold, by
-    ``case``: one row each (D = 1); exactly ``max_dict`` distinct rows
-    each; ``max_dict`` each and one more in one unprobed shard; more in
-    every shard; or four rows that a first-byte hash folds into two."""
+def _row_blocks(n: int, allow: str, case: str) -> list:
+    """One window of ``_ROWS_W`` full-width blocks whose shards hold, by
+    ``case``: one row each; exactly ``_ROWS`` distinct rows each;
+    ``_ROWS`` each and one more in one shard; or one more in every shard
+    (the windows the dictionary upload used to take, the first two, or
+    refuse)."""
 
     def cmd(s: int, w: int) -> bytes:
         if case == "one_row":
             r = s % 2
         elif case == "at_max":
-            r = w % _MAX_DICT
-        elif case == "one_unprobed_over":
-            r = w % (_MAX_DICT + (s == _UNPROBED))
-        elif case == "all_over":
-            r = w % (_MAX_DICT + 1)
-        else:  # "collision"
-            r = w % 4
-            return _probe_row(allow, "ab"[r % 2] + str(r // 2), r)
-        return _probe_row(allow, f"k{r:02d}", r)
+            r = w % _ROWS
+        elif case == "one_over":
+            r = w % (_ROWS + (s == _ODD_SHARD))
+        else:  # "all_over"
+            r = w % (_ROWS + 1)
+        return _row_op(allow, f"k{r:02d}", r)
 
     return [
         build_block(list(range(n)), [[cmd(s, w)] for s in range(n)])
-        for w in range(_PROBE_W)
+        for w in range(_ROWS_W)
     ]
 
 
-def _first_byte_fold(a: np.ndarray) -> np.ndarray:
-    """A forged weak fold: rows that share their first byte collide."""
-    return a[..., 0].astype(np.uint64)
-
-
-def _assert_same_dict(got, want) -> None:
-    if got is None or want is None:
-        assert got is None and want is None
-        return
-    assert type(got) is type(want)
-    for a, b in zip(got, want):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        assert a.tobytes() == b.tobytes()
-
-
 @pytest.fixture(scope="module")
-def probe_tables():
+def pack_tables():
     """One table with zero padding on the S axis (12 shards of 16) and
     one without (16 of 16), on the 8-device CPU mesh."""
-    engines = {n: _mk(n, device=True, window=_PROBE_W) for n in (12, 16)}
+    engines = {n: _mk(n, device=True, window=_ROWS_W) for n in (12, 16)}
     assert engines[12]._dev.S == 16 and engines[16]._dev.S == 16
     yield {n: e._dev for n, e in engines.items()}
     for e in engines.values():
@@ -1231,86 +1189,44 @@ def probe_tables():
 
 @pytest.mark.parametrize("n", [12, 16], ids=["padded", "full"])
 @pytest.mark.parametrize("allow", ["set", "get", "mixed"])
-class TestDictProbe:
-    """``_dict_rows`` = the probe, then ``_dict_full``: the probe says
-    None only where the full path alone says None."""
+@pytest.mark.parametrize(
+    "case", ["one_row", "at_max", "one_over", "all_over"]
+)
+def test_windows_of_few_and_many_rows_conform(n, allow, case):
+    """However few distinct rows a window holds, it uploads as rows and
+    answers as the host engine does. The window runs on an empty table
+    (its reads miss), after a SET window of the same rows, and again
+    (its reads hit)."""
+    dev = _mk(n, device=True, window=_ROWS_W)
+    host = _mk(n, device=False, window=_ROWS_W)
+    futs = {}
+    for e in (dev, host):
+        futs[e] = [
+            e.submit_block(b)
+            for a in (allow, "set", allow)
+            for b in _row_blocks(n, a, case)
+        ]
+        e.flush()
+    assert dev.device_lane_active
+    replies = [_frames(f) for f in futs[dev]]
+    assert replies == [_frames(f) for f in futs[host]]
+    if allow != "set":
+        from rabia_tpu.apps.kvstore import _result_bin
 
-    @pytest.mark.parametrize(
-        "case,outcome",
-        [
-            ("one_row", "built"),
-            ("at_max", "built"),  # (c): exactly max_dict still compresses
-            ("one_unprobed_over", "rejected"),
-            ("all_over", "probe_rejected"),
-            ("collision", "rejected"),
-        ],
-    )
-    def test_equals_the_full_path(
-        self, probe_tables, monkeypatch, n, allow, case, outcome
-    ):
-        from rabia_tpu.apps import device_kv
-
-        if case == "collision":
-            monkeypatch.setattr(device_kv, "_fold_words", _first_byte_fold)
-        dev = probe_tables[n]
-        g = dev._gather_window(_probe_blocks(n, allow, case), allow)
-        assert g is not None
-        before = dict(dev.dict_attempts)
-        got = dev._dict_rows(g, _MAX_DICT)
-        counted = {
-            o: dev.dict_attempts[o] - before[o] for o in dev.dict_attempts
-        }
-        assert counted == {
-            o: int(o == outcome) for o in dev.dict_attempts
-        }
-        _assert_same_dict(got, dev._dict_full(g, _MAX_DICT))
-        assert (got is not None) == (outcome == "built")
-        if outcome == "built":
-            D = 1 if case == "one_row" else _MAX_DICT
-            assert got.dkl.shape == (dev.S, D)
-
-    def test_rejection_reads_a_few_shards_and_never_sorts_the_window(
-        self, probe_tables, monkeypatch, n, allow
-    ):
-        from rabia_tpu.apps import device_kv
-
-        folded, sorts = [], []
-        fold, argsort = device_kv._fold_words, np.argsort
-
-        def counting_fold(a):
-            folded.append(a.shape)
-            return fold(a)
-
-        def counting_argsort(*args, **kw):
-            sorts.append(1)
-            return argsort(*args, **kw)
-
-        monkeypatch.setattr(device_kv, "_fold_words", counting_fold)
-        monkeypatch.setattr(np, "argsort", counting_argsort)
-        dev = probe_tables[n]
-        g = dev._gather_window(_probe_blocks(n, allow, "all_over"), allow)
-        assert dev._dict_rows(g, _MAX_DICT) is None
-        assert len(folded) == 2 and not sorts
-        assert all(s[:2] == (_PROBE_W, 8) for s in folded)
-        # the counts do count: a window that compresses reaches both
-        g = dev._gather_window(_probe_blocks(n, allow, "at_max"), allow)
-        assert dev._dict_rows(g, _MAX_DICT) is not None
-        assert sorts and any(s[:2] == (_PROBE_W, dev.S) for s in folded)
-
-    def test_probe_reads_no_column_beyond_the_real_shards(
-        self, probe_tables, n, allow
-    ):
-        # (d): rows that only a read of columns >= n_shards could see
-        dev = probe_tables[n]
-        g = dev._gather_window(_probe_blocks(n, allow, "one_row"), allow)
-        _kind, klen_w, _vlen, _kwin, _vwin = g
-        beyond = klen_w[:, dev.n_shards :]
-        assert not beyond.any()  # the S axis' padding is all zero
-        beyond[:] = np.arange(1, _PROBE_W + 1, dtype=klen_w.dtype)[:, None]
-        assert not dev._dict_probe_rejects(g, _MAX_DICT)
-        # the same rows in a real, probed column are seen
-        klen_w[:, 0] = np.arange(1, _PROBE_W + 1, dtype=klen_w.dtype)
-        assert dev._dict_probe_rejects(g, _MAX_DICT)
+        missed = [_result_bin(1, 0) in r for r in replies]
+        assert any(missed[:_ROWS_W]) and not any(missed[2 * _ROWS_W :])
+    # one program a window kind, keyed on (W, widths) alone
+    programs = {"set": _ROWS_W, "get": "get", "mixed": "mix"}
+    assert {k[0] for k in dev._dev._fused_cache} == {
+        _ROWS_W, programs[allow]
+    }
+    dev.sync_to_host()
+    want = _store_content(host.sms[0], n)
+    assert want
+    for sm in dev.sms:
+        assert _store_content(sm, n) == want
+    dev.close()
+    host.close()
 
 
 # -- the pack's plane pool: warm buffers, handed out again only when idle --
@@ -1397,9 +1313,9 @@ class TestGatherIntoDirtyPlanes:
     so a plane full of 0xFF ends up the numpy path's on a zeroed one."""
 
     def test_native_gather_over_changing_widths(
-        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, monkeypatch, n, allow
     ):
-        dev = probe_tables[n]
+        dev = pack_tables[n]
         shapes = set()
         for klen, vmax in _WIDTHS:  # consecutive windows, other buckets
             blocks = _width_blocks(n, allow, klen, vmax)
@@ -1412,7 +1328,7 @@ class TestGatherIntoDirtyPlanes:
         assert len(shapes) == 2
 
     def test_bounds_trip_falls_back_to_numpy(
-        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, monkeypatch, n, allow
     ):
         from rabia_tpu.apps.device_kv import DeviceKVTable
 
@@ -1424,7 +1340,7 @@ class TestGatherIntoDirtyPlanes:
             last = dbufs[-1]
             return spy(self_, [*dbufs[:-1], last[: len(last) // 2]], *a)
 
-        dev = probe_tables[n]
+        dev = pack_tables[n]
         blocks = _width_blocks(n, allow, 13, 40)
         want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
         monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", short_buffer)
@@ -1434,7 +1350,7 @@ class TestGatherIntoDirtyPlanes:
         _assert_same_planes(got, want)
 
     def test_block_bytes_of_another_layout_are_copied_for_c(
-        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, monkeypatch, n, allow
     ):
         from rabia_tpu.apps.device_kv import DeviceKVTable
 
@@ -1446,7 +1362,7 @@ class TestGatherIntoDirtyPlanes:
             wide = np.repeat(dbufs[0], 2)
             return spy(self_, [wide[::2], *dbufs[1:]], *a)
 
-        dev = probe_tables[n]
+        dev = pack_tables[n]
         blocks = _width_blocks(n, allow, 13, 40)
         want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
         monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", strided)
@@ -1456,11 +1372,11 @@ class TestGatherIntoDirtyPlanes:
         _assert_same_planes(got, want)
 
     def test_scattered_blocks_take_the_numpy_path(
-        self, probe_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, monkeypatch, n, allow
     ):
         # blocks that are not the full sorted grid: the scatter covers
         # only the ops' cells, so the planes are cleared first
-        dev = probe_tables[n]
+        dev = pack_tables[n]
         full = _width_blocks(n, allow, 13, 40)
         shards = list(range(n - 1, 0, -2))
         blocks = [

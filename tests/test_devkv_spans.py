@@ -28,7 +28,7 @@ WINDOW = 4
 N_WINDOWS = 3
 
 PACK_PARTS = tuple(
-    f"rabia.cycle.pack.{p}" for p in ("parse", "alloc", "gather", "dict")
+    f"rabia.cycle.pack.{p}" for p in ("parse", "alloc", "gather")
 )
 CALLS = ("rabia.dispatch.call", "rabia.jit.first_call")
 PROGRAM = {
@@ -184,56 +184,6 @@ class TestSpansPerWindow:
         eng.close()
 
 
-class TestDictAttempts:
-    OUTCOMES = ("built", "probe_rejected", "rejected")
-
-    def _counts(self, eng) -> dict:
-        snap = eng.metrics.snapshot()
-        return {
-            o: snap[f'rabia_devkv_dict_attempts_total{{outcome="{o}"}}']
-            for o in self.OUTCOMES
-        }
-
-    def test_counter_counts_one_outcome_per_window_packed(self, traced):
-        eng = _engine()
-        rng = np.random.default_rng(29)
-        for kind in ("set", "get", "mixed"):
-            for _ in range(N_WINDOWS):
-                _window(eng, kind, rng)
-        eng.flush()
-        # four waves of three keys a shard: every window compresses
-        packed = 3 * N_WINDOWS
-        assert self._counts(eng) == {
-            "built": packed, "probe_rejected": 0, "rejected": 0,
-        }
-        text = eng.metrics.render_prometheus()
-        for o in self.OUTCOMES:
-            assert f'rabia_devkv_dict_attempts_total{{outcome="{o}"}}' in text
-        # the attempt keeps its span: once a window, inside the pack
-        rep = traced.report()
-        assert rep["rabia.cycle.pack.dict"]["count"] == packed
-        assert rep["rabia.cycle.pack"]["count"] == packed
-        assert _total("rabia.cycle.pack.dict") <= _total("rabia.cycle.pack")
-        eng.close()
-
-    def test_window_over_max_dict_is_probe_rejected(self, traced):
-        waves = 40  # every wave another row: over the 32 of max_dict
-        eng = _engine(window=waves)
-        for w in range(waves):
-            eng.submit_block(build_block(
-                list(range(N_SHARDS)),
-                [[encode_set_bin(f"k{w}", f"v{w}")] for _ in range(N_SHARDS)],
-            ))
-        eng.run_cycle()
-        eng.flush()
-        assert eng.device_lane_active
-        assert self._counts(eng) == {
-            "built": 0, "probe_rejected": 1, "rejected": 0,
-        }
-        assert traced.report()["rabia.cycle.pack.dict"]["count"] == 1
-        eng.close()
-
-
 def test_profiler_events_tile_the_dispatch(tmp_path):
     """The same spans as TraceMe events in a profiler trace (an annotation's
     event begins where it is made): inside one ``rabia.devkv.*`` event the
@@ -292,14 +242,13 @@ class TestUploadBytes:
         dev = eng._dev
         beside = eng.alive.nbytes + eng.S * 4  # alive mask + base slots
         if kind == "set":
-            ops = dev.pack_window_auto(blocks)
+            ops = dev.pack_window(blocks)
             want = beside + sum(a.nbytes for a in ops)
         elif kind == "get":
-            ops = dev.pack_get_window_auto(blocks)
-            # the key dictionary only: a lookup never uploads values
-            want = beside + ops.idx.nbytes + ops.dkl.nbytes + ops.dk.nbytes
+            # the key planes only: a lookup never uploads values
+            want = beside + sum(a.nbytes for a in dev.pack_get_window(blocks))
         else:
-            kinds, ops, _vlen, _vwin = dev.pack_mixed_window_auto(blocks)
+            kinds, ops = dev.pack_mixed_window(blocks)
             want = beside + kinds.nbytes + sum(a.nbytes for a in ops)
         before = eng.metrics.snapshot()["rabia_devkv_upload_bytes_total"]
         for b in blocks:
