@@ -28,8 +28,9 @@ Span naming taxonomy (dotted, coarse→fine):
   engine.kernel.{start,route,step,outbox}
   wire.{serialize,deserialize}
   sm.apply
-  rabia.cycle.{pack,book,wait,settle}, rabia.cycle.pack.{parse,alloc,gather,dict},
+  rabia.cycle.{pack,book,wait,settle}, rabia.cycle.pack.{parse,alloc,gather},
     rabia.cycle.settle.download
+  rabia.fetch.values (on a readback worker's thread, not the window's)
   rabia.devkv.{decide_apply,lookup_window,mixed_apply,read_probe}
     (reserved for the dispatch spans: the benchmark selects them by prefix)
   rabia.dispatch.{place,call}, rabia.jit.first_call
@@ -38,6 +39,7 @@ Span naming taxonomy (dotted, coarse→fine):
 from __future__ import annotations
 
 import os
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -61,19 +63,24 @@ class Tracer:
 
     enabled: bool = False
     spans: dict = field(default_factory=dict)
+    # the device lane's readback workers record spans too
+    _lock: threading.Lock = field(
+        default_factory=threading.Lock, repr=False, compare=False
+    )
 
     def record(self, name: str, dt: float) -> None:
-        st = self.spans.get(name)
-        if st is None:
-            st = self.spans[name] = SpanStats()
-        st.add(dt)
+        with self._lock:
+            st = self.spans.get(name)
+            if st is None:
+                st = self.spans[name] = SpanStats()
+            st.add(dt)
 
     def report(self) -> dict:
         """{span: {count, total_s, avg_us, max_us}} sorted by total time."""
         out = {}
-        for name, st in sorted(
-            self.spans.items(), key=lambda kv: -kv[1].total_s
-        ):
+        with self._lock:
+            items = list(self.spans.items())
+        for name, st in sorted(items, key=lambda kv: -kv[1].total_s):
             out[name] = {
                 "count": st.count,
                 "total_s": round(st.total_s, 4),

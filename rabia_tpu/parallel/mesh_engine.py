@@ -167,6 +167,29 @@ def _block_op_kind(block) -> Optional[int]:
     return first if bool((codes == first).all()) else None
 
 
+_VALUE_FETCH_OUTCOMES = ("prefetched", "inline", "unused")
+# Value planes smaller than this are never handed to a readback worker:
+# the hand-over (a pool task, a thread woken, the interpreter lock passed
+# back and forth) costs the window's thread more than downloading them
+# itself does (262 KB a window: 0.19 ms inline; PERF.md §6, PR 33)
+_PREFETCH_MIN_BYTES = 1 << 20
+
+
+def _prefetch_values(planes: tuple) -> tuple:
+    """A window's value planes on the host, as a readback worker fetches
+    them: each whole and C-contiguous, so that a wave's row of one is a
+    view and the settle copies nothing. Takes device handles, returns
+    arrays and touches nothing of the engine. The span lies on the
+    worker's thread and outside ``rabia.devkv.*``: no reader of the
+    window's thread sees it."""
+    from rabia_tpu.apps.device_kv import DeviceKVTable
+
+    with device_annotation(
+        "rabia.fetch.values", bytes=sum(int(p.nbytes) for p in planes)
+    ):
+        return tuple(map(DeviceKVTable._fetch, planes))
+
+
 class MeshFuture:
     """Synchronously settled result holder for one submitted batch.
 
@@ -513,11 +536,31 @@ class MeshEngine:
         self._dev_value_download_bytes = 0
         m.counter(
             "devkv_value_download_bytes_total",
-            "Value-plane bytes the settle downloaded from the device "
+            "Value-plane bytes a settle took from the device and used, "
             "because a read's version had left the host segments (the "
-            "rabia.cycle.settle.download spans)",
+            "rabia.cycle.settle.download spans; a prefetched plane that "
+            "the settle did not need is not counted)",
             fn=lambda: self._dev_value_download_bytes,
         )
+        # does a GET-bearing window fetch its value planes on a readback
+        # worker at dispatch? Iff the newest such window to settle had to
+        # download them (see _dev_settle_values)
+        self._dev_prefetch = False
+        self._dev_value_fetch = dict.fromkeys(_VALUE_FETCH_OUTCOMES, 0)
+        for _outcome in _VALUE_FETCH_OUTCOMES:
+            m.counter(
+                "devkv_value_fetch_total",
+                "Settled GET-bearing device windows by where their value "
+                "planes came from (the outcome= of the "
+                "rabia.cycle.settle.download spans): prefetched = a "
+                "readback worker fetched them from dispatch on and the "
+                "settle picked them up, inline = the settle downloaded "
+                "them on the window's thread, unused = prefetched, but "
+                "every read resolved from the host segments. A window that "
+                "neither prefetched nor downloaded counts nowhere",
+                {"outcome": _outcome},
+                fn=lambda o=_outcome: self._dev_value_fetch[o],
+            )
         m.counter(
             "devkv_program_builds_total",
             "Window programs built, one per distinct signature (each "
@@ -585,7 +628,8 @@ class MeshEngine:
             # not value planes (~70 B/op)
             # pipelined-commit records: dispatched-but-unresolved
             # windows (flags unread); see _run_cycle_fullwidth_device.
-            # Flag/meta fetches run on a worker pool (2 per allowed
+            # Flag/meta fetches, and the value planes' where a window
+            # prefetches them, run on a worker pool (3 per allowed
             # in-flight window — see _dev_fetcher): issued from the
             # main thread they would queue BEHIND the just-dispatched
             # next window on the single-stream device and wait out a
@@ -1249,20 +1293,21 @@ class MeshEngine:
         return applied
 
     def _dev_fetcher(self):
-        """The executor that fetches window flags/meta off the main
-        thread (see _run_cycle_fullwidth_device). Lazy and
+        """The executor that fetches window flags/meta/value planes
+        off the main thread (see _run_cycle_fullwidth_device). Lazy and
         recreatable: demotion shuts it down (host mode needs no worker),
         re-promotion's first pipelined window brings it back."""
         import concurrent.futures
 
         if self._dev_fetcher_pool is None:
-            # two workers per allowed in-flight window (GET/mixed
-            # windows submit TWO blocking fetches — flags + meta): with
-            # a deeper pipe, window k's readbacks must not queue behind
-            # k-1's or the fetches serialize one RTT apart and the
-            # extra depth hides nothing
+            # three workers per allowed in-flight window (GET/mixed
+            # windows submit up to THREE blocking fetches — flags, meta
+            # and, when prefetching, the value planes): with a deeper
+            # pipe, window k's readbacks must not queue behind k-1's or
+            # the fetches serialize one RTT apart and the extra depth
+            # hides nothing
             self._dev_fetcher_pool = concurrent.futures.ThreadPoolExecutor(
-                max_workers=2 * self._dev_inflight,
+                max_workers=3 * self._dev_inflight,
                 thread_name_prefix="devkv-flags",
             )
         return self._dev_fetcher_pool
@@ -1300,7 +1345,10 @@ class MeshEngine:
             # roll back EVERY optimistic window, newest first — the
             # device state was never adopted, so restoring the host
             # bookkeeping re-creates the pre-window world exactly; the
-            # host path then re-decides the same blocks
+            # host path then re-decides the same blocks. A record's
+            # readback futures (a value prefetch among them) go with it
+            # unread: the workers hold device handles and nothing of the
+            # engine, so a late completion touches nothing
             while self._dev_pipe:
                 r = self._dev_pipe.pop()
                 d, rn = r["depth"], r["n"]
@@ -1421,12 +1469,56 @@ class MeshEngine:
             bounds = np.arange(len(block) + 1, dtype=np.int64)
             bfut._settle_bulk(FrameGroups(frames, bounds))
 
+    def _dev_prefetch_values(self, pool, planes):
+        """At dispatch: start the fetch of a GET-bearing window's value
+        planes on a readback worker, iff the newest such window to
+        settle had to download its own (``_dev_settle_values`` keeps
+        that observation) and the planes are large enough to be worth a
+        hand-over. Submitted after the window's flags and meta, which
+        must not queue behind the bulk copy. Returns the future, or None
+        when the planes stay on the device until a settle asks."""
+        if not (planes and self._dev_prefetch):
+            return None
+        if sum(int(p.nbytes) for p in planes) < _PREFETCH_MIN_BYTES:
+            return None
+        return pool.submit(_prefetch_values, planes)
+
+    def _dev_settle_values(self, rec, resolved: bool):
+        """At settle: the value planes of a GET-bearing window on the
+        host, or None when its reads ``resolved`` from the host
+        segments. A plane comes from the window's worker when dispatch
+        started one (the span is then the wait for it) and is downloaded
+        here when not, exactly as before there were prefetches;
+        ``rabia.cycle.settle.download`` is entered once either way. What
+        this window needed decides whether the next window dispatched
+        prefetches: a deployment whose reads resolve on the host pays
+        for no transfer, one whose reads have left the segments pays for
+        it off the window's thread."""
+        fut = rec["val_fut"]
+        self._dev_prefetch = not resolved
+        if resolved:
+            if fut is not None:
+                fut.cancel()  # if still queued, the transfer is never made
+                self._dev_value_fetch["unused"] += 1
+            return None
+        outcome = "inline" if fut is None else "prefetched"
+        with device_annotation("rabia.cycle.settle.download", outcome=outcome):
+            planes = (
+                tuple(map(np.asarray, rec["val_dev"]))
+                if fut is None
+                else fut.result()
+            )
+        self._dev_value_fetch[outcome] += 1
+        self._dev_value_download_bytes += sum(p.nbytes for p in planes)
+        return planes
+
     def _dev_settle_get(self, rec) -> None:
         """Settle a clean GET window: meta (found/version) was fetched
         on the worker alongside the flags; value bytes resolve from the
-        host-side segments unless an eviction between dispatch and
-        resolution forces the value-plane download (the device handles
-        were retained in the record for exactly that edge)."""
+        host-side segments unless an evicted version forces the
+        value-plane download (:meth:`_dev_settle_values`: from the
+        window's worker, or from the device handles retained in the
+        record)."""
         from rabia_tpu.apps.device_kv import (
             GetFrameGroups,
             ResolvedGetFrameGroups,
@@ -1435,16 +1527,13 @@ class MeshEngine:
         depth = rec["depth"]
         found, ver = rec["meta_fut"].result()
         resolved = not self._dev_unresolvable(found[:depth], ver[:depth])
+        planes = self._dev_settle_values(rec, resolved)
         if resolved:
             rsv = self._dev_make_resolver()
         else:
             # eviction edge: the window pays the value-plane download
             self._read_stats["fallback"] += depth * rec["n"]
-            vlen_d, valw_d = rec["val_dev"]
-            with device_annotation("rabia.cycle.settle.download"):
-                vlen = np.asarray(vlen_d)
-                valw = np.asarray(valw_d)
-            self._dev_value_download_bytes += vlen.nbytes + valw.nbytes
+            vlen, valw = planes
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             if resolved:
@@ -1460,7 +1549,8 @@ class MeshEngine:
         """Settle a clean mixed window: SET versions derive from the
         recorded per-wave cumulative counters; GET meta was fetched on
         the worker; GET values resolve host-side with the downloaded
-        value planes as the eviction fallback.
+        value plane as the eviction fallback
+        (:meth:`_dev_settle_values`).
 
         A DEFERRED window (DEL-bearing, or dispatched behind one)
         derives its versions HERE instead of at dispatch: FIFO
@@ -1513,12 +1603,11 @@ class MeshEngine:
             resolved = not self._dev_unresolvable(
                 gfound_h[:g] & is_get_rows, gver_h[:g]
             )
+            planes = self._dev_settle_values(rec, resolved)
             if resolved:
                 rsv = self._dev_make_resolver()
             else:
-                with device_annotation("rabia.cycle.settle.download"):
-                    gval_h = np.asarray(rec["gval_dev"])
-                self._dev_value_download_bytes += gval_h.nbytes
+                (gval_h,) = planes
         for t, (block, bfut, _inv) in enumerate(rec["entries"]):
             sh = np.asarray(block.shards, np.int64)
             row_kind = kind[t]
@@ -1620,6 +1709,7 @@ class MeshEngine:
                     lambda f=found_d, v=ver_d: (np.asarray(f), np.asarray(v))
                 ),
                 "val_dev": (vlen_d, valw_d),
+                "val_fut": self._dev_prefetch_values(pool, (vlen_d, valw_d)),
                 # read-only: the chained state passes through untouched
                 "new_state": state_base,
                 "entries": batch,
@@ -1644,7 +1734,9 @@ class MeshEngine:
         host at SET time or seeded at re-promotion — (shard, version)
         is unique content identity). Only when the vectorized
         resolvability check finds an evicted version does the window
-        download the value planes (~70 bytes/op, the round-4 cost).
+        download the value planes (~70 bytes/op, the round-4 cost); once
+        a window had to, the next ones fetch theirs on a worker from
+        dispatch on (:meth:`_dev_prefetch_values`).
 
         PIPELINED: the lookup chains on the newest in-flight window's
         output state (reads observe every earlier window's SETs —
@@ -1687,6 +1779,7 @@ class MeshEngine:
                     lambda f=found_d, v=ver_d: (np.asarray(f), np.asarray(v))
                 ),
                 "val_dev": (vlen_d, valw_d),
+                "val_fut": self._dev_prefetch_values(pool, (vlen_d, valw_d)),
                 # read-only window: the chained state passes through
                 "new_state": state_base,
                 "entries": entries,
@@ -1706,12 +1799,13 @@ class MeshEngine:
         once); GET responses in the steady state carry META ONLY — value
         bytes resolve from the host-side segments (this window's SETs
         included, so reads of same-window writes resolve too), with the
-        value-plane download kept as the eviction fallback.
+        value-plane download kept as the eviction fallback (on a
+        readback worker from dispatch on, once a window needed it).
 
         PIPELINED like the pure-SET lane: the dispatch chains on the
         newest in-flight window's output state, bookkeeping advances
         optimistically, and the flags + GET meta are fetched on
-        the worker thread while the next window packs — settlement and
+        the worker threads while the next window packs — settlement and
         the dirty-rollback both live in :meth:`_dev_resolve_one` /
         :meth:`_dev_settle_mixed`."""
         W = self.window
@@ -1788,19 +1882,22 @@ class MeshEngine:
                 self._dev_sver += sver_delta
             self._dev_commit_window(entries, count)
             pool = self._dev_fetcher()
+            val_dev = (gval_dev,) if len(get_waves) else None
             rec = {
                 "kind": "mixed",
                 "flags_fut": pool.submit(np.asarray, flags_dev),
                 # meta fetched optimistically alongside the flags (a
                 # dirty window wastes one small transfer — the rollback
-                # edge); value planes stay on device unless eviction
-                # forces the fallback at settle time
+                # edge); the value plane stays on the device unless the
+                # last settle had to download one (then a worker
+                # fetches it from here on) or this one's settle has to
                 "meta_fut": (
                     pool.submit(np.asarray, meta_dev)
                     if len(get_waves)
                     else None
                 ),
-                "gval_dev": gval_dev if len(get_waves) else None,
+                "val_dev": val_dev,
+                "val_fut": self._dev_prefetch_values(pool, val_dev),
                 "new_state": new_state,
                 "entries": entries,
                 "depth": count,
