@@ -330,6 +330,25 @@ class TableDump(dict):
         )
 
 
+def _state_planes(S: int, P: int, K4: int, VW4: int) -> tuple:
+    """The table's seven state planes as ``(shape, dtype)``, in the order
+    of ``DeviceKVTable.state``: ``S`` shards of ``P`` slots, keys of
+    ``K4`` and values of ``VW4`` 32-bit words."""
+    return (
+        ((S, P), np.bool_),  # used
+        ((S, P, K4), np.uint32),  # key words
+        ((S, P), np.int32),  # key len
+        ((S, P), np.int32),  # version
+        ((S, P, VW4), np.uint32),  # value words
+        ((S, P), np.int32),  # value len
+        ((S,), np.int32),  # shard_ver
+    )
+
+
+def _plane_bytes(planes) -> int:
+    return sum(int(np.prod(sh)) * np.dtype(dt).itemsize for sh, dt in planes)
+
+
 class DeviceKVTable:
     """Device twin of the vector store's SET lane (see module doc)."""
 
@@ -367,15 +386,11 @@ class DeviceKVTable:
         wave_sharding = NamedSharding(kernel.mesh, P(None, SHARD_AXIS))
         put = self._put_shards = lambda a: jax.device_put(a, shard_sharding)
         self._put_waves = lambda a: jax.device_put(a, wave_sharding)
-        self.state = (
-            put(jnp.zeros((S, Pc), bool)),  # used
-            put(jnp.zeros((S, Pc, self.K4), jnp.uint32)),  # key words
-            put(jnp.zeros((S, Pc), jnp.int32)),  # key len
-            put(jnp.zeros((S, Pc), jnp.int32)),  # version
-            put(jnp.zeros((S, Pc, self.VW4), jnp.uint32)),  # value words
-            put(jnp.zeros((S, Pc), jnp.int32)),  # value len
-            put(jnp.zeros((S,), jnp.int32)),  # shard_ver
-        )
+        planes = _state_planes(S, Pc, self.K4, self.VW4)
+        self.state = tuple(put(jnp.zeros(sh, dt)) for sh, dt in planes)
+        # what the table holds on the device, every chip's share together
+        # (the engine's devkv_table_bytes reads it)
+        self.table_bytes = _plane_bytes(planes)
         self._fused = None  # built per (W, Ku4, VWu4) — see decide_apply
         self._fused_cache: dict = {}
         # True when the most recent decide_apply/lookup_window built a

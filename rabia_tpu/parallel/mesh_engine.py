@@ -504,6 +504,13 @@ class MeshEngine:
             "Consensus-free lookup_only probe windows dispatched",
             fn=lambda: self._read_stats["probe_windows"],
         )
+        m.gauge(
+            "devkv_table_bytes",
+            "Bytes of the device table's seven state planes, from their "
+            "shapes (every chip's share together; 0 without a device "
+            "store)",
+            fn=lambda: self._dev.table_bytes if self._dev is not None else 0,
+        )
         m.counter(
             "devkv_upload_bytes_total",
             "Host bytes placed on the device for window dispatches (the "

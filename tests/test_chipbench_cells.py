@@ -1,0 +1,216 @@
+"""The cells of PR 35: ``kv-r5-s4096.ycsb-a-sat`` and the configuration
+``kv-r5-s4096-p512`` (the north-star deployment at 512 records a shard)
+with its cell ``kv-r5-s4096-p512.ycsb-b-sat``.
+
+- the new configuration's shape rehearsed at a small size: 512 records a
+  shard (so key indices reach the stem's third hex digit), 8 shards,
+  5 replicas, window 4, through ``chipbench.run.run_cell`` unchanged, under
+  YCSB-B's and YCSB-A's proportions, held to ``kv_plain``;
+- the generator at 512 records a shard;
+- the program's ``devkv_table_bytes`` against the benchmark's hand count
+  (``peaks.table_bytes``), at the rehearsal's size on a device table and at
+  the configuration's own size from the planes' shapes alone;
+- the data: the new file against ``kv-r5-s4096.json`` and its entry, both
+  new cells through ``spec.load_cell``, and their reply samples.
+
+Nothing here touches a TPU.
+"""
+
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from chipbench import gen, peaks, run, spec, wire
+from rabia_tpu.apps import device_kv
+
+REPO = spec.REPO_ROOT
+CONFIG = REPO / "chipbench/configs/kv-r5-s4096-p512.json"
+BASE = REPO / "chipbench/configs/kv-r5-s4096.json"
+NEW_CELLS = ("kv-r5-s4096.ycsb-a-sat", "kv-r5-s4096-p512.ycsb-b-sat")
+SMALL = {"n_shards": 8, "window": 4, "records_at_capacity": 8 * 512}
+
+
+def _json(path: Path) -> dict:
+    return json.loads(path.read_text())
+
+
+# -- the data --------------------------------------------------------------------
+
+
+def test_new_configuration_is_kv_r5_s4096_at_512_records_a_shard():
+    new, old = _json(CONFIG), _json(BASE)
+    assert new["name"] == "kv-r5-s4096-p512"
+    for key in set(old) - {"name", "source", "deployment", "per_shard_capacity",
+                           "records_at_capacity", "reduced", "assumed"}:
+        assert new[key] == old[key], key  # the runner's keys, the guarantees
+    assert set(new) == set(old)
+    assert new["per_shard_capacity"] == 512
+    assert new["records_at_capacity"] == 4096 * 512 == 2_097_152
+    assert set(new["reduced"]) == set(old["reduced"])
+    for key in ("replica_processes", "adaptive_batching"):
+        assert new["reduced"][key] == old["reduced"][key], key
+    assert new["reduced"]["records_per_shard"].startswith("512 records a shard")
+    assert new["assumed"] == old["assumed"]
+    entry = {c["name"]: c for c in spec.load_benchmark()["configs"]}[new["name"]]
+    assert entry["source"] == new["source"] and len(entry["source"]) <= 200
+    assert entry["source"].startswith(old["source"]) and entry["source"] != old["source"]
+    assert entry["reduced"] == list(new["reduced"])
+    assert entry["file"] == str(CONFIG.relative_to(REPO))
+
+
+def test_both_new_cells_load_and_share_every_reader_and_traffic_file():
+    bench = spec.load_benchmark()
+    assert tuple(w["name"] for w in bench["workloads"][-2:]) == NEW_CELLS
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    pack, table = (spec.load_cell(name) for name in NEW_CELLS)
+    assert pack.chips == table.chips == 1
+    assert pack.config == _json(BASE) and table.config == _json(CONFIG)
+    assert pack.traffic == _json(REPO / "chipbench/traffic/ycsb-a-sat.json")
+    assert table.traffic == _json(REPO / "chipbench/traffic/ycsb-b-sat.json")
+    for cell in (pack, table):
+        assert len(cell.readers) == len(bench["per_layer"]) == 16
+        assert cell.traffic["check_block_share"] == 1 / 512
+        assert cell.traffic["in_flight_windows"] == 3
+    # the two cells of a pair take the same traffic key for key
+    assert table.traffic == spec.load_cell("kv-r5-s4096.ycsb-b-sat").traffic
+
+
+def test_hand_count_of_the_larger_table_and_its_window():
+    config = _json(CONFIG)
+    table = 4096 * 512 * 109 + 4096 * 4  # per slot 109 B, as at 256 a shard
+    assert peaks.table_bytes(config) == table == 228_605_952
+    ops = 64 * 4096
+    assert peaks.window_bytes(config) == 2 * table + ops * 108 + 12 == 485_523_468
+
+
+@pytest.mark.parametrize("seed", (792490177, 1, 2350000001, 2**31 + 35, 3))
+@pytest.mark.parametrize("cell", NEW_CELLS)
+def test_sample_is_not_empty_in_a_traced_window(cell, seed):
+    """A traced run measures at most 4 s, which hold some 4,000 blocks of
+    either cell after the load (256 or 512 waves) and the warm-up (some 700
+    blocks): 8 expected picks at 1/512 (PERF.md, PR 29)."""
+    c = spec.load_cell(cell)
+    picked = gen.Generator(seed, c.config, c.traffic).sampler()
+    first = c.config["per_shard_capacity"] + 800
+    assert sum(picked(i) for i in range(first, first + 4000)) >= 1
+
+
+# -- the generator at 512 records a shard ------------------------------------------
+
+
+def test_generator_at_512_records_a_shard():
+    config = dict(_json(CONFIG), n_shards=64, window=8)
+    traffic = dict(_json(REPO / "chipbench/traffic/ycsb-b-sat.json"), pool_windows=80)
+    g = gen.Generator(2**31 + 35, config, traffic)
+    keys = {g.key_bytes(s, j) for s in range(g.S) for j in range(g.n_keys)}
+    assert g.n_keys == 512 and len(keys) == 64 * 512  # distinct
+    # the stem's third hex digit is in use: index 0x1ff of shard 0x3f
+    assert g.key_bytes(63, 511)[:8] == b"003f1ff."
+    assert g.key_bytes(0, 256)[:8] == b"0000100."
+    assert len(g.load_waves()) == 512
+    waves = g.pool_waves()
+    kid = np.stack([w.kid for w in waves])  # [waves, S]
+    kind = np.stack([w.kind for w in waves])
+    assert kid.min() >= 0 and kid.max() == 511  # every op a record of its shard
+    assert (kid >= 256).any()
+    wave = waves[0]
+    data, sizes = g.encode(wave)
+    at = np.concatenate([[0], np.cumsum(sizes)])
+    for s in (0, 17, 63):  # the op on the wire names that record's key
+        klen = int(g.klen[s, wave.kid[s]])
+        op = data[at[s] : at[s + 1]].tobytes()
+        assert op[3 : 3 + klen] == g.key_bytes(s, int(wave.kid[s]))
+    # YCSB's law over 512 ranks: the hottest record takes 1/H(512, 0.99) of
+    # its shard's requests, the next 2^-0.99 of that
+    top = 1 / (1 / np.arange(1, 513) ** 0.99).sum()
+    assert top == pytest.approx(0.1426, abs=1e-3)
+    counts = np.stack([np.bincount(kid[:, s], minlength=512) for s in range(g.S)])
+    by_rank = -np.sort(-counts, axis=1) / len(waves)
+    assert by_rank[:, 0].mean() == pytest.approx(top, rel=0.05)
+    assert by_rank[:, 1].mean() == pytest.approx(top / 2**0.99, rel=0.08)
+    assert len(set(counts.argmax(axis=1).tolist())) > 32  # scattered per shard
+    assert (kind == wire.SET).mean() == pytest.approx(0.05, abs=0.005)
+
+
+# -- the program's gauge against the benchmark's hand count ---------------------------
+
+
+def test_table_bytes_of_the_planes_equal_the_hand_count_at_full_size():
+    """``devkv_table_bytes`` is the bytes of ``DeviceKVTable.state``'s seven
+    planes; at the configuration's own size they are counted from the
+    shapes, with no device."""
+    for path in (CONFIG, BASE, REPO / "chipbench/configs/kv-r5-s16384.json"):
+        config = _json(path)
+        planes = device_kv._state_planes(
+            config["n_shards"], config["per_shard_capacity"],
+            config["key_bytes"] // 4, config["value_bytes"] // 4,
+        )
+        assert len(planes) == 7
+        assert device_kv._plane_bytes(planes) == peaks.table_bytes(config), path.name
+
+
+# -- the configuration's shape, rehearsed ---------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory) -> Path:
+    """The repo's ``BENCHMARK.json`` and ``chipbench`` (a link), with the
+    new configuration cut to 8 shards x 512 records and window 4 (its file
+    in a directory that is searched first), every block's replies compared,
+    and a YCSB-A cell over it, which the benchmark itself does not hold."""
+    root = tmp_path_factory.mktemp("root")
+    (root / "chipbench").symlink_to(REPO / "chipbench", target_is_directory=True)
+    for sub in ("configs", "traffic"):
+        (root / "small" / sub).mkdir(parents=True)
+    small = dict(_json(CONFIG), **SMALL)
+    (root / "small/configs/kv-r5-s4096-p512.json").write_text(json.dumps(small))
+    for name in ("ycsb-a-sat", "ycsb-b-sat"):
+        traffic = _json(REPO / f"chipbench/traffic/{name}.json")
+        (root / f"small/traffic/{name}.json").write_text(
+            json.dumps(dict(traffic, check_block_share=1.0))
+        )
+    bench = spec.load_benchmark(REPO)
+    bench["paths"] = ["small"] + bench["paths"]
+    for c in bench["configs"]:
+        if c["name"] == "kv-r5-s4096-p512":
+            c["file"] = "small/configs/kv-r5-s4096-p512.json"
+    bench["workloads"].append(
+        {"name": "kv-r5-s4096-p512.ycsb-a-sat", "config": "kv-r5-s4096-p512",
+         "traffic": "ycsb-a-sat", "chips": 1, "why": "throwaway"}
+    )
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+@pytest.mark.parametrize("traffic", ("ycsb-b-sat", "ycsb-a-sat"))
+def test_rehearsal_at_512_records_a_shard_is_correct(root, traffic):
+    seen = {}
+    result = run.run_cell(
+        f"kv-r5-s4096-p512.{traffic}", 2**31 + 35, 0.5, False, root=root,
+        require_chip=False, engine_hook=lambda eng, runner: seen.update(eng=eng, run=runner),
+    )
+    eng, runner = seen["eng"], seen["run"]
+    assert eng._dev.P == 512 and eng._dev.n_shards == 8
+    assert result["correct"] is True and result["failed"] == 0
+    checks = result["checks"]
+    assert checks["replies_compared"]["value"] >= 8 * result["window"]["blocks_settled"] > 0
+    for name in ("reply_mismatches", "replica_mismatches", "lane_faults",
+                 "unsettled_blocks"):
+        assert checks[name]["value"] == 0, name
+    assert result["window"]["window_compiles"] == 0
+    # loaded to capacity, and all five replica stores rebuilt to the reference
+    # (replica_mismatches 0 compares every row of each with kv_plain)
+    assert [len(sm.store) for sm in eng.sms] == [4096] * 5
+    # the pool is 24 waves of 8 ops: 192 draws tell 5 % from 50 %, no more
+    updates = np.mean([np.mean(w.kind == wire.SET) for w in runner.stream[512:]])
+    assert updates == pytest.approx({"ycsb-b-sat": 0.05, "ycsb-a-sat": 0.5}[traffic], abs=0.12)
+    assert max(int(w.kid.max()) for w in runner.stream[512:]) >= 256
+    # the gauge, on a table that exists: 8 x 512 slots of 109 B and 8 counters
+    small = dict(_json(CONFIG), **SMALL)
+    snap = eng.metrics.snapshot()
+    assert snap["rabia_devkv_table_bytes"] == peaks.table_bytes(small) == 446_496
+    assert "rabia_devkv_table_bytes 446496" in eng.metrics.render_prometheus()
