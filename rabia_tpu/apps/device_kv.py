@@ -14,7 +14,7 @@ pure latency, not bandwidth.
 
 Design (built to move few bytes and make few round trips between host
 and device; what each choice is worth on the attached chip is not yet
-measured):
+measured, the value plane's apart):
 - the host pre-gathers each op's key/value bytes into fixed-width
   windows bucketed to the LARGEST ACTUAL width in the window (Ku/VWu,
   power-of-two, not the table max) and packs them as u32 words — the
@@ -22,6 +22,17 @@ measured):
 - the device table stores keys/values as u32 words too, so matching is
   word compares and updates are one-hot word selects, not byte-wise
   dynamic-index gathers/scatters (which lower poorly on TPU);
+- the mixed window's scan over the waves carries every plane but the
+  value plane: it decides from ``used``, the keys, the lengths and the
+  versions, records the slot each GET reads and each SET writes, and
+  the value rows are fetched and written once a window, after it
+  (:meth:`DeviceKVTable._resolve_values`, one-hot contractions on the
+  matrix unit). Measured on a TPU v5e (4096 shards, 64 waves, a full
+  table, a YCSB-B-like window; PERF.md, PR 36): the whole window
+  program takes 16.2 ms at 512 slots a shard where the per-wave
+  one-hot pass over the plane took 42.1, and 3.6 ms for 2.5 at 64
+  slots; with the rows fetched by ``take_along_axis`` and written by
+  a scatter, 46.8 and 11.7 ms: dynamic-index gathers do lower poorly;
 - per-op versions never travel: ``vers[t, s] = shard_ver[s] + t + 1``
   on a clean window, computed by the engine from its host-side mirror.
 
@@ -1022,12 +1033,95 @@ class DeviceKVTable:
                 max_phases=max_phases,
             )
 
+    @staticmethod
+    def _resolve_values(valw0, gslot, gwave, wslot, vwin):
+        """A mixed window's value reads and writes, resolved once from
+        what its scan recorded: ``(gval u32[G, S, VW4], valw_new)``.
+
+        ``valw0 u32[S, P, VW4]`` is the value plane as the window found
+        it, ``vwin u32[W, S, VW4]`` the ops' padded value rows,
+        ``wslot i32[W, S]`` the slot each wave's SET wrote (-1: none) and
+        ``gslot i32[G, S]`` the slot the GET of wave ``gwave[g]`` reads
+        (-1: none, a zero row). One op a shard a wave, so a wave never
+        reads what it writes: a GET answers with the row of the latest
+        earlier wave that wrote its slot, else with the plane's; a slot
+        ends the window holding its last writer's row, else its own.
+
+        The plane's rows are fetched, and the SETs' rows laid into the
+        plane, by a one-hot contraction with the shard as the batch axis
+        (so a table split over shards needs no collective): the words go
+        through the matrix unit a byte at a time, as int8 with an int32
+        accumulator, where a sum with one non-zero term cannot overflow
+        or round. A row forwarded from an earlier wave of the window is
+        picked from the ops' own rows by a select over the waves."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        W, P = wslot.shape[0], valw0.shape[1]
+        I8, I32, U32 = jnp.int8, jnp.int32, jnp.uint32
+
+        def to_bytes(a):  # u32[..., V] -> i8[..., 4V]: byte k of word v at kV + v
+            a = lax.bitcast_convert_type(a, I32)
+            return jnp.concatenate(
+                [((a << (24 - 8 * k)) >> 24).astype(I8) for k in range(4)], -1
+            )
+
+        def to_words(b):  # i8[..., 4V] -> u32[..., V]
+            V = b.shape[-1] // 4
+            w = 0
+            for k in range(4):
+                w |= (b[..., k * V : (k + 1) * V].astype(I32) & 0xFF) << (8 * k)
+            return lax.bitcast_convert_type(w, U32)
+
+        def rows(spec, onehot, src):
+            picked = jnp.einsum(
+                spec, onehot.astype(I8), to_bytes(src),
+                preferred_element_type=I32,
+            )
+            # each sum is one byte: handing it on as int8 lets the
+            # compiler write a quarter of the accumulator's bytes
+            return to_words(picked.astype(I8))
+
+        t = jnp.arange(W, dtype=I32)
+        # reads: the latest wave before the GET's that wrote its slot
+        wrote = (
+            (wslot[None] == gslot[:, None])
+            & (gslot[:, None] >= 0)
+            & (t[None, :, None] < gwave[:, None, None])
+        )  # [G, W, S]
+        src = jnp.where(wrote, t[None, :, None], -1).max(1)  # [G, S]
+        forwarded = jnp.where(
+            (src[:, None] == t[None, :, None])[..., None], vwin[None], U32(0)
+        ).sum(1, dtype=U32)
+        from_plane = jnp.where(src < 0, gslot, -1)
+        gval = forwarded | rows(
+            "gsp,spb->gsb", from_plane[:, :, None] == jnp.arange(P), valw0
+        )
+        # writes: each slot's last writer
+        later = (wslot[None] == wslot[:, None]) & (
+            t[None, :, None] > t[:, None, None]
+        )  # [W (writer), W (a later wave), S]
+        last = jnp.where(later.any(1), -1, wslot)  # [W, S]
+        lands = last[:, :, None] == jnp.arange(P)  # [W, S, P]
+        valw = jnp.where(
+            lands.any(0)[:, :, None], rows("wsp,wsb->spb", lands, vwin), valw0
+        )
+        return gval, valw
+
     def _build_mixed(self, Ku4: int, VWu4: int, Gp: int):
         """Jitted MIXED window: consensus + per-op kind mask over the
         same table — SET ops mutate (identical update rules to
         :meth:`_build_fused`), GET ops read the wave-entry state (reads
         in wave t observe every apply from waves < t — the host store's
         FIFO semantics), all in ONE scan over the waves.
+
+        The scan carries ``(used, key words, key len, version, value
+        len, shard_ver)`` and decides everything from them: match, found
+        bits, versions, DEL, slot choice, overflow. The value plane is
+        not in it. Each wave emits the slot its GET reads and the slot
+        its SET writes, and :meth:`_resolve_values` fetches and writes
+        the value rows once, after the scan, from the plane as the
+        window found it (scope ``value_resolve``).
 
         ``Gp`` (static) is the padded count of GET-bearing waves; the
         program gathers those waves' lookup outputs ON DEVICE (the host
@@ -1059,8 +1153,8 @@ class DeviceKVTable:
                 all_v1 = jnp.all(jnp.where(present, decided == V1, True))
 
             def wave_step(carry, inp):
-                ok_w, kind_t, klen_t, vlen_t, kwin_t, vwin_t = inp
-                used, keyw, klen, ver, valw, vlen, sver = carry
+                ok_w, kind_t, klen_t, vlen_t, kwin_t = inp
+                used, keyw, klen, ver, vlen, sver = carry
                 klen_t = klen_t.astype(jnp.int32)
                 vlen_t = vlen_t.astype(jnp.int32)
                 kind_t = kind_t.astype(jnp.int32)
@@ -1071,17 +1165,21 @@ class DeviceKVTable:
                         & (keyw == kwin_t[:, None, :]).all(-1)
                     )  # [S, P]
                     found = eq.any(1)
+                    hit = jnp.argmax(eq, 1)
                 # reads (GET/DEL/EXISTS found bits) are against the
                 # wave-entry state, before this wave's applies touch the
-                # table; gver/gval carry data for GET ops only (a DEL's
+                # table; gver/gslot carry data for GET ops only (a DEL's
                 # response is its found bit, an EXISTS's is a boolean)
                 with jax.named_scope("get_gather"):
                     rsel = (kind_t >= 2) & (klen_t > 0)
                     gsel = found & rsel
-                    oh_get = eq & (found & (kind_t == 2))[:, None]
+                    is_get = found & (kind_t == 2)
+                    oh_get = eq & is_get[:, None]
                     gver = (ver * oh_get).sum(1)
                     gvlen = (vlen * oh_get).sum(1)
-                    gval = (valw * oh_get[:, :, None]).sum(1)
+                    # the slot whose value this GET answers with, as the
+                    # table stood when the wave began (-1: no value)
+                    gslot = jnp.where(is_get, hit, -1)
                 with jax.named_scope("apply_set"):
                     # DEL applies: clear the matched slot (the table is
                     # compare-all associative — no probe chains to
@@ -1094,9 +1192,7 @@ class DeviceKVTable:
                     # SET applies: same one-hot word-select update as the
                     # pure-SET program, gated on this op BEING a SET
                     is_set = ok_w & (kind_t == 1)
-                    slot = jnp.where(
-                        found, jnp.argmax(eq, 1), jnp.argmax(~used, 1)
-                    )
+                    slot = jnp.where(found, hit, jnp.argmax(~used, 1))
                     full = used.all(1)
                     apply = is_set & (found | ~full)
                     overflow = jnp.any(is_set & ~found & full)
@@ -1109,31 +1205,36 @@ class DeviceKVTable:
                     klen = jnp.where(onehot, klen_t[:, None], klen)
                     new_ver = sver + 1
                     ver = jnp.where(onehot, new_ver[:, None], ver)
-                    valw = jnp.where(oh3, vwin_t[:, None, :], valw)
                     vlen = jnp.where(onehot, vlen_t[:, None], vlen)
                     sver = jnp.where(apply, new_ver, sver)
-                return (used, keyw, klen, ver, valw, vlen, sver), (
+                    # the slot this wave's SET writes its value row to
+                    # (-1: writes none)
+                    wslot = jnp.where(apply, slot, -1)
+                return (used, keyw, klen, ver, vlen, sver), (
                     overflow,
                     gsel,
                     gver,
                     gvlen,
-                    gval,
+                    gslot,
+                    wslot,
                 )
 
+            used0, keyw0, klen0, ver0, valw0, vlen0, sver0 = state
             kwin_full = jnp.pad(ops.kwin, ((0, 0), (0, 0), (0, K4 - Ku4)))
             vwin_full = jnp.pad(ops.vwin, ((0, 0), (0, 0), (0, VW4 - VWu4)))
-            xs = (present, kind_w, ops.klen, ops.vlen, kwin_full, vwin_full)
-            new_state, (over_w, gfound, gver, gvlen, gval) = lax.scan(
-                wave_step, state, xs
+            xs = (present, kind_w, ops.klen, ops.vlen, kwin_full)
+            (
+                (used, keyw, klen, ver, vlen, sver),
+                (over_w, gfound, gver, gvlen, gslot, wslot),
+            ) = lax.scan(
+                wave_step, (used0, keyw0, klen0, ver0, vlen0, sver0), xs
             )
             with jax.named_scope("flags"):
                 flags = jnp.stack(
                     [
                         all_v1.astype(I32),
                         jnp.any(over_w).astype(I32),
-                        jnp.any(
-                            new_state[6] >= jnp.int32(2**31 - 2)
-                        ).astype(I32),
+                        jnp.any(sver >= jnp.int32(2**31 - 2)).astype(I32),
                     ]
                 )
             # device-side gather of the GET-bearing waves + two-plane
@@ -1142,8 +1243,13 @@ class DeviceKVTable:
                 gfound_g = jnp.take(gfound, gidx, axis=0).astype(I32)
                 gver_g = jnp.take(gver, gidx, axis=0)
                 gvlen_g = jnp.take(gvlen, gidx, axis=0)
-                gval_g = jnp.take(gval, gidx, axis=0)
                 meta = jnp.stack([gver_g, (gvlen_g << 1) | gfound_g])
+            with jax.named_scope("value_resolve"):
+                gval_g, valw = DeviceKVTable._resolve_values(
+                    valw0, jnp.take(gslot, gidx, axis=0), gidx, wslot,
+                    vwin_full,
+                )
+            new_state = (used, keyw, klen, ver, valw, vlen, sver)
             return new_state, flags, meta, gval_g
 
         return jax.jit(mixed, static_argnames=("W", "max_phases"))

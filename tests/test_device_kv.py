@@ -1603,3 +1603,305 @@ class TestPlanePoolBounds:
         assert len(pool) == _PLANE_POOL_CAP
         assert pool.outcomes["fresh"] >= 3 * _PLANE_POOL_CAP
         _assert_same_replies(pairs)
+
+
+# -- the mixed window's value resolve: reads and writes once a window ------
+#
+# The scan over the waves carries no value plane: it records which slot
+# each GET reads and each SET writes, and ``_resolve_values`` fetches and
+# writes the rows once, after it. The cases below are one window in one
+# shard each (every shard runs the same waves with values of its own).
+
+_RESOLVE_W = 8  # waves a window: most cases fill fewer, the rest are fillers
+
+
+def _resolve_case(case: str, P: int, vlen: int) -> tuple:
+    """``(prefill waves, the window's waves)``, a wave being
+    ``(opcode letter, key, value)`` for every shard."""
+    val = lambda tag: (tag * vlen)[:vlen]
+    if case == "set_then_get":
+        return [], [("S", "k", val("a")), ("G", "k", "")]
+    if case == "set_set_get":
+        return [], [("S", "k", val("a")), ("S", "k", val("b")), ("G", "k", "")]
+    if case == "get_before_its_set":
+        return [("S", "k", val("o"))], [
+            ("G", "k", ""), ("S", "k", val("n")), ("G", "k", ""),
+        ]
+    if case == "del_then_insert_into_freed_slot":
+        # at P == 1 the freed slot is the only one j can take
+        return [("S", "k", val("o"))], [
+            ("D", "k", ""), ("S", "j", val("n")), ("G", "j", ""), ("G", "k", ""),
+        ]
+    if case == "set_refused_by_full_shard":
+        fill = [("S", f"f{i}", val("f")) for i in range(P)]
+        return fill, [("S", "one_more", val("x")), ("G", "f0", "")]
+    if case == "fillers_past_depth":
+        return [("S", "k", val("o"))], [("G", "k", "")]
+    if case == "a_full_window":
+        return [], [("S", "k", val("a"))] + [
+            ("G", "k", "") if t % 2 else ("S", "k", val("bcdefgh"[t // 2]))
+            for t in range(1, _RESOLVE_W)
+        ]
+    raise KeyError(case)
+
+
+def _resolve_block(n: int, wave: tuple):
+    from rabia_tpu.apps.kvstore import KVOperation, KVOpType, encode_op_bin
+
+    op, key, value = wave
+    if op == "S":
+        cmds = [[encode_set_bin(key, f"{s}{value}"[: len(value)])] for s in range(n)]
+    else:
+        kind = {"G": KVOpType.Get, "D": KVOpType.Delete}[op]
+        cmds = [[encode_op_bin(KVOperation(kind, key))] for _ in range(n)]
+    return build_block(list(range(n)), cmds)
+
+
+@pytest.mark.parametrize("vlen", [5, 64], ids=["v5", "v64"])
+@pytest.mark.parametrize("P", [1, 4, 64], ids=["p1", "p4", "p64"])
+@pytest.mark.parametrize(
+    "case",
+    [
+        "set_then_get", "set_set_get", "get_before_its_set",
+        "del_then_insert_into_freed_slot", "set_refused_by_full_shard",
+        "fillers_past_depth", "a_full_window",
+    ],
+)
+def test_value_resolve_in_one_window(case, P, vlen):
+    n = 2
+    pre, win = _resolve_case(case, P, vlen)
+    engines = []
+    for device in (True, False):
+        e = _mk(
+            n, device=device, window=_RESOLVE_W,
+            device_store_kw={"per_shard_capacity": P, "value_width": 64},
+        )
+        if device:
+            # no host segment outlives its window: every GET frame below
+            # is made of the bytes the device program answered with
+            e._dev_vseg_cap = 1
+        for k in range(0, len(pre), _RESOLVE_W):
+            for wave in pre[k : k + _RESOLVE_W]:
+                e.submit_block(_resolve_block(n, wave))
+            e.flush()
+        futs = [e.submit_block(_resolve_block(n, wave)) for wave in win]
+        e.flush()
+        engines.append((e, futs))
+    (dev, fd), (host, fh) = engines
+    refused = case == "set_refused_by_full_shard"
+    assert dev._dev_active != refused
+    for t, (a, b) in enumerate(zip(fd, fh)):
+        assert _frames(a) == _frames(b), (case, t)
+    want = _store_content(host.sms[0], n)
+    if not refused:
+        assert dev._dev_value_fetch["unused"] == 0
+        rows = dev._dev.dump()["rows"]
+        assert {(s, k): (v, ver) for s, k, v, ver in rows} == want
+        dev._demote_device_store()
+    for sm in dev.sms:
+        assert _store_content(sm, n) == want
+
+
+def _random_table_and_windows(seed: int, n: int, P: int, W: int, mesh):
+    """A table with garbage rows under its clear ``used`` bits and three
+    random windows of all four kinds over a keyspace a little larger than
+    a shard holds: inserts into freed slots, refused SETs, fillers."""
+    from rabia_tpu.apps.device_kv import DeviceKVTable, DeviceWindowOps
+    from rabia_tpu.parallel.mesh import MeshPhaseKernel
+
+    rng = np.random.default_rng(seed)
+    tab = DeviceKVTable(n, MeshPhaseKernel(n, 3, mesh), per_shard_capacity=P)
+    state = list(tab.state)
+    state[4] = tab._put_shards(
+        rng.integers(0, 2**32, size=state[4].shape, dtype=np.uint32)
+    )
+    windows = []
+    for depth in (W, W, W - 5):
+        S = tab.S
+        kind = np.zeros((W, S), np.int8)
+        klen = np.zeros((W, S), np.int16)
+        vlen = np.zeros((W, S), np.int16)
+        kwin = np.zeros((W, S, tab.K), np.uint8)
+        vwin = np.zeros((W, S, tab.VW), np.uint8)
+        kk = rng.choice([1, 2, 3, 4], size=(depth, n), p=(0.4, 0.3, 0.15, 0.15))
+        kid = rng.integers(0, P + 3, size=(depth, n))
+        kl = 4 + kid % 5
+        kb = np.zeros((depth, n, tab.K), np.uint8)
+        kb[..., 0], kb[..., 1], kb[..., 2:4] = kid, kid >> 8, 0x41
+        for j in range(4, 9):
+            kb[..., j] = np.where(kl > j, 0x30 + kid % 7, 0)
+        vl = np.where(kk == 1, rng.integers(0, tab.VW + 1, size=(depth, n)), 0)
+        vb = rng.integers(0, 256, size=(depth, n, tab.VW), dtype=np.uint8)
+        vb[np.arange(tab.VW) >= vl[..., None]] = 0
+        kind[:depth, :n], klen[:depth, :n], vlen[:depth, :n] = kk, kl, vl
+        kwin[:depth, :n], vwin[:depth, :n] = kb, vb
+        ops = DeviceWindowOps(
+            klen, vlen, kwin.view(np.uint32), vwin.view(np.uint32)
+        )
+        gets = np.nonzero((kind >= 2).any(1))[0]
+        windows.append((depth, kind, ops, gets))
+    # the last window reads back a third of its GET waves: Gp < W
+    depth, kind, ops, gets = windows[-1]
+    windows[-1] = (depth, kind, ops, gets[: max(1, len(gets) // 3)])
+    return tab, tuple(state), windows
+
+
+def _same_outputs(got, want) -> None:
+    import jax
+
+    got = jax.tree_util.tree_leaves(got)
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want) == 10  # seven planes, flags, meta, gval
+    for i, (a, b) in enumerate(zip(got, want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, i
+        assert np.array_equal(a, b), (i, int((a != b).sum()))
+
+
+def _run_both_programs(tab, state, windows, reference=None) -> list:
+    """Chain the windows through ``mixed_apply`` (and the per-wave
+    reference, when given, from the same states); the outputs of each."""
+    S, W = tab.S, windows[0][1].shape[0]
+    alive = np.ones((S, 3), bool)
+    base = np.zeros(S, np.int32)
+    outs = []
+    for depth, kind, ops, gets in windows:
+        new = tab.mixed_apply(alive, base, depth, kind, gets, ops, W=W, state=state)
+        if reference is not None:
+            gidx = np.zeros(new[3].shape[0], np.int32)
+            gidx[: len(gets)] = gets
+            old = reference(
+                state, alive, base, np.int32(depth), kind, gidx, ops,
+                W=W, max_phases=4,
+            )
+            _same_outputs(new, old)
+        outs.append(new)
+        state = new[0]
+    return outs
+
+
+@pytest.mark.parametrize(
+    "seed,n,P,W",
+    [(s, 16, 8, 32) for s in range(6)] + [(100, 8, 1, 8), (101, 8, 64, 16)],
+)
+def test_mixed_program_equals_the_per_wave_program(seed, n, P, W):
+    # all four outputs, bit for bit, every word of the new value plane in
+    # slots whose used bit is clear included
+    import jax
+
+    from per_wave_mixed import build_per_wave_mixed
+
+    tab, state, windows = _random_table_and_windows(
+        seed, n, P, W, make_mesh(jax.devices()[:1])
+    )
+    outs = _run_both_programs(
+        tab, state, windows, build_per_wave_mixed(tab, tab.K4, tab.VW4)
+    )
+    flags = np.array([np.asarray(o[1]) for o in outs])
+    assert flags[:, 0].all()  # every window decided
+    if P < 64:
+        assert flags[:, 1].any()  # and some SET met a full shard
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _scans(sub)
+
+
+def test_no_scan_of_the_mixed_program_carries_the_value_plane():
+    import jax
+
+    n, P, W = 16, 8, 4
+    tab, state, windows = _random_table_and_windows(
+        3, n, P, W + 5, make_mesh(jax.devices()[:1])
+    )
+    depth, kind, ops, gets = windows[0]
+    fn = tab._build_mixed(tab.K4, tab.VW4, 8)
+    closed = jax.make_jaxpr(
+        lambda *a: fn(*a, W=W + 5, max_phases=4)
+    )(state, np.ones((tab.S, 3), bool), np.zeros(tab.S, np.int32),
+      np.int32(depth), kind, np.zeros(8, np.int32), ops)
+    plane = (tab.S, P, tab.VW4)
+    assert tuple(state[4].shape) == plane != tuple(state[1].shape)
+    scans = list(_scans(closed.jaxpr))
+    carried = [
+        tuple(v.aval.shape)
+        for eqn in scans
+        for v in eqn.invars[
+            eqn.params["num_consts"] : eqn.params["num_consts"]
+            + eqn.params["num_carry"]
+        ]
+    ]
+    assert (tab.S, P) in carried  # the scan over the waves was looked at
+    assert plane not in carried
+    # nor does any scan read or emit it by the wave
+    assert not [
+        v.aval.shape
+        for eqn in scans
+        for v in list(eqn.invars) + list(eqn.outvars)
+        if tuple(v.aval.shape)[-3:] == plane
+    ]
+
+
+def test_sharded_mixed_program_equals_one_device_and_adds_no_collective():
+    import re
+
+    import jax
+
+    from per_wave_mixed import build_per_wave_mixed
+
+    n, P, W = 16, 8, 16
+    results, texts = [], {}
+    for devices in (1, 4):
+        tab, state, windows = _random_table_and_windows(
+            7, n, P, W, make_mesh(jax.devices()[:devices])
+        )
+        results.append(_run_both_programs(tab, state, windows))
+        if devices == 4:
+            depth, kind, ops, gets = windows[0]
+            args = (
+                state, tab.kernel.place(np.ones((tab.S, 3), bool)),
+                tab._put_shards(np.zeros(tab.S, np.int32)), np.int32(depth),
+                tab._put_waves(kind), np.zeros(16, np.int32),
+                tab._place_ops(ops),
+            )
+            for name, fn in (
+                ("resolve_once", tab._build_mixed(tab.K4, tab.VW4, 16)),
+                ("per_wave", build_per_wave_mixed(tab, tab.K4, tab.VW4)),
+            ):
+                texts[name] = (
+                    fn.lower(*args, W=W, max_phases=4).compile().as_text()
+                )
+    for one, four in zip(*results):
+        _same_outputs(four, one)
+
+    def collectives(text: str) -> list:
+        """``(collective, the jax op that asked for it)`` of each one."""
+        found = re.findall(
+            r"= \S+ (all-gather|all-reduce|all-to-all|collective-permute|"
+            r"reduce-scatter|collective-broadcast)(?:-start)?\("
+            r".*?op_name=\"([^\"]*)\"", text,
+        )
+        return sorted(found)
+
+    # the shard is the resolve's batch axis, so a table split four ways
+    # needs nothing from another device. What the per-wave program had
+    # stays: the consensus's exchange over the replica axis and the
+    # scalar reductions behind the three flags
+    got = collectives(texts["resolve_once"])
+    assert got == collectives(texts["per_wave"])
+    assert got and all(
+        op == "all-reduce" or "/consensus/" in name for op, name in got
+    )
+    assert not [name for _, name in got if "value_resolve" in name]
+    assert "value_resolve" in texts["resolve_once"]
