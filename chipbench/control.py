@@ -21,7 +21,10 @@ small size and this command shows it on the chip at the cell's own size.
 - ``lagging_replica``: the last replica's store misses the newest write of
   one key at the final sync; breaks "all R replica stores equal".
 
-There is no exchange between chips to leave out: every cell is one chip.
+No fault leaves out an exchange between chips: on four chips
+(``kv-r5-s16384``, since PR 30) the chips split the shard axis, each holds
+its shards with all their replicas, and no op's outcome depends on another
+chip's. ``half_batch`` there blanks the shards of two of the four chips.
 """
 
 from __future__ import annotations
@@ -54,17 +57,12 @@ def _dropped_window(eng, run=None, every: int = 5) -> None:
 
 
 def _half_batch(eng, run=None) -> None:
-    """Key length 0 means "no op here": blank it for the upper shards in
-    whichever form the window was packed."""
+    """Key length 0 means "no op here": blank it for the upper shards."""
     dev = eng._dev
     half = dev.n_shards // 2
     place = dev._place_ops
 
     def place_half(ops):
-        if hasattr(ops, "dkl"):
-            dkl = ops.dkl.copy()
-            dkl[half:] = 0
-            return place(ops._replace(dkl=dkl))
         klen = ops.klen.copy()
         klen[:, half:] = 0
         return place(ops._replace(klen=klen))

@@ -1,6 +1,6 @@
 """Pack + resolve on the host: median length of the program's
-``rabia.cycle.pack`` span (one per window: parse, plane allocation, gather
-and the dictionary attempt), in milliseconds."""
+``rabia.cycle.pack`` span (one per window: parse, plane allocation and
+gather), in milliseconds."""
 
 import statistics
 

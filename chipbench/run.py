@@ -50,29 +50,48 @@ def _warn(msg: str) -> None:
     print(f"chipbench: warning: {msg}", file=sys.stderr, flush=True)
 
 
+def engine_options(config: dict) -> dict:
+    """The file's ``engine`` object, held to the keywords ``MeshEngine`` takes."""
+    import inspect
+
+    from rabia_tpu.parallel import MeshEngine
+
+    return spec.engine_options(config, inspect.signature(MeshEngine).parameters)
+
+
 def build_engine(config: dict):
     from rabia_tpu.apps.vector_kv import VectorShardedKV
+    from rabia_tpu.core.errors import ValidationError
     from rabia_tpu.native import build as native_build
     from rabia_tpu.parallel import MeshEngine, make_mesh
 
     # without g++ the pack would silently take its numpy path
     if native_build.load_hostkernel() is None or native_build.load_codec() is None:
         raise NoChip("native hostkernel or codec failed to build or load")
+    options = engine_options(config)
+    print(f"chipbench: engine options {json.dumps(options)}", file=sys.stderr, flush=True)
     S = int(config["n_shards"])
     # the host replicas stay empty until the final sync, which rebuilds them
-    return MeshEngine(
-        lambda: VectorShardedKV(S, capacity=1 << 12),
-        n_shards=S,
-        n_replicas=int(config["n_replicas"]),
-        mesh=make_mesh(),
-        window=int(config["window"]),
-        device_store=True,
-        device_store_kw={
-            "per_shard_capacity": int(config["per_shard_capacity"]),
-            "key_lanes": int(config["key_bytes"]) // 8,
-            "value_width": int(config["value_bytes"]),
-        },
-    )
+    try:
+        return MeshEngine(
+            lambda: VectorShardedKV(S, capacity=1 << 12),
+            n_shards=S,
+            n_replicas=int(config["n_replicas"]),
+            mesh=make_mesh(),
+            window=int(config["window"]),
+            device_store=True,
+            device_store_kw={
+                "per_shard_capacity": int(config["per_shard_capacity"]),
+                "key_lanes": int(config["key_bytes"]) // 8,
+                "value_width": int(config["value_bytes"]),
+            },
+            **options,
+        )
+    except ValidationError as e:
+        raise spec.SpecError(
+            f"{config.get('name', '?')}: the engine refused the configuration "
+            f"(engine options {json.dumps(options)}): {e}"
+        ) from None
 
 
 class Runner:
@@ -416,6 +435,9 @@ def main(argv=None) -> int:
         )
     except NoChip as e:
         print(f"chipbench: {e}; this benchmark measures on the chip only", file=sys.stderr)
+        return 2
+    except spec.SpecError as e:
+        print(f"chipbench: {e}", file=sys.stderr)
         return 2
     sys.stderr.flush()
     print(json.dumps(result), flush=True)

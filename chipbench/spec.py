@@ -22,6 +22,13 @@ class SpecError(ValueError):
     """``BENCHMARK.json`` or a file it names is missing or malformed."""
 
 
+# what the runner sets from a configuration's shapes; a file states a shape once
+ENGINE_RESERVED = frozenset(
+    {"sm_factory", "n_shards", "n_replicas", "mesh", "window", "device_store",
+     "device_store_kw"}
+)
+
+
 @dataclass(frozen=True)
 class Cell:
     name: str
@@ -58,6 +65,31 @@ def _load_reader(path: Path):
     if not callable(getattr(mod, "read", None)):
         raise SpecError(f"{path}: a metric reader defines read(ctx)")
     return mod.read
+
+
+def engine_options(config: dict, accepted) -> dict:
+    """A configuration's ``engine`` object: the engine's keyword options that
+    the deployment states beyond its shapes (its batching, its pipe depth, its
+    read path), passed on as they stand. ``accepted`` holds the names of the
+    engine's parameters. A file without the key states none."""
+    name = config.get("name", "?")
+    options = config.get("engine", {})
+    if not isinstance(options, dict):
+        raise SpecError(f"{name}: engine: an object of options, not {options!r}")
+    for key, value in options.items():
+        if key in ENGINE_RESERVED:
+            raise SpecError(
+                f"{name}: engine.{key}: the runner sets it from the "
+                "configuration's shapes, and a file states a shape once"
+            )
+        if key not in accepted:
+            raise SpecError(
+                f"{name}: engine.{key}: not an option of the engine; it takes "
+                f"{sorted(set(accepted) - ENGINE_RESERVED)}"
+            )
+        if isinstance(value, (dict, list)):
+            raise SpecError(f"{name}: engine.{key}: a JSON scalar, not {value!r}")
+    return dict(options)
 
 
 def load_benchmark(root: Path = REPO_ROOT) -> dict:

@@ -9,12 +9,15 @@ describes a topology, at import or later).
 - the plain reference and the wire format against the program's own
   ``KVStore`` and codec (a second witness, never used by a run);
 - the trace reducer against a small trace recorded on the chip;
-- the bytes function against a hand count;
+- the bytes function and ``window_hbm_share`` against hand counts;
+- a configuration's ``engine`` object: passed on to ``MeshEngine``, refused
+  where it restates a shape, names no option or holds no scalar;
 - ``BENCHMARK.json``: names, units, bounds, and every file found by name.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import re
 from pathlib import Path
@@ -48,6 +51,11 @@ TINY_TRAFFIC = {
         "pool_windows": 5, "warmup_windows": 4, "check_block_share": 1.0,
     },
 }
+# throwaway configurations that state an engine: the shapes above, a file each
+TINY_ENGINES = {
+    "tiny-depth2": {"device_store_inflight": 2},
+    "tiny-governed": {"latency_target_ms": 50.0, "min_window": 2, "max_window": 4},
+}
 THROWAWAY_METRIC = '''"""Throwaway: blocks submitted per window dispatched, where the loop is
 closed (a reader with nothing to read elsewhere returns nothing)."""
 
@@ -73,15 +81,21 @@ def root(tmp_path_factory) -> Path:
     for name, traffic in TINY_TRAFFIC.items():
         (root / f"extra/traffic/{name}.json").write_text(json.dumps(traffic))
     (root / "extra/metrics/blocks_per_window.py").write_text(THROWAWAY_METRIC)
+    for name, engine in TINY_ENGINES.items():
+        (root / f"extra/configs/{name}.json").write_text(
+            json.dumps(dict(TINY_CONFIG, name=name, engine=engine))
+        )
     bench = spec.load_benchmark(REPO)
     bench["paths"] = bench["paths"] + ["extra"]
-    bench["configs"].append(
-        {"name": "tiny", "source": "test", "file": "extra/configs/tiny.json",
-         "reduced": [], "why": "throwaway"}
-    )
-    for traffic in TINY_TRAFFIC:
+    cells = [("tiny", t) for t in TINY_TRAFFIC] + [(c, "tiny-closed") for c in TINY_ENGINES]
+    for config in ("tiny", *TINY_ENGINES):
+        bench["configs"].append(
+            {"name": config, "source": "test", "file": f"extra/configs/{config}.json",
+             "reduced": [], "why": "throwaway"}
+        )
+    for config, traffic in cells:
         bench["workloads"].append(
-            {"name": f"tiny.{traffic}", "config": "tiny", "traffic": traffic,
+            {"name": f"{config}.{traffic}", "config": config, "traffic": traffic,
              "chips": 1, "why": "throwaway"}
         )
     bench["per_layer"].append(
@@ -130,6 +144,95 @@ def test_planted_fault_is_not_correct(root, fault):
         "lagging_replica": {"replica_mismatches"},
     }[fault]
     assert expect <= failed
+
+
+# -- a configuration states its engine -----------------------------------------
+
+
+class _Built(Exception):
+    """Raised by the recorder in ``MeshEngine``'s place: nothing is built."""
+
+
+@pytest.mark.parametrize(
+    "name", ["kv-r5-s4096", "kv-r3-s64", "kv-r5-s16384", "kv-r5-s4096-p512"]
+)
+def test_committed_configuration_builds_the_engine_it_built_before(monkeypatch, name):
+    """No committed file states an ``engine``: ``MeshEngine`` gets the factory
+    and the six keywords that ``build_engine`` passed before the key existed,
+    and nothing else."""
+    import rabia_tpu.parallel as parallel
+
+    calls = []
+
+    @functools.wraps(parallel.MeshEngine)
+    def recorder(*args, **kw):
+        calls.append((args, kw))
+        raise _Built
+
+    monkeypatch.setattr(parallel, "MeshEngine", recorder)
+    config = json.loads((REPO / f"chipbench/configs/{name}.json").read_text())
+    with pytest.raises(_Built):
+        run.build_engine(config)
+    ((args, kw),) = calls
+    assert len(args) == 1 and callable(args[0])
+    mesh = kw.pop("mesh")
+    assert dict(mesh.shape) == dict(parallel.make_mesh().shape)
+    assert kw == {
+        "n_shards": config["n_shards"], "n_replicas": config["n_replicas"],
+        "window": config["window"], "device_store": True,
+        "device_store_kw": {
+            "per_shard_capacity": config["per_shard_capacity"],
+            "key_lanes": config["key_bytes"] // 8,
+            "value_width": config["value_bytes"],
+        },
+    }
+
+
+def test_engine_option_reaches_the_engine_and_the_run_is_correct(root, capfd):
+    seen = []
+    result = run.run_cell(
+        "tiny-depth2.tiny-closed", 2**31 + 13, 0.4, False, root=root,
+        require_chip=False, engine_hook=lambda eng, runner: seen.append(eng),
+    )
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["checks"]["lane_faults"]["value"] == 0
+    assert seen[0]._dev_inflight == 2 and seen[0].latency_target_ms is None
+    assert 'chipbench: engine options {"device_store_inflight": 2}' in capfd.readouterr().err
+
+
+def test_engine_options_turn_the_governor_on(root):
+    cell = spec.load_cell("tiny-governed.tiny-closed", root)
+    eng = run.build_engine(cell.config)
+    try:
+        assert eng.latency_target_ms == 50.0
+        assert (eng.min_window, eng.max_window, eng.window) == (2, 4, 4)
+        assert eng._dev_inflight == 1  # the engine's own default under a target
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize(
+    "key, value",
+    [
+        ("window", 8),  # reserved: the file's own ``window`` is the shape
+        ("device_store", False),
+        ("device_read_path", True),  # no such option
+        ("device_store_inflight", [2]),  # not a scalar
+        ("latency_target_ms", 0),  # the engine refuses the value
+    ],
+)
+def test_engine_object_is_refused_by_key(key, value):
+    config = dict(TINY_CONFIG, engine={key: value})
+    with pytest.raises(spec.SpecError) as refused:
+        run.build_engine(config)
+    assert key in str(refused.value) and "tiny" in str(refused.value)
+
+
+def test_a_spec_error_exits_with_its_text_and_no_result(capsys):
+    rc = run.main(["--workload", "no-such.cell", "--seed", "1", "--seconds", "1"])
+    out = capsys.readouterr()
+    assert rc != 0 and out.out == "" and "Traceback" not in out.err
+    assert "unknown workload 'no-such.cell'" in out.err
 
 
 def test_no_chip_no_result(capsys):
@@ -268,6 +371,35 @@ def test_window_bytes_hand_count():
         peaks.hbm_peak("TPU v9")
 
 
+@pytest.mark.parametrize(
+    "busy_s, windows, share",
+    [
+        # 1,335,820 B at 819 GB/s = 1.631 us, over 28.5305 us of device a window
+        (171_183e-9, 6, 1_335_820 / 819e9 / 28.5305e-6 * 100),
+        (171_183e-9, 0, None),  # no window dispatched in the trace
+        (0.0, 6, None),  # no device op in the window
+    ],
+)
+def test_window_hbm_share_is_one_chips_share(busy_s, windows, share):
+    """A chip's bytes over a chip's peak and a chip's time: the same trace
+    read as four chips' (``busy_s`` is their mean) gives a quarter."""
+    read = spec.load_cell("kv-r3-s64.ycsb-a-sat", REPO).readers["window_hbm_share"]
+    config = json.loads((REPO / "chipbench/configs/kv-r3-s64.json").read_text())
+    # 2 x (64 x 64 slots x 109 B + 64 x 4 B) + 4,096 ops x (100 + 8) B + 12 B
+    assert peaks.window_bytes(config) == 2 * 446_720 + 4096 * 108 + 12 == 1_335_820
+
+    def on(n_devices):
+        return read({"trace": {"busy_s": busy_s, "n_devices": n_devices},
+                     "windows": windows, "config": config,
+                     "device_kind": "TPU v5 lite"})
+
+    if share is None:
+        assert on(1) is None and on(4) is None
+    else:
+        assert on(1) == pytest.approx(share, rel=1e-12)
+        assert on(4) == on(1) / 4
+
+
 def test_interval_arithmetic():
     s, e = trace._merge(np.array([5.0, 0.0, 1.0, 9.0]), np.array([7.0, 2.0, 4.0, 10.0]))
     assert s.tolist() == [0.0, 5.0, 9.0] and e.tolist() == [4.0, 7.0, 10.0]
@@ -353,6 +485,7 @@ def test_benchmark_json_names_units_and_files():
     for c in bench["configs"]:
         held = json.loads((REPO / c["file"]).read_text())
         assert held["name"] == c["name"] and set(c["reduced"]) == set(held["reduced"])
+        run.engine_options(held)  # an ``engine`` object, if the file states one
         assert all(NAME.match(k) for k in c["reduced"])
     four = sum(w["chips"] == 4 for w in bench["workloads"])
     assert four <= max(1, len(bench["workloads"]) // 2)
