@@ -371,6 +371,7 @@ class DeviceKVTable:
         per_shard_capacity: int = 64,
         key_lanes: int = 4,
         value_width: int = 64,
+        rungs: Sequence[int] = (),
     ) -> None:
         import jax
         import jax.numpy as jnp
@@ -408,6 +409,18 @@ class DeviceKVTable:
         # new program: the engine's latency governor must not read that
         # dispatch's wall time as window latency
         self.compiled_on_last_call = False
+        # the window ladder: every static window size W the owner will
+        # dispatch at (a governed engine's rungs). With more than one
+        # rung a signature is built for all of them at once, when its
+        # kind and widths are first needed at any (see _program), so a
+        # window that lands on another rung later finds its program.
+        # None or one rung: each signature is built where it is needed
+        self.rungs = tuple(sorted({int(w) for w in rungs}))
+        self._laddered = len(self.rungs) > 1
+        self._building_ladder = False
+        # programs the ladder built ahead of need, ever (the engine's
+        # devkv_ladder_programs_total reads it)
+        self.ladder_programs = 0
         # host bytes device_put for window dispatches, ever (the engine's
         # devkv_upload_bytes_total reads it)
         self.upload_bytes = 0
@@ -701,14 +714,49 @@ class DeviceKVTable:
             "rabia.dispatch.place", bytes=nbytes, devices=self.n_devices
         )
 
-    def _program(self, key: tuple, build):
-        """The jitted program of signature ``key``, built by ``build()``
-        on a miss (which ``compiled_on_last_call`` then says)."""
+    def _program(self, key: tuple, W: int, build, at_rung):
+        """The jitted program of signature ``key`` (static window size
+        ``W``), built by ``build()`` on a miss (which
+        ``compiled_on_last_call`` then says).
+
+        On a table with a ladder a miss builds the whole ladder:
+        ``at_rung(w)`` dispatches an EMPTY window (depth 0, no ops) of
+        the same kind and widths at rung ``w`` through the caller's own
+        dispatch method, once for every other rung, and its outputs are
+        dropped. The programs are functional (nothing is donated), so
+        the table is untouched; each sibling is traced, compiled and run
+        once with operands of exactly the types a real window places,
+        so the real window's call is a cache hit. All of it lies inside
+        the dispatch that first needed the kind (span
+        ``rabia.ladder.build``), which ``compiled_on_last_call`` marks
+        as it marks any first call."""
         fn = self._fused_cache.get(key)
         self.compiled_on_last_call = fn is None
         if fn is None:
             fn = self._fused_cache[key] = build()
+            if self._building_ladder:
+                self.ladder_programs += 1
+            else:
+                self._build_ladder(key, W, at_rung)
         return fn
+
+    def _build_ladder(self, key: tuple, W: int, at_rung) -> None:
+        import jax
+
+        if not self._laddered:
+            return
+        others = [w for w in self.rungs if w != W]
+        self._building_ladder = True
+        try:
+            with device_annotation(
+                "rabia.ladder.build", sig=str(key),
+                rungs=",".join(map(str, others)),
+            ):
+                for w in others:
+                    jax.block_until_ready(at_rung(w))
+        finally:
+            self._building_ladder = False
+        self.compiled_on_last_call = True  # the siblings' calls reset it
 
     def _calling(self, key: tuple):
         """The span around the jitted call that follows ``_program(key,
@@ -794,7 +842,13 @@ class DeviceKVTable:
                 [kwin, np.zeros((pad,) + kwin.shape[1:], kwin.dtype)]
             )
         key = ("get", W, kwin.shape[2])
-        fn = self._program(key, lambda: self._build_lookup(key[2]))
+        fn = self._program(
+            key, W, lambda: self._build_lookup(key[2]),
+            lambda w: self.lookup_window(
+                alive, base, 0, (klen[:0], kwin[:0]), W=w,
+                max_phases=max_phases, state=state,
+            ),
+        )
         with self._placing(alive, base, klen, kwin):
             alive_d = self.kernel.place(alive)
             base_d = self._put_shards(base)
@@ -876,7 +930,10 @@ class DeviceKVTable:
             )
         key = ("ro", W, kwin.shape[2])
         fn = self._program(
-            key, lambda: self._build_lookup_only(key[2])
+            key, W, lambda: self._build_lookup_only(key[2]),
+            lambda w: self.lookup_only(
+                (klen[:0], kwin[:0]), W=w, state=state
+            ),
         )
         with self._placing(klen, kwin):
             klen_d = self._put_waves(klen)
@@ -1017,7 +1074,13 @@ class DeviceKVTable:
                 )
             )
         key = (W, ops.kwin.shape[2], ops.vwin.shape[2])
-        fn = self._program(key, lambda: self._build_fused(key[1], key[2]))
+        fn = self._program(
+            key, W, lambda: self._build_fused(key[1], key[2]),
+            lambda w: self.decide_apply(
+                alive, base, 0, DeviceWindowOps(*(a[:0] for a in ops)),
+                W=w, max_phases=max_phases, state=state,
+            ),
+        )
         with self._placing(alive, base, *ops):
             alive_d = self.kernel.place(alive)
             base_d = self._put_shards(base)
@@ -1280,14 +1343,26 @@ class DeviceKVTable:
             kind = np.concatenate(
                 [kind, np.zeros((W - kind.shape[0], kind.shape[1]), kind.dtype)]
             )
-        Gp = 1
-        while Gp < max(1, len(get_waves)):
-            Gp <<= 1
+        if self._laddered:
+            # a table with a ladder fixes Gp at W: a window of rung W
+            # then has one mixed signature whatever share of its waves
+            # bears a GET and however full it is, so the ladder holds
+            # one program a rung and not one for each (W, Gp) pair
+            Gp = W
+        else:
+            Gp = 1
+            while Gp < max(1, len(get_waves)):
+                Gp <<= 1
         gidx = np.zeros(Gp, np.int32)
         gidx[: len(get_waves)] = get_waves
         key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
         fn = self._program(
-            key, lambda: self._build_mixed(key[2], key[3], Gp)
+            key, W, lambda: self._build_mixed(key[2], key[3], Gp),
+            lambda w: self.mixed_apply(
+                alive, base, 0, kind[:0], get_waves[:0],
+                DeviceWindowOps(*(a[:0] for a in ops)), W=w,
+                max_phases=max_phases, state=state,
+            ),
         )
         with self._placing(alive, base, kind, *ops):
             alive_d = self.kernel.place(alive)

@@ -431,6 +431,27 @@ class MeshEngine:
             "mesh_window_resizes_total", "Governor window resizes",
             fn=lambda: self.window_resizes,
         )
+        # device windows dispatched, by the rung (static window size)
+        # each ran at; the same event as the rabia.window.w<W> marker
+        self._dev_windows = dict.fromkeys(self._ladder(), 0)
+        for _w in self._dev_windows:
+            m.counter(
+                "devkv_windows_total",
+                "Device windows dispatched by the window size (rung) they "
+                "ran at (the rabia.window.w<W> markers)",
+                {"w": str(_w)},
+                fn=lambda w=_w: self._dev_windows[w],
+            )
+        m.counter(
+            "devkv_ladder_programs_total",
+            "Window programs the table's ladder built ahead of need: the "
+            "siblings, at every other rung, of a signature first needed "
+            "at one (inside the rabia.ladder.build spans; 0 without a "
+            "latency target)",
+            fn=lambda: (
+                self._dev.ladder_programs if self._dev is not None else 0
+            ),
+        )
         m.counter(
             "engine_decided_total", "Slots decided (bulk device lane)",
             {"value": "v1"}, fn=lambda: self.decided_v1,
@@ -449,9 +470,14 @@ class MeshEngine:
         # (upsizing will not re-enter it until the ceiling ages out)
         self._lat_ceiling: Optional[int] = None
         self._lat_ceiling_age = 0
-        # windows to leave untimed: the first cycle at any window size
-        # pays that size's jit compile (seconds), which must not read as
-        # latency or the governor ratchets W down one compile at a time
+        # host time of cycles that only drained a device window since
+        # the last dispatching cycle: part of that window's sample
+        self._lat_drain_ms = 0.0
+        # windows to leave untimed: on the host lanes the first cycle at
+        # any window size pays that size's jit compile (seconds), which
+        # must not read as latency or the governor ratchets W down one
+        # compile at a time. The device lane's rungs are built together
+        # (DeviceKVTable's ladder), so a resize there skips nothing
         self._lat_skip = 1
         # set by lane demotions DURING a timed cycle: that sample is void
         self._lat_invalidate = False
@@ -621,7 +647,8 @@ class MeshEngine:
                     "(the demotion target)"
                 )
             self._dev = DeviceKVTable(
-                self.n_shards, self.kernel, **(device_store_kw or {})
+                self.n_shards, self.kernel, rungs=self._ladder(),
+                **(device_store_kw or {}),
             )
             self._dev_active = True
             # host mirror of the device per-shard version counters:
@@ -641,8 +668,7 @@ class MeshEngine:
             # main thread they would queue BEHIND the just-dispatched
             # next window on the single-stream device and wait out a
             # full window per cycle, and on a single worker the fetches
-            # serialize one readback apart. What the threads buy is not
-            # yet measured on the attached chip.
+            # serialize one readback apart.
             self._dev_pipe: list = []
             # in-flight windows whose version derivation is DEFERRED to
             # settlement (DEL bumps the shard version only when found —
@@ -665,10 +691,18 @@ class MeshEngine:
         self._dev_cooldown = 0
         # max dispatched-but-unresolved windows (pipe depth): the extra
         # windows keep the device busy while earlier windows' readbacks
-        # are in flight. Default: 3 for throughput mode (not yet
-        # measured on the attached chip); 1 under a latency target
-        # (each extra window delays future settlement by one more
-        # window, which a p99 target cannot absorb).
+        # are in flight. Default: 3 for throughput mode; 1 under a
+        # latency target (each extra window delays future settlement by
+        # one more window, which a p99 target cannot absorb). On one
+        # TPU v5e at 4096 shards x 5 replicas, window 64, a saturating
+        # closed loop of three windows commits the same at depth 1, 2
+        # and 3 (7.25M / 7.35M / 7.29M ops/s, single runs): the host
+        # packs window N+1 before it needs window N resolved, so depth 1
+        # hides the device as depth 3 does, and depth 3 holds 187 MB
+        # more on the chip. What costs throughput is the CLIENT's depth:
+        # one window outstanding runs host and device in lockstep (4.55M
+        # ops/s), which a smaller window, two of which pipeline, cures
+        # (PERF.md, PR 37 and PR 38).
         if device_store_inflight is None:
             device_store_inflight = 1 if latency_target_ms is not None else 3
         self._dev_inflight = max(1, int(device_store_inflight))
@@ -786,21 +820,55 @@ class MeshEngine:
             applied = self._run_cycle_inner()
         finally:
             self._lat_timing = False
+        dt_ms = (time.perf_counter() - t0) * 1e3
         if self.cycles > cycles_before:
-            # time only cycles that consumed a window (an idle probe
-            # costs ~µs and would drown the window samples). A lane
+            # a sample is a cycle that consumed a window (an idle probe
+            # costs ~µs and would drown the window samples), with the
+            # cycles before it that dispatched nothing and only drained
+            # an in-flight device window: a client that keeps one
+            # window outstanding makes the engine resolve each window in
+            # a cycle of its own, and that wait and settle are the
+            # window's cost as much as its pack. A lane
             # demotion mid-cycle (device -> host, block -> scalar) runs
             # a second dispatch plus that path's jit compile inside this
             # one sample — one-off machinery, not steady-state latency
             invalid = self._lat_invalidate
             self._lat_invalidate = False
+            dt_ms += self._lat_drain_ms
+            self._lat_drain_ms = 0.0
             if self._lat_skip:
                 self._lat_skip -= 1  # compile warmup, not latency
             elif not invalid:
-                dt_ms = (time.perf_counter() - t0) * 1e3
                 self._lat_samples.append(dt_ms)
                 self._govern(dt_ms)
+        elif applied:
+            self._lat_drain_ms += dt_ms
         return applied
+
+    def _rung_below(self, w: int) -> int:
+        return max(self.min_window, w // 2)
+
+    def _rung_above(self, w: int) -> int:
+        return min(self.max_window, w * 2)
+
+    def _ladder(self) -> tuple[int, ...]:
+        """Every window size this engine can dispatch at: without a
+        latency target its ``window`` alone; with one, every size the
+        governor's steps (:meth:`_govern`: halve, double, or drop to
+        ``min_window``) reach from the starting one — the powers of two
+        from ``min_window`` to ``max_window`` when all three are powers
+        of two. The device table builds each program for all of them at
+        once (``DeviceKVTable.rungs``)."""
+        if self.latency_target_ms is None:
+            return (self.window,)
+        seen: set[int] = set()
+        todo = [self.window]
+        while todo:
+            w = todo.pop()
+            if w not in seen:
+                seen.add(w)
+                todo += [self._rung_below(w), self._rung_above(w)]
+        return tuple(sorted(seen))
 
     def _p99(self) -> float:
         """Interpolated empirical p99 over the current samples.
@@ -848,17 +916,21 @@ class MeshEngine:
         samples among ≥4 pull the trimmed estimate over 2× too. Round 4 halved on a SINGLE 2× overshoot —
         where lone 5–10× glitches occur, that evicts a healthy window
         size and the resulting ceiling parks the engine below its
-        sustainable throughput for the rest of the run. How often such
-        glitches occur on the attached chip is not yet measured.
+        sustainable throughput for the rest of the run.
         Genuine overload produces a
         second overshoot within a sample or two; a glitch does not.
-        Upsize: with trimmed p99 ≤ 0.7×target AND demand saturating the
-        current window (a deeper window would actually amortize more),
+        Upsize: with trimmed p99 ≤ 0.7×target AND demand that would
+        fill the next rung (a deeper window amortizes more only when
+        there is work to put in it: a window dispatched half empty runs
+        the larger program on the smaller window's ops),
         W doubles after 8 samples — headroom-based, so an occasional
         spike below the target no longer vetoes growth the way the old
         max-proxy did. Samples clear on every resize so each decision
-        is measured at the current W; each ladder size jit-compiles
-        once per process.
+        is measured at the current W (:meth:`_resize`). On the host
+        lanes each ladder size jit-compiles once per process, where it
+        first lands; the device lane's table builds a program for every
+        rung of :meth:`_ladder` at once, with the first window of its
+        kind.
 
         Anti-oscillation: a downsize records the size that failed as a
         CEILING; upsizing never re-enters a size at or above a live
@@ -928,16 +1000,14 @@ class MeshEngine:
             if p99d > 2.0 * t and len(s) >= 4:
                 # fast descent: overshooting by 2x even on the trimmed
                 # estimate means the target is at or below the dispatch
-                # floor — walking the ladder rung by rung would pay one
-                # jit compile (seconds) per intermediate size on the way
-                # down. Jump to the floor; if the target is achievable
-                # there, the upsize path climbs back with evidence.
-                self.window = self.min_window
+                # floor — walking the ladder rung by rung would spend
+                # eight overshooting windows on every intermediate size
+                # on the way down. Jump to the floor; if the target is
+                # achievable there, the upsize path climbs back with
+                # evidence.
+                self._resize(self.min_window, p99d)
             else:
-                self.window = max(self.min_window, self.window // 2)
-            s.clear()
-            self._lat_skip = 1
-            self.window_resizes += 1
+                self._resize(self._rung_below(self.window), p99d)
         elif (
             len(s) >= 8
             and p99d <= 0.7 * t
@@ -952,10 +1022,22 @@ class MeshEngine:
                 self._lat_ceiling = None  # probe the evicted size
                 blocked = False
             if not blocked:
-                self.window = min(self.max_window, self.window * 2)
-                s.clear()
-                self._lat_skip = 1
-                self.window_resizes += 1
+                self._resize(self._rung_above(self.window), p99d)
+
+    def _resize(self, to: int, p99d: float) -> None:
+        """Move the governor to rung ``to``: every decision is measured
+        at the current W, so the samples start again. On the host lanes
+        the next cycle compiles the new size's program and goes untimed;
+        the device lane's ladder built it with the first window of its
+        kind, so there the next cycle is a sample like any other."""
+        with device_annotation(
+            "rabia.governor.resize",
+            **{"from": self.window, "to": to, "p99_ms": round(p99d, 3)},
+        ):
+            self.window = to
+            self._lat_samples.clear()
+            self._lat_skip = 0 if self._dev_active else 1
+            self.window_resizes += 1
 
     def governor_stats(self) -> dict:
         """Observable governor state: current window, resize count, the
@@ -1066,8 +1148,8 @@ class MeshEngine:
         for s in range(self.n_shards):
             q = len(self.queues[s])
             depth[s] = min(q, W)
-            saturated |= q >= W
-        self._lat_saturated |= saturated  # a deeper window had demand
+            saturated |= q >= self._rung_above(W)
+        self._lat_saturated |= saturated  # the next rung had demand
         if not depth.any():
             return 0
         # initial votes: every live replica proposes/accepts V1 for a slot
@@ -1135,7 +1217,7 @@ class MeshEngine:
 
         W = self.window
         n = self.n_shards
-        self._lat_saturated |= len(self._full_blocks) >= W
+        self._lat_saturated |= len(self._full_blocks) >= self._rung_above(W)
         # uniform-kind runs use the lean programs (SET windows carry no
         # GET readback planes, GET windows mutate nothing); a kind
         # boundary INSIDE the window — or a block interleaving SET and
@@ -1189,8 +1271,9 @@ class MeshEngine:
                 max_phases=self.max_phases, state=state_base,
             )
         with device_annotation("rabia.cycle.book"):
-            # a new (W, widths) signature compiles inside this dispatch —
-            # seconds of jit, not window latency
+            # the first window of a kind or of a widths signature
+            # compiles inside this dispatch, with its siblings at every
+            # other rung — seconds of jit, not window latency
             self._lat_invalidate |= (
                 self._dev.compiled_on_last_call and self._lat_timing
             )
@@ -1283,8 +1366,14 @@ class MeshEngine:
         pipe policy so the three dispatch paths cannot diverge."""
         rec["t0"] = time.perf_counter()
         self._dev_pipe.append(rec)
+        W = self.window
+        # a marker of no length: the rung this window ran at, in the
+        # name because a trace's readers see names and durations only
+        with device_annotation(f"rabia.window.w{W}"):
+            self._dev_windows[W] = self._dev_windows.get(W, 0) + 1
         if self._dev.compiled_on_last_call:
-            # a jit compile (new window size / widths signature) ran
+            # a jit compile (the first window of a kind or of a widths
+            # signature, with its whole ladder) ran
             # inside this dispatch: seconds of one-off machinery sat
             # between every in-flight window's dispatch and its
             # resolve. Their settle samples would read as latency —
@@ -2104,7 +2193,7 @@ class MeshEngine:
         W = self.window
         n = self.n_shards
         depth = min(len(self._full_blocks), W)
-        self._lat_saturated |= len(self._full_blocks) >= W
+        self._lat_saturated |= len(self._full_blocks) >= self._rung_above(W)
         base = np.zeros(self.S, np.int32)
         base[:n] = self.next_slot
         if self._multi:

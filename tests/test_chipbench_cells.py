@@ -13,6 +13,12 @@ with its cell ``kv-r5-s4096-p512.ycsb-b-sat``.
 - the data: the new file against ``kv-r5-s4096.json`` and its entry, both
   new cells through ``spec.load_cell``, and their reply samples.
 
+PR 38 adds the configuration ``kv-r5-s4096-ab`` (the same shapes with the
+window governed), the traffic ``ycsb-b-w1`` and the cell
+``kv-r5-s4096-ab.ycsb-b-w1``: the data, a rehearsal over a small governed
+configuration whose target forces a descent, and the two readers
+(``window_waves_mean``, ``governor_resizes``) on a span table.
+
 Nothing here touches a TPU.
 """
 
@@ -31,6 +37,8 @@ REPO = spec.REPO_ROOT
 CONFIG = REPO / "chipbench/configs/kv-r5-s4096-p512.json"
 BASE = REPO / "chipbench/configs/kv-r5-s4096.json"
 NEW_CELLS = ("kv-r5-s4096.ycsb-a-sat", "kv-r5-s4096-p512.ycsb-b-sat")
+AB_CONFIG = REPO / "chipbench/configs/kv-r5-s4096-ab.json"
+AB_CELL = "kv-r5-s4096-ab.ycsb-b-w1"
 SMALL = {"n_shards": 8, "window": 4, "records_at_capacity": 8 * 512}
 
 
@@ -64,7 +72,7 @@ def test_new_configuration_is_kv_r5_s4096_at_512_records_a_shard():
 
 def test_both_new_cells_load_and_share_every_reader_and_traffic_file():
     bench = spec.load_benchmark()
-    assert tuple(w["name"] for w in bench["workloads"][-2:]) == NEW_CELLS
+    assert tuple(w["name"] for w in bench["workloads"][3:5]) == NEW_CELLS
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     pack, table = (spec.load_cell(name) for name in NEW_CELLS)
     assert pack.chips == table.chips == 1
@@ -72,7 +80,7 @@ def test_both_new_cells_load_and_share_every_reader_and_traffic_file():
     assert pack.traffic == _json(REPO / "chipbench/traffic/ycsb-a-sat.json")
     assert table.traffic == _json(REPO / "chipbench/traffic/ycsb-b-sat.json")
     for cell in (pack, table):
-        assert len(cell.readers) == len(bench["per_layer"]) == 16
+        assert len(cell.readers) == len(bench["per_layer"]) == 18
         assert cell.traffic["check_block_share"] == 1 / 512
         assert cell.traffic["in_flight_windows"] == 3
     # the two cells of a pair take the same traffic key for key
@@ -88,11 +96,12 @@ def test_hand_count_of_the_larger_table_and_its_window():
 
 
 @pytest.mark.parametrize("seed", (792490177, 1, 2350000001, 2**31 + 35, 3))
-@pytest.mark.parametrize("cell", NEW_CELLS)
+@pytest.mark.parametrize("cell", NEW_CELLS + (AB_CELL,))
 def test_sample_is_not_empty_in_a_traced_window(cell, seed):
     """A traced run measures at most 4 s, which hold some 4,000 blocks of
     either cell after the load (256 or 512 waves) and the warm-up (some 700
-    blocks): 8 expected picks at 1/512 (PERF.md, PR 29)."""
+    blocks; 64 windows of at most 64 in the governed cell): 8 expected
+    picks at 1/512 (PERF.md, PR 29), 16 at the governed cell's 1/256."""
     c = spec.load_cell(cell)
     picked = gen.Generator(seed, c.config, c.traffic).sampler()
     first = c.config["per_shard_capacity"] + 800
@@ -214,3 +223,139 @@ def test_rehearsal_at_512_records_a_shard_is_correct(root, traffic):
     snap = eng.metrics.snapshot()
     assert snap["rabia_devkv_table_bytes"] == peaks.table_bytes(small) == 446_496
     assert "rabia_devkv_table_bytes 446496" in eng.metrics.render_prometheus()
+
+
+# -- PR 38: the governed configuration, its traffic and its cell -------------------------
+
+
+def test_governed_configuration_is_p512_with_its_batching_on():
+    new, old = _json(AB_CONFIG), _json(CONFIG)
+    assert new["name"] == "kv-r5-s4096-ab"
+    for key in ("reference", "n_shards", "n_replicas", "window", "per_shard_capacity",
+                "key_bytes", "value_bytes", "records_at_capacity", "guarantees"):
+        assert new[key] == old[key], key  # the shapes, the reference, the five guarantees
+    assert set(new) == set(old) | {"engine"}
+    assert new["engine"] == {"latency_target_ms": new["engine"]["latency_target_ms"],
+                             "min_window": 8, "max_window": 64}
+    assert 0 < new["engine"]["latency_target_ms"] < 60
+    assert run.engine_options(new) == new["engine"]
+    assert list(new["reduced"]) == ["replica_processes", "records_per_shard"]
+    assert new["reduced"]["replica_processes"] == old["reduced"]["replica_processes"]
+    assert set(new["assumed"]) == set(old["assumed"]) | set(new["engine"])
+    entry = {c["name"]: c for c in spec.load_benchmark()["configs"]}[new["name"]]
+    assert entry["source"] == new["source"] and len(entry["source"]) <= 200
+    assert "adaptive batching on" in entry["source"]
+    sources = [c["source"] for c in spec.load_benchmark()["configs"]]
+    assert len(set(sources)) == len(sources)
+    assert entry["reduced"] == list(new["reduced"])
+    assert entry["file"] == str(AB_CONFIG.relative_to(REPO)) and len(entry["why"]) <= 200
+
+
+def test_governed_cell_loads_with_one_window_outstanding():
+    bench = spec.load_benchmark()
+    assert [w["name"] for w in bench["workloads"]][-1] == AB_CELL
+    assert len(bench["workloads"]) == 6 and len(bench["configs"]) == 5
+    cell = spec.load_cell(AB_CELL)
+    assert cell.chips == 1 and cell.config == _json(AB_CONFIG)
+    sat = _json(REPO / "chipbench/traffic/ycsb-b-sat.json")
+    differs = {"name", "what", "in_flight_windows", "warmup_windows",
+               "check_block_share", "departs"}
+    assert set(cell.traffic) == set(sat)
+    for key in set(sat) - differs:
+        assert cell.traffic[key] == sat[key], key  # the generator's and the pool's
+    assert cell.traffic["loop"] == "closed" and cell.traffic["in_flight_windows"] == 1
+    assert cell.traffic["warmup_windows"] == 64
+    assert cell.traffic["check_block_share"] == 1 / 256
+    for key in ("block_shape", "record_size"):
+        assert cell.traffic["departs"][key] == sat["departs"][key]
+    assert [m["name"] for m in bench["per_layer"][-2:]] == [
+        "window_waves_mean", "governor_resizes"]
+    for m in bench["per_layer"][-2:]:
+        assert m["layer"] == "pipe" and "workloads" not in m
+    assert set(cell.readers) == {m["name"] for m in bench["per_layer"]}
+    # the engine it builds: the governor on, every rung known to the table
+    small = dict(cell.config, n_shards=8, per_shard_capacity=8)
+    eng = run.build_engine(small)
+    try:
+        assert eng.latency_target_ms == cell.config["engine"]["latency_target_ms"]
+        assert eng._ladder() == eng._dev.rungs == (8, 16, 32, 64)
+        assert eng.window == 64 and eng._dev_inflight == 1
+    finally:
+        eng.close()
+
+
+@pytest.fixture(scope="module")
+def governed_root(tmp_path_factory) -> Path:
+    """The repo's benchmark with the governed configuration cut to 256
+    shards x 16 records on the rungs 2, 4, 8 (its file in a directory that
+    is searched first) and a target no cycle on a CPU meets, so that the
+    governor descends in the load and stays down; the cell's traffic as it
+    is, but an eighth of the blocks compared."""
+    root = tmp_path_factory.mktemp("governed")
+    (root / "chipbench").symlink_to(REPO / "chipbench", target_is_directory=True)
+    for sub in ("configs", "traffic"):
+        (root / "small" / sub).mkdir(parents=True)
+    small = dict(
+        _json(AB_CONFIG), n_shards=256, per_shard_capacity=16, window=8,
+        records_at_capacity=256 * 16,
+        engine={"latency_target_ms": 1e-3, "min_window": 2, "max_window": 8},
+    )
+    (root / "small/configs/kv-r5-s4096-ab.json").write_text(json.dumps(small))
+    traffic = _json(REPO / "chipbench/traffic/ycsb-b-w1.json")
+    (root / "small/traffic/ycsb-b-w1.json").write_text(
+        json.dumps(dict(traffic, check_block_share=0.125))
+    )
+    bench = spec.load_benchmark(REPO)
+    bench["paths"] = ["small"] + bench["paths"]
+    for c in bench["configs"]:
+        if c["name"] == "kv-r5-s4096-ab":
+            c["file"] = "small/configs/kv-r5-s4096-ab.json"
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+def test_rehearsal_of_the_governed_cell_descends_and_is_correct(governed_root):
+    seen = {}
+    result = run.run_cell(
+        AB_CELL, 2**31 + 38, 0.5, False, root=governed_root, require_chip=False,
+        engine_hook=lambda eng, runner: seen.update(eng=eng, run=runner),
+    )
+    eng, runner = seen["eng"], seen["run"]
+    assert result["correct"] is True and result["failed"] == 0
+    checks = result["checks"]
+    assert checks["replies_compared"]["value"] >= 256
+    for name in ("reply_mismatches", "replica_mismatches", "lane_faults",
+                 "unsettled_blocks"):
+        assert checks[name]["value"] == 0, name
+    # every rung's program was built in set-up: none by a measured window
+    assert result["window"]["window_compiles"] == 0
+    assert all(not measured for _, _, measured in runner.compiles)
+    assert eng._dev.rungs == (2, 4, 8) and eng._dev.ladder_programs >= 4
+    sigs = set(eng._dev._fused_cache)
+    assert {k[1] for k in sigs if k[0] == "mix"} == {2, 4, 8}
+    assert all(k[4] == k[1] for k in sigs if k[0] == "mix")  # Gp = W
+    # the governor came down, and the measured windows ran under the top rung
+    assert eng.window == 2 and eng.window_resizes >= 1
+    assert eng._dev_windows[2] >= result["window"]["windows"] > 0
+    assert runner._target == 8  # one window of the configuration outstanding
+    assert [len(sm.store) for sm in eng.sms] == [256 * 16] * 5
+
+
+def test_readers_of_the_rung_and_the_resizes():
+    cell = spec.load_cell(AB_CELL)
+    mean, resizes = cell.readers["window_waves_mean"], cell.readers["governor_resizes"]
+    # a program without the markers (the parent): nothing to read, no error
+    parent = {"spans": {"rabia.cycle.pack": [0.01] * 5, "chipbench.run_cycle": [0.03] * 5}}
+    assert mean(parent) is None and resizes(parent) is None
+    assert mean({"spans": {}}) is None and resizes({"spans": {}}) is None
+    # an ungoverned cell: every window at 64, no resize
+    fixed = {"spans": {"rabia.window.w64": [1e-6] * 91, "rabia.cycle.pack": [0.012] * 91}}
+    assert mean(fixed) == 64 and resizes(fixed) == 0
+    # a governor that sat at 32, probed 64 twice and once went down to 16
+    walked = {"spans": {
+        "rabia.window.w32": [1e-6] * 150, "rabia.window.w64": [1e-6] * 16,
+        "rabia.window.w16": [1e-6] * 8, "rabia.governor.resize": [2e-5] * 5,
+        "rabia.window.wide": [1e-6],  # no rung in its name: not a marker
+    }}
+    assert mean(walked) == pytest.approx((150 * 32 + 16 * 64 + 8 * 16) / 174)
+    assert resizes(walked) == 5

@@ -10,6 +10,8 @@ Runs on the virtual CPU mesh (conftest pins JAX to CPU).
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -387,6 +389,238 @@ class TestGovernedDeviceLane:
         want = _store_content(host.sms[0], n)
         for sm in eng.sms:
             assert _store_content(sm, n) == want
+
+
+def _governed(n, **kw):
+    """A governed device-lane engine on the rungs 1, 2, 4, 8."""
+    return MeshEngine(
+        lambda: VectorShardedKV(n, capacity=1 << 12),
+        n_shards=n,
+        n_replicas=3,
+        mesh=make_mesh(),
+        window=8,
+        device_store=True,
+        device_store_kw={"per_shard_capacity": 16},
+        latency_target_ms=kw.pop("latency_target_ms", 60_000.0),
+        min_window=1,
+        max_window=8,
+        **kw,
+    )
+
+
+def _walk_the_ladder(eng, blocks, hook=None):
+    """Submit ``blocks`` a batch at a time and run the engine through
+    every rung: down under a target no cycle can meet (two overshooting
+    samples a rung), up under one every cycle meets (sixteen samples to
+    probe the failed size, eight a rung after that), down again. Batches
+    leave partial windows at their ends. Returns the futures in order."""
+    futs = []
+    it = iter(blocks)
+
+    def feed(k):
+        got = list(itertools.islice(it, k))
+        futs.extend(eng.submit_block(b) for b in got)
+        return len(got)
+
+    def cycles(target, until):
+        eng.latency_target_ms = target
+        for _ in range(400):
+            if until():
+                return
+            if len(eng._full_blocks) < eng.window and not feed(2 * eng.window + 3):
+                break
+            before = eng.cycles
+            eng.run_cycle()
+            if hook is not None and eng.cycles != before:
+                hook()
+        assert until(), (target, eng.window, eng.governor_stats())
+
+    cycles(1e-6, lambda: eng.window == eng.min_window)
+    cycles(60_000.0, lambda: eng.window == eng.max_window)
+    cycles(1e-6, lambda: eng.window == eng.min_window)
+    feed(1 << 30)
+    eng.latency_target_ms = 60_000.0
+    eng.flush()
+    return futs
+
+
+class TestWindowLadder:
+    """A governed table builds a signature for every rung at once, with
+    the first window of its kind and widths (``DeviceKVTable._program``):
+    a window that lands on another rung later compiles nothing."""
+
+    @staticmethod
+    def _four_kinds(n, rng, waves):
+        """Random SET/GET/DEL/EXISTS per (wave, shard), every third wave
+        SET only (a wave of a mixed window that bears no GET)."""
+        from rabia_tpu.apps.kvstore import KVOperation, KVOpType, encode_op_bin
+
+        out = []
+        for w in range(waves):
+            cmds = []
+            for s in range(n):
+                k = f"k{s}_{int(rng.integers(0, 3))}"
+                x = 0.0 if w % 3 == 0 else rng.random()
+                if x < 0.4:
+                    cmds.append([encode_set_bin(k, "v" * int(rng.integers(1, 60)) + str(w))])
+                elif x < 0.75:
+                    cmds.append([TestDeviceGetWindows._enc_get(k)])
+                elif x < 0.9:
+                    cmds.append([encode_op_bin(KVOperation(KVOpType.Delete, k))])
+                else:
+                    cmds.append([encode_op_bin(KVOperation(KVOpType.Exists, k))])
+            out.append(build_block(list(range(n)), cmds))
+        return out
+
+    def test_every_rung_up_and_down_agrees_with_the_host_engine(self):
+        """SET/GET/DEL/EXISTS (``kv_plain`` knows SET and GET only, so the
+        four kinds are held to the fixed-window host engine): reply for
+        reply, store for store on all replicas, and the lane stays."""
+        n = 8
+        eng = _governed(n)
+        host = _mk(n, device=False)
+        blocks = self._four_kinds(n, np.random.default_rng(38), 700)
+        futs = _walk_the_ladder(eng, blocks)
+        want = [host.submit_block(b) for b in
+                self._four_kinds(n, np.random.default_rng(38), 700)][: len(futs)]
+        host.flush()
+        assert eng.device_lane_active and eng.divergences == 0
+        assert all(eng._dev_windows[w] > 0 for w in (1, 2, 4, 8)), eng._dev_windows
+        assert eng.window_resizes >= 9
+        assert len(futs) > 200
+        for i, (a, b) in enumerate(zip(futs, want)):
+            assert _frames(a) == _frames(b), i
+        eng.sync_to_host()
+        content = _store_content(host.sms[0], n)
+        assert content
+        for sm in eng.sms:
+            assert _store_content(sm, n) == content
+        eng.close()
+        host.close()
+
+    def test_every_rung_up_and_down_agrees_with_kv_plain(self):
+        """The benchmark's own generator, wire format, reference and
+        check over a governed engine: YCSB-A's SET/GET waves, every reply
+        and all replica stores against ``kv_plain``."""
+        from chipbench import check, gen
+        from chipbench.reference.kv_plain import PlainKV
+
+        config = {"n_shards": 8, "per_shard_capacity": 16, "key_bytes": 32,
+                  "value_bytes": 64, "window": 8}
+        traffic = {"readproportion": 0.5, "updateproportion": 0.5,
+                   "requestdistribution": "zipfian", "zipfian_constant": 0.99,
+                   "pool_windows": 80, "check_block_share": 1.0}
+        g = gen.Generator(2**31 + 38, config, traffic)
+        waves = g.load_waves() + g.pool_waves()
+        eng = _governed(g.S)
+        futs = _walk_the_ladder(eng, (g.block(*g.encode(w)) for w in waves))
+        assert check.lane_faults(eng) == 0
+        assert all(eng._dev_windows[w] > 0 for w in (1, 2, 4, 8)), eng._dev_windows
+        ref = PlainKV(g.S, g.n_keys, g.VW)
+        for i, (wave, fut) in enumerate(zip(waves, futs)):
+            want = ref.apply_wave(wave.kind, wave.kid, wave.vlen, wave.val, range(g.S))
+            got = fut.result()
+            assert {s: bytes(got[s][0]) for s in range(g.S)} == want, i
+        assert len(futs) > 200
+        eng.sync_to_host()
+        assert check.replica_mismatches(eng, ref, g) == (0, "")
+        eng.close()
+
+    def test_no_rung_compiles_after_the_first_window_of_its_kind(self, caplog):
+        """After the first SET window and the first mixed window the
+        table holds both kinds at all four rungs; whatever rung runs next
+        ``_fused_cache`` gains nothing, ``compiled_on_last_call`` is never
+        set, and JAX compiles no window program (``jax_log_compiles``)."""
+        import logging
+
+        import jax
+
+        n = 8
+        eng = _governed(n)
+        dev = eng._dev
+        assert dev.rungs == eng._ladder() == (1, 2, 4, 8)
+        rng = np.random.default_rng(7)
+        for b in _set_blocks(n, 3, rng):  # a partial SET window at rung 8
+            eng.submit_block(b)
+        eng.flush()
+        assert dev.compiled_on_last_call
+        assert set(dev._fused_cache) == {(w, 1, 8) for w in (1, 2, 4, 8)}
+        assert dev.ladder_programs == 3
+        mixed = [
+            build_block(
+                list(range(n)),
+                [[encode_set_bin(f"k{s}_0", "x" * 40)] if (s + w) % 4 == 0
+                 else [TestDeviceGetWindows._enc_get(f"k{s}_{w % 3}")]
+                 for s in range(n)],
+            )
+            for w in range(600)
+        ]
+        f0 = eng.submit_block(mixed[0])
+        eng.flush()
+        assert f0.done() and dev.compiled_on_last_call
+        # a governed table's mixed signature has Gp = W: one program a rung
+        sigs = {(w, 1, 8) for w in (1, 2, 4, 8)} | {
+            ("mix", w, 1, 16, w) for w in (1, 2, 4, 8)
+        }
+        assert set(dev._fused_cache) == sigs and dev.ladder_programs == 6
+        snap = eng.metrics.snapshot()
+        assert snap["rabia_devkv_ladder_programs_total"] == 6
+        assert snap["rabia_devkv_program_builds_total"] == 8
+
+        flagged = []
+        with jax.log_compiles(), caplog.at_level(logging.WARNING, logger="jax"):
+            caplog.clear()
+            futs = _walk_the_ladder(
+                eng, mixed[1:], hook=lambda: flagged.append(dev.compiled_on_last_call)
+            )
+            built = [r.getMessage() for r in caplog.records
+                     if "Compiling jit(mixed)" in r.getMessage()
+                     or "Compiling jit(fused)" in r.getMessage()]
+        assert len(futs) > 200 and all(f.done() for f in futs)
+        assert all(eng._dev_windows[w] > 0 for w in (1, 2, 4, 8)), eng._dev_windows
+        assert set(dev._fused_cache) == sigs
+        assert flagged and not any(flagged)
+        assert built == []
+        assert eng.device_lane_active
+        by_rung = {w: eng.metrics.snapshot()[f'rabia_devkv_windows_total{{w="{w}"}}']
+                   for w in (1, 2, 4, 8)}
+        assert by_rung == eng._dev_windows
+        eng.close()
+
+    def test_an_engine_without_a_target_builds_the_parents_signatures(self):
+        """One rung, its ``window``: each signature is built where it is
+        first needed and nowhere else, and the mixed program keeps its
+        ``Gp`` (the GET-bearing waves, rounded up to a power of two)."""
+        n = 8
+        eng = _mk(n, device=True, window=8)
+        dev = eng._dev
+        assert dev.rungs == eng._ladder() == (8,)
+        get = TestDeviceGetWindows._enc_get
+        shards = list(range(n))
+        sets = _set_blocks(n, 8, np.random.default_rng(3))
+        reads = lambda k: [
+            build_block(shards, [[encode_set_bin(f"k{s}_0", "y" * 33)] if s == w % n
+                                 else [get(f"k{s}_1")] for s in shards])
+            for w in range(k)
+        ]
+        seen = []
+        for batch in (sets, reads(8), reads(3), sets[:2] + reads(1), reads(8)):
+            for b in batch:
+                eng.submit_block(b)
+            eng.flush()
+            seen.append(set(dev._fused_cache))
+        assert seen == [
+            {(8, 1, 8)},
+            {(8, 1, 8), ("mix", 8, 1, 16, 8)},
+            {(8, 1, 8), ("mix", 8, 1, 16, 8), ("mix", 8, 1, 16, 4)},
+            {(8, 1, 8), ("mix", 8, 1, 16, 8), ("mix", 8, 1, 16, 4),
+             ("mix", 8, 1, 16, 1)},
+            {(8, 1, 8), ("mix", 8, 1, 16, 8), ("mix", 8, 1, 16, 4),
+             ("mix", 8, 1, 16, 1)},
+        ]
+        assert dev.ladder_programs == 0
+        assert eng._dev_windows == {8: 5}
+        eng.close()
 
 
 class TestDeviceGetWindows:

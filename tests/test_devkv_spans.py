@@ -233,6 +233,58 @@ def test_profiler_events_tile_the_dispatch(tmp_path):
     assert len(events["rabia.cycle.settle"]) == 2  # both windows settled
 
 
+class TestPipeSpans:
+    """The pipe's own events (PR 38): the rung marker at every dispatch,
+    the governor's resizes, the ladder's builds."""
+
+    def test_ungoverned_engine_marks_its_one_rung_and_nothing_else(self, traced):
+        eng = _engine()
+        rng = np.random.default_rng(3)
+        for kind in ("set", "mixed", "mixed"):
+            _window(eng, kind, rng)
+        eng.flush()
+        rep = traced.report()
+        assert rep[f"rabia.window.w{WINDOW}"]["count"] == 3
+        assert [n for n in rep if n.startswith("rabia.window.")] == [
+            f"rabia.window.w{WINDOW}"]
+        assert "rabia.governor.resize" not in rep and "rabia.ladder.build" not in rep
+        assert not any(n.startswith("rabia.devkv.w") for n in rep)
+        eng.close()
+
+    def test_governed_engine_marks_each_rung_resize_and_ladder_build(self, traced):
+        eng = _engine(latency_target_ms=60_000.0, min_window=2, max_window=WINDOW)
+        rng = np.random.default_rng(4)
+        _window(eng, "set", rng)  # builds the SET ladder: one sibling
+        _window(eng, "mixed", rng)  # and the mixed one
+        rep = traced.report()
+        assert rep["rabia.ladder.build"]["count"] == 2
+        assert rep["rabia.jit.first_call"]["count"] == 4  # two rungs x two kinds
+        # each build lies inside the dispatch span that first needed the kind
+        assert _total("rabia.ladder.build") <= (
+            _total(PROGRAM["set"]) + _total(PROGRAM["mixed"]))
+        eng.latency_target_ms = 1e-6  # nothing meets it: down a rung
+        for _ in range(3):
+            _window(eng, "mixed", rng)
+        eng.flush()
+        assert eng.window == 2 and eng.window_resizes == 1
+        eng.latency_target_ms = 60_000.0
+        _window(eng, "mixed", rng)  # a whole window of 4 blocks: two of rung 2
+        eng.flush()
+        rep = traced.report()
+        assert rep["rabia.governor.resize"]["count"] == 1
+        # a ladder build for each kind and widths first seen (the values'
+        # widths are drawn), never one for a rung: two programs a build
+        builds = rep["rabia.ladder.build"]["count"]
+        assert rep["rabia.jit.first_call"]["count"] == 2 * builds
+        assert len(eng._dev._fused_cache) == 2 * builds
+        assert eng._dev.ladder_programs == builds
+        marks = {n: rep[n]["count"] for n in rep if n.startswith("rabia.window.")}
+        assert marks == {f"rabia.window.w{w}": eng._dev_windows[w] for w in (2, WINDOW)}
+        assert marks["rabia.window.w2"] >= 2 and marks[f"rabia.window.w{WINDOW}"] >= 4
+        assert eng.device_lane_active
+        eng.close()
+
+
 class TestUploadBytes:
     @pytest.mark.parametrize("kind", ["set", "get", "mixed"])
     def test_counter_grows_by_the_placed_operands(self, kind):

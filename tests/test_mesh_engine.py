@@ -680,6 +680,70 @@ class TestLatencyGovernor:
         assert eng.window == 4
         assert eng._lat_ceiling == 8
 
+    def test_growing_needs_demand_that_fills_the_next_rung(self):
+        # a deeper window amortizes more only when there is work to put
+        # in it (PERF.md, PR 38: with 32 blocks queued the governor went
+        # from 32 to 64 and ran the 64-wave program half empty)
+        from rabia_tpu.apps.kvstore import encode_set_bin
+        from rabia_tpu.core.blocks import build_block
+
+        n = 4
+        eng = self._mk(S=n, window=2, latency_target_ms=60_000.0, max_window=8)
+        block = lambda i: build_block(
+            list(range(n)), [[encode_set_bin(f"k{s}", f"v{i}")] for s in range(n)]
+        )
+        for depth, grows in ((2, False), (3, False), (4, True)):
+            eng.window = 2
+            eng._lat_samples.clear()
+            eng._lat_skip = 0
+            for i in range(12):
+                while len(eng._full_blocks) < depth:
+                    eng.submit_block(block(i))
+                eng.run_cycle()
+            eng.flush()
+            assert (eng.window > 2) is grows, (depth, eng.window)
+
+    def test_a_drain_cycle_counts_into_its_windows_sample(self):
+        # a client that keeps one window outstanding makes the engine
+        # resolve each device window in a cycle of its own; that wait
+        # and settle belong to the window's sample (PERF.md, PR 38: at
+        # 64 the dispatching cycle alone read 22 ms of a 57 ms window)
+        import time
+
+        from rabia_tpu.apps.kvstore import encode_set_bin
+        from rabia_tpu.core.blocks import build_block
+
+        n = 4
+        eng = self._mk(
+            S=n, window=2, device_store=True, latency_target_ms=60_000.0,
+            min_window=2, max_window=2,
+        )
+        block = lambda i: build_block(
+            list(range(n)), [[encode_set_bin(f"k{s}", f"v{i}")] for s in range(n)]
+        )
+        for i in range(2):
+            eng.submit_block(block(i))
+        eng.run_cycle()  # dispatches (and compiles: skipped as a sample)
+        assert eng.cycles == 1 and eng._lat_drain_ms == 0.0
+        resolve = eng._dev_resolve_one
+
+        def slow_resolve():
+            time.sleep(0.05)
+            return resolve()
+
+        eng._dev_resolve_one = slow_resolve
+        assert eng.run_cycle() == 2 * n  # nothing to dispatch: a drain
+        assert eng.cycles == 1 and eng._lat_drain_ms >= 50.0
+        assert len(eng._lat_samples) == 0
+        assert eng.run_cycle() == 0 and eng._lat_drain_ms >= 50.0  # idle: no time added
+        for i in range(2):
+            eng.submit_block(block(2 + i))
+        eng.run_cycle()
+        assert eng.cycles == 2 and eng._lat_drain_ms == 0.0
+        assert len(eng._lat_samples) == 1 and eng._lat_samples[0] >= 50.0
+        eng.flush()
+        eng.close()
+
     def test_governor_stats_before_any_sample(self):
         eng = self._mk(window=4, latency_target_ms=100.0)
         st = eng.governor_stats()
