@@ -60,6 +60,8 @@ the host store but the observable key->(value, version) mapping cannot.
 
 from __future__ import annotations
 
+import ctypes
+import os
 import sys
 from collections.abc import Sequence
 from typing import NamedTuple, Optional
@@ -73,6 +75,9 @@ from rabia_tpu.apps.vector_kv import _RESP_DT
 __all__ = ["DeviceKVTable", "DeviceWindowOps", "MixedFrameGroups"]
 
 _SET_HDR = 3  # binary SET op: u8 opcode(1) + u16 klen + key + value
+# the window packers' envelopes as rk_pack_scan takes them: bit o set =
+# opcode o allowed (1 SET, 2 GET, 3 DEL, 4 EXISTS; _parse_window's masks)
+_ALLOW_OPCODES = {"set": 1 << 1, "get": 1 << 2, "mixed": 0b11110}
 
 # buffers the plane pool keeps, idle or handed out: five planes a window
 # times the windows that hold them (the pipe's three in flight, the
@@ -132,6 +137,16 @@ class _PlanePool:
         return self._bufs[-1]
 
 
+def _address(a: np.ndarray) -> int:
+    """The address of a C-contiguous array's first byte, for native
+    code: ``a.ctypes.data``, at a third of its cost where the array is
+    writable (the window packers take three of these a block)."""
+    try:
+        return ctypes.addressof(ctypes.c_char.from_buffer(a))
+    except (TypeError, ValueError):  # read-only, or empty
+        return a.ctypes.data
+
+
 def _bucket(n: int, lo: int = 4) -> int:
     """Round up to a power of two (>= lo, multiple of 4 for u32 views)."""
     b = lo
@@ -152,6 +167,18 @@ class DeviceWindowOps(NamedTuple):
     vlen: np.ndarray  # i16[W, S]
     kwin: np.ndarray  # u32[W, S, Ku/4]
     vwin: np.ndarray  # u32[W, S, VWu/4]
+
+
+class _BlockPointers(NamedTuple):
+    """A window's blocks as ``rk_pack_scan`` / ``rk_pack_gather`` read
+    them, with everything the addresses point into kept alive."""
+
+    lib: object  # the host kernel library
+    data: list  # bytes of each block
+    cols: list  # i64 cmd_sizes, counts, shards of each block, in turn
+    data_p: object  # char*[W]
+    data_len: np.ndarray  # i64[W]
+    cols_p: np.ndarray  # uintp[3 W]
 
 
 def _get_frame(found: bool, ver: int, val: bytes) -> bytes:
@@ -432,10 +459,9 @@ class DeviceKVTable:
         # planes taken from it by outcome, ever (the engine's
         # devkv_pack_buffers_total reads it)
         self.pack_buffers = self._planes.outcomes
-        # the gather's per-op columns by name, kept across windows and
-        # grown on demand; they die inside _gather_into, so nothing can
-        # hold them
-        self._scratch: dict = {}
+        # windows packed by the path that packed them, ever (the
+        # engine's devkv_pack_windows_total reads it)
+        self.pack_windows = {"native": 0, "numpy": 0}
 
     # -- host-side packing -------------------------------------------------
 
@@ -462,19 +488,51 @@ class DeviceKVTable:
 
     def _gather_window(self, blocks, allow: str) -> Optional[tuple]:
         """Shared validate + bucket + fixed-width gather behind the
-        three window packers (``allow``: "set", "get" or "mixed") —
-        including the end-of-buffer gather clamp, maintained ONCE.
+        three window packers (``allow``: "set", "get" or "mixed").
+
+        On the grid shape (every block full width, one op a shard,
+        shards in order) native code reads the blocks where they lie:
+        one scan validates the window and finds its widths
+        (:meth:`_native_scan`), one gather writes the planes
+        (:meth:`_native_pack_gather`), and no per-op numpy array is
+        made. Whatever the scan does not take (another shape, an op
+        outside the envelope, no library, ``RABIA_PY_DEVPACK=1``) goes
+        to :meth:`_parse_window` and :meth:`_gather_into` whole: the
+        numpy path is the semantics owner, and it alone decides that a
+        window is outside the envelope.
 
         Returns ``(kind i8[W,S], klen i16[W,S], vlen i16[W,S],
         kwin u8[W,S,Ku], vwin u8[W,S,VWu])`` or None when any op is
         outside the requested envelope (wrong opcode, >1 op per shard,
         key/value over the table widths) — the caller demotes."""
-        with device_annotation("rabia.cycle.pack.parse"):
+        W = len(blocks)
+        with device_annotation("rabia.cycle.pack.parse") as span:
+            parsed = None
+            scan = self._native_scan(blocks, allow)
+            if scan is None:
+                parsed = self._parse_window(blocks, allow)
+            if span is not None:
+                span.set_metadata(path="numpy" if scan is None else "native")
+        planes = None
+        if scan is not None:
+            planes = self._take_planes(W, *scan[1:])
+            with device_annotation("rabia.cycle.pack.gather"):
+                if self._native_pack_gather(scan, *planes):
+                    self.pack_windows["native"] += 1
+                    return planes
+            # the C gather's own bounds check: numpy writes every row again
             parsed = self._parse_window(blocks, allow)
+        self.pack_windows["numpy"] += 1
         if parsed is None:
             return None
-        parsed, ku, vu = parsed
-        W = len(blocks)
+        if planes is None or parsed[1:] != scan[1:]:
+            planes = self._take_planes(W, *parsed[1:])
+        with device_annotation("rabia.cycle.pack.gather"):
+            self._gather_into(parsed[0], *planes)
+        return planes
+
+    def _take_planes(self, W: int, ku: int, vu: int) -> tuple:
+        """The window's five planes from the pool, holding anything."""
         S = self.S
         specs = (
             ((W, S), np.int8),
@@ -489,13 +547,83 @@ class DeviceKVTable:
         with device_annotation(
             "rabia.cycle.pack.alloc", reused=sum(b is not None for b in bufs)
         ):
-            planes = tuple(
+            return tuple(
                 (pool.fresh(nb) if b is None else b).view(dt).reshape(sh)
                 for b, nb, (sh, dt) in zip(bufs, sizes, specs)
             )
-        with device_annotation("rabia.cycle.pack.gather"):
-            self._gather_into(parsed, *planes)
-        return planes
+
+    def _native_scan(self, blocks, allow: str) -> Optional[tuple]:
+        """The native parse of a grid-shaped window: one C pass
+        (``rk_pack_scan``) over each block's ``data``, ``cmd_sizes``,
+        ``counts`` and ``shards`` where they lie checks the shape and
+        every op against the ``allow`` envelope, all that
+        :meth:`_parse_window` checks, and finds the widest key and
+        value. Returns ``(pointers, ku, vu)`` for
+        :meth:`_native_pack_gather` (``pointers`` keeps alive what it
+        points into), or None: not the grid shape, an op outside the
+        envelope, the library unavailable or ``RABIA_PY_DEVPACK=1`` —
+        the numpy parse then runs on the whole window and decides."""
+        # =1 opts out, matching the docstring/tests convention — a plain
+        # truthiness test made RABIA_PY_DEVPACK=0 ALSO disable the
+        # native path
+        if os.environ.get("RABIA_PY_DEVPACK") == "1":
+            return None
+        from rabia_tpu.native.build import load_hostkernel
+
+        lib = load_hostkernel()
+        if lib is None:
+            return None
+        W = len(blocks)
+        if W == 0:
+            return None
+        # a PayloadBlock holds exact bytes and i64 arrays; an array of
+        # another layout is copied for C (contiguous ones come back as
+        # they are)
+        data = [b.data for b in blocks]
+        cols = [
+            np.ascontiguousarray(a, np.int64)
+            for b in blocks
+            for a in (b.cmd_sizes, b.counts, b.shards)
+        ]
+        try:
+            data_p = (ctypes.c_char_p * W)(*data)
+        except TypeError:  # bytes-like, not bytes: numpy reads those
+            return None
+        data_len = np.fromiter(map(len, data), np.int64, W)
+        cols_p = np.fromiter(map(_address, cols), np.uintp, 3 * W)
+        cols_len = np.fromiter(map(len, cols), np.int64, 3 * W)
+        widest = np.zeros(2, np.int64)
+        rc = lib.rk_pack_scan(
+            W, self.n_shards, _SET_HDR, _ALLOW_OPCODES[allow], self.K,
+            self.VW, data_p, data_len.ctypes.data, cols_p.ctypes.data,
+            cols_len.ctypes.data, widest.ctypes.data,
+        )
+        if rc != 0:
+            return None
+        pointers = _BlockPointers(lib, data, cols, data_p, data_len, cols_p)
+        return pointers, _bucket(int(widest[0])), _bucket(int(widest[1]))
+
+    def _native_pack_gather(
+        self, scan, kind_w, klen_w, vlen_w, kwin_w, vwin_w
+    ) -> bool:
+        """One-pass C gather (``rk_pack_gather``) of a window
+        :meth:`_native_scan` took into the five planes, every row
+        written whole: op ``s`` of block ``t`` is wave t, shard s, its
+        bytes read where they lie, its header by the C loop itself. No
+        per-op array exists on this path. The C loop checks its own
+        bounds; False (they tripped, which leaves the planes half
+        written) routes the caller to the numpy path, which writes
+        every row again. Byte-equivalence with the numpy path is
+        pinned in tests/test_device_kv.py."""
+        at = scan[0]
+        W, S, ku = kwin_w.shape
+        rc = at.lib.rk_pack_gather(
+            W, self.n_shards, S, _SET_HDR, ku, vwin_w.shape[2],
+            at.data_p, at.data_len.ctypes.data, at.cols_p.ctypes.data,
+            kind_w.ctypes.data, klen_w.ctypes.data, vlen_w.ctypes.data,
+            kwin_w.ctypes.data, vwin_w.ctypes.data,
+        )
+        return rc == 0
 
     def _parse_window(self, blocks, allow: str) -> Optional[tuple]:
         """Parse and validate every block of a window against the
@@ -538,38 +666,25 @@ class DeviceKVTable:
     def _gather_into(
         self, parsed, kind_w, klen_w, vlen_w, kwin_w, vwin_w
     ) -> None:
-        """Fill the ``[W, S, ...]`` planes from the parsed blocks (the
-        native one-pass gather on the grid shape, else numpy). The
-        planes come from the pool and may hold anything: every path
-        writes every byte of all five."""
+        """Fill the ``[W, S, ...]`` planes from the parsed blocks, in
+        numpy (the semantics owner of the native gather, and the path
+        of every window that one does not take). The planes come from
+        the pool and may hold anything: both branches write every byte
+        of all five."""
         counts = [len(p[2]) for p in parsed]
-        n_ops = sum(counts)
-
-        def column(name: str, parts: list, dtype) -> np.ndarray:
-            """The blocks' per-op arrays end to end, in scratch."""
-            a = self._scratch.get(name)
-            if a is None or len(a) < n_ops:
-                a = self._scratch[name] = np.empty(n_ops, dtype)
-            return np.concatenate(parts, out=a[:n_ops])
-
-        off_all = column("off", [p[2] for p in parsed], np.int64)  # in-block
-        klen_all = column("klen", [p[3] for p in parsed], np.int64)
-        vlen_all = column("vlen", [p[4] for p in parsed], np.int64)
-        op_all = column("op", [p[5] for p in parsed], np.uint8)
-        sh_all = column("sh", [p[0].shards for p in parsed], np.int64)
+        off_all = np.concatenate([p[2] for p in parsed])  # in-block
+        klen_all = np.concatenate([p[3] for p in parsed])
+        vlen_all = np.concatenate([p[4] for p in parsed])
+        op_all = np.concatenate([p[5] for p in parsed])
+        sh_all = np.concatenate([p[0].shards for p in parsed])
         W = len(parsed)
         n = self.n_shards
         # full-width sorted blocks (the block lane's shape): op i of a
         # block is shard i's, so the scatter is a contiguous assign —
         # advanced-index scatters on 500k+ rows were ~half the gather cost
-        grid = n_ops == W * n and bool(
+        grid = len(off_all) == W * n and bool(
             (sh_all.reshape(W, n) == np.arange(n)[None, :]).all()
         )
-        if grid and self._native_pack_gather(
-            [p[1] for p in parsed], off_all, klen_all, vlen_all, op_all,
-            n, kind_w, klen_w, vlen_w, kwin_w, vwin_w,
-        ):
-            return
         ku, vu = kwin_w.shape[2], vwin_w.shape[2]
         kcols = np.arange(ku)[None, :]
         vcols = np.arange(vu)[None, :]
@@ -593,7 +708,7 @@ class DeviceKVTable:
         vw = dbuf_all[vidx]
         vw = np.where(vcols < vlen_all[:, None], vw, 0)
         if grid:
-            # whole rows, whatever the native gather left behind
+            # whole rows, whatever a native gather left behind
             for plane, vals in (
                 (kind_w, op_all), (klen_w, klen_all), (vlen_w, vlen_all),
                 (kwin_w, kw), (vwin_w, vw),
@@ -610,50 +725,11 @@ class DeviceKVTable:
             kwin_w[t_all, sh_all] = kw
             vwin_w[t_all, sh_all] = vw
 
-    def _native_pack_gather(
-        self, dbufs, off_all, klen_all, vlen_all, op_all, n,
-        kind_w, klen_w, vlen_w, kwin_w, vwin_w,
-    ) -> bool:
-        """One-pass C gather into the five planes, every row written
-        whole (GRID fast path only: op ``t * n + i`` is wave t, shard
-        i). The blocks' bytes are read where they lie, ``dbufs[t]`` by
-        its own base pointer with ``off_all`` relative to it, so the
-        window's bytes are never concatenated. The numpy gather stays
-        the semantics owner — False (library unavailable,
-        ``RABIA_PY_DEVPACK=1``, or the C bounds check tripping, which
-        leaves the planes half written) routes the caller to it, and
-        it writes every row again. Byte-equivalence with the numpy
-        path is pinned in tests/test_device_kv.py."""
-        import os
-
-        # =1 opts out, matching the docstring/tests convention — a plain
-        # truthiness test made RABIA_PY_DEVPACK=0 ALSO disable the
-        # native gather
-        if os.environ.get("RABIA_PY_DEVPACK") == "1":
-            return False
-        from rabia_tpu.native.build import load_hostkernel
-
-        lib = load_hostkernel()
-        if lib is None:
-            return False
-        dbufs = [np.ascontiguousarray(d, np.uint8) for d in dbufs]
-        bases = np.array([d.ctypes.data for d in dbufs], np.uintp)
-        lens = np.array([len(d) for d in dbufs], np.int64)
-        W, S, ku = kwin_w.shape
-        rc = lib.rk_pack_gather(
-            W, n, S, _SET_HDR, ku, vwin_w.shape[2],
-            bases.ctypes.data, lens.ctypes.data,
-            off_all.ctypes.data, klen_all.ctypes.data,
-            vlen_all.ctypes.data, op_all.ctypes.data,
-            kind_w.ctypes.data, klen_w.ctypes.data, vlen_w.ctypes.data,
-            kwin_w.ctypes.data, vwin_w.ctypes.data,
-        )
-        return rc == 0
-
     def pack_window(self, blocks) -> Optional[DeviceWindowOps]:
         """Pack SET-only ``blocks`` (one per wave, FIFO order) into
         device inputs; None when outside the write lane's envelope —
-        the caller demotes. All numpy, no per-op Python loop."""
+        the caller demotes. Native code or numpy (see
+        :meth:`_gather_window`), no per-op Python loop."""
         g = self._gather_window(blocks, "set")
         if g is None:
             return None

@@ -165,7 +165,7 @@ class _DeviceSpan:
         self.t0 = time.perf_counter()
 
     def __enter__(self):
-        return None
+        return self.ann
 
     def __exit__(self, *exc):
         if self.ann is not None:
@@ -182,7 +182,10 @@ def device_annotation(name: str, **stats):
     enabled. With no profiler session and the tracer off it reads no
     clock and allocates only the annotation. The span begins where this
     is called (a ``TraceAnnotation``'s event starts at its construction,
-    not at ``__enter__``): call it in the ``with`` statement itself."""
+    not at ``__enter__``): call it in the ``with`` statement itself.
+    ``with device_annotation(name) as span:`` binds the profiler's
+    annotation, or None where there is none: an argument known only
+    inside the span is added by ``span.set_metadata(path=...)``."""
     cls = _annotation_cls
     if cls is False:
         cls = _resolve_annotation_cls()

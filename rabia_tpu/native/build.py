@@ -244,11 +244,17 @@ def load_hostkernel() -> ctypes.CDLL | None:
         lib.rk_open_scan.argtypes = [
             ctypes.c_int32, p, p, p, p, p, p, p, p, p, p,
         ]
+        lib.rk_pack_scan.restype = ctypes.c_int32
+        lib.rk_pack_scan.argtypes = [
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_uint64, ctypes.c_int64, ctypes.c_int64,
+            p, p, p, p, p,
+        ]
         lib.rk_pack_gather.restype = ctypes.c_int32
         lib.rk_pack_gather.argtypes = [
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            p, p, p, p, p, p,
+            p, p, p,
             p, p, p, p, p,
         ]
         lib.rk_stall_scan.restype = ctypes.c_int32

@@ -225,44 +225,97 @@ void rk_start_slots(
   }
 }
 
-// Columnar open-candidate scan (engine _open_slots prologue): one pass
-// instead of ~9 numpy dispatches per tick. Fills head[s] =
-// max(next_slot, applied) and cand[s]; returns the candidate count so an
-// idle tick exits on a single int.
-// Device-KV window pack gather (the GRID fast path: W full-width sorted
-// blocks, op t * n + s covers wave t, shard s). One pass reads each
-// block's padded bytes where they lie (W base pointers; an op's offset
-// is relative to its own block) and, per op, its offset, key length,
-// value length and opcode, and writes the five padded planes —
-// replacing numpy's concatenate + materialize-gather + where-mask +
-// reshape-scatter chain (~4 full passes over the op bytes) with a
-// single read+write. The planes may hold anything on entry (they are
-// reused across windows): every row is written whole, its bytes then
-// zeros to the row's width, and so are the columns n..S that no op
-// covers. Validation stays in Python (the numpy path remains the
-// semantics owner and fallback); this function only trusts its own
-// bounds check and returns nonzero on any out-of-range op so the
-// caller can fall back.
+// Device-KV window pack (the GRID shape: W full-width blocks, one op a
+// shard, shards 0..n-1 in order; op s of block t is wave t, shard s).
+// Two passes over what a PayloadBlock holds, read where it lies: per
+// block the base pointers of `data` (the ops' bytes end to end),
+// `cmd_sizes`, `counts` and `shards` (i64 each), as `cols[3 * t + 0..2]`
+// with their lengths beside them. An op's offset is the running sum of
+// its block's sizes; its header is `u8 opcode | u16 klen LE`, its value
+// what is left of its size after header and key.
+//
+// rk_pack_scan validates the window against the packers' envelope and
+// finds its widest key and value. It checks all that the numpy parse
+// (apps/device_kv.py _parse_window, the semantics owner) checks, and
+// the grid shape itself: counts == 1, shards 0..n-1 in order, a block
+// at least `hdr` bytes an op, every op inside its block's bytes,
+// opcode in `allow` (bit o set = opcode o allowed), 0 < klen <= K,
+// 0 <= vlen <= VW, vlen == 0 unless SET. It returns 0 and fills
+// `widest` ({klen, vlen}), or nonzero ("not mine": the caller runs the
+// numpy parse on the whole window, which alone decides what is outside
+// the envelope). It writes nothing else.
+static const uint64_t OP_SET = 1;
+
+int32_t rk_pack_scan(
+    int64_t W, int64_t n, int64_t hdr, uint64_t allow, int64_t K,
+    int64_t VW,
+    const uint8_t* const* data, const int64_t* data_len,
+    const int64_t* const* cols, const int64_t* cols_len,
+    int64_t* widest) {
+  int64_t kmax = 0, vmax = 0;
+  for (int64_t t = 0; t < W; t++) {
+    const int64_t* sizes = cols[3 * t];
+    const int64_t* counts = cols[3 * t + 1];
+    const int64_t* shards = cols[3 * t + 2];
+    const int64_t dlen = data_len[t];
+    if (cols_len[3 * t] != n || cols_len[3 * t + 1] != n ||
+        cols_len[3 * t + 2] != n || dlen < hdr * n) {
+      return 1;
+    }
+    const uint8_t* d = data[t];
+    int64_t off = 0;
+    for (int64_t s = 0; s < n; s++) {
+      const int64_t sz = sizes[s];
+      if (counts[s] != 1 || shards[s] != s || sz < hdr ||
+          sz > dlen - off) {
+        return 1;
+      }
+      const uint64_t op = d[off];
+      const int64_t kl = (int64_t)d[off + 1] | ((int64_t)d[off + 2] << 8);
+      const int64_t vl = sz - hdr - kl;
+      if (op >= 64 || !((allow >> op) & 1) || kl <= 0 || kl > K ||
+          vl < 0 || vl > VW || (vl != 0 && op != OP_SET)) {
+        return 1;
+      }
+      if (kl > kmax) kmax = kl;
+      if (vl > vmax) vmax = vl;
+      off += sz;
+    }
+  }
+  widest[0] = kmax;
+  widest[1] = vmax;
+  return 0;
+}
+
+// rk_pack_gather writes the window's five padded planes from the same
+// pointers, reading each op's header itself: one read of the sizes and
+// the op bytes, one write of the planes (the numpy gather concatenates,
+// materializes, masks and scatters: ~4 passes over the op bytes). The
+// planes may hold anything on entry (they are reused across windows):
+// every row is written whole, its bytes then zeros to the row's width,
+// and so are the columns n..S that no op covers. It trusts nothing the
+// scan found: an op outside its block's bytes or wider than the planes
+// returns nonzero with the planes half written, and the caller's numpy
+// path (the fallback) writes every row again.
 int32_t rk_pack_gather(
     int64_t W, int64_t n, int64_t S, int64_t hdr, int64_t ku, int64_t vu,
-    const uint8_t* const* dbuf, const int64_t* dbuf_len,
-    const int64_t* off, const int64_t* klen, const int64_t* vlen,
-    const uint8_t* op,
+    const uint8_t* const* data, const int64_t* data_len,
+    const int64_t* const* cols,
     int8_t* kind_w, int16_t* klen_w, int16_t* vlen_w,
     uint8_t* kwin, uint8_t* vwin) {
   for (int64_t t = 0; t < W; t++) {
-    const uint8_t* d = dbuf[t];
+    const uint8_t* d = data[t];
+    const int64_t* sizes = cols[3 * t];
+    const int64_t dlen = data_len[t];
+    int64_t off = 0;
     for (int64_t s = 0; s < n; s++) {
-      const int64_t i = t * n + s;
-      const int64_t kl = klen[i];
-      const int64_t vl = vlen[i];
-      const int64_t o = off[i] + hdr;
-      if (kl < 0 || vl < 0 || kl > ku || vl > vu || o < 0 ||
-          o + kl + vl > dbuf_len[t]) {
-        return 1;  // out of envelope/bounds: caller uses the numpy path
-      }
+      const int64_t sz = sizes[s];
+      if (sz < hdr || sz > dlen - off) return 1;
+      const int64_t kl = (int64_t)d[off + 1] | ((int64_t)d[off + 2] << 8);
+      const int64_t vl = sz - hdr - kl;
+      if (kl > ku || vl < 0 || vl > vu) return 1;
       const int64_t row = t * S + s;
-      kind_w[row] = (int8_t)op[i];
+      kind_w[row] = (int8_t)d[off];
       klen_w[row] = (int16_t)kl;
       vlen_w[row] = (int16_t)vl;
       uint8_t* k = kwin + row * ku;
@@ -270,9 +323,10 @@ int32_t rk_pack_gather(
       // zeros first, the bytes over them: one memset of the row's
       // width costs less than one of each tail's own length
       std::memset(k, 0, (size_t)ku);
-      std::memcpy(k, d + o, (size_t)kl);
+      std::memcpy(k, d + off + hdr, (size_t)kl);
       std::memset(v, 0, (size_t)vu);
-      if (vl) std::memcpy(v, d + o + kl, (size_t)vl);
+      if (vl) std::memcpy(v, d + off + hdr + kl, (size_t)vl);
+      off += sz;
     }
     const int64_t row = t * S + n;  // the wave's uncovered columns
     const size_t pad = (size_t)(S - n);
@@ -285,6 +339,10 @@ int32_t rk_pack_gather(
   return 0;
 }
 
+// Columnar open-candidate scan (engine _open_slots prologue): one pass
+// instead of ~9 numpy dispatches per tick. Fills head[s] =
+// max(next_slot, applied) and cand[s]; returns the candidate count so an
+// idle tick exits on a single int.
 int32_t rk_open_scan(
     int32_t S,
     const int64_t* next_slot, const int64_t* applied,

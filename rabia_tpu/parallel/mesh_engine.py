@@ -559,6 +559,23 @@ class MeshEngine:
                     else 0
                 ),
             )
+        for _path in ("native", "numpy"):
+            m.counter(
+                "devkv_pack_windows_total",
+                "Windows the pack was asked for by the path that packed "
+                "them (the path= of the rabia.cycle.pack.parse spans): "
+                "native = one C scan and one C gather read the blocks "
+                "where they lie (full-width blocks, one op a shard, "
+                "shards in order, every op inside the envelope), numpy "
+                "= the numpy parse and gather (any other window, the "
+                "ones it refuses included, and RABIA_PY_DEVPACK=1)",
+                {"path": _path},
+                fn=(
+                    lambda p=_path: self._dev.pack_windows[p]
+                    if self._dev is not None
+                    else 0
+                ),
+            )
         m.counter(
             "devkv_sync_rows_total",
             "Device table rows materialized on the host by dump() (the "

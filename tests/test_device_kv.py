@@ -1484,8 +1484,9 @@ def _width_blocks(n: int, allow: str, klen: int, vmax: int, W: int = 5):
     ]
 
 
-def _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch) -> tuple:
-    """The semantics owner's planes: the numpy gather into zeroed ones."""
+def _numpy_planes_on_zeros(dev, blocks, allow) -> tuple:
+    """The semantics owner's planes: the numpy parse, and the numpy
+    gather into zeroed ones."""
     parsed, ku, vu = dev._parse_window(blocks, allow)
     W, S = len(blocks), dev.S
     planes = (
@@ -1495,9 +1496,7 @@ def _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch) -> tuple:
         np.zeros((W, S, ku), np.uint8),
         np.zeros((W, S, vu), np.uint8),
     )
-    monkeypatch.setenv("RABIA_PY_DEVPACK", "1")
     dev._gather_into(parsed, *planes)
-    monkeypatch.delenv("RABIA_PY_DEVPACK")
     return planes
 
 
@@ -1532,12 +1531,18 @@ def native_gather_calls(monkeypatch):
     calls = []
     orig = DeviceKVTable._native_pack_gather
 
-    def spy(self_, *a):
-        calls.append(orig(self_, *a))
+    def spy(self_, scan, *planes):
+        calls.append(orig(self_, scan, *planes))
         return calls[-1]
 
     monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", spy)
     return calls
+
+
+def _packed_by(dev, before: dict) -> dict:
+    """Windows packed since ``before`` (a copy of ``dev.pack_windows``),
+    by path: what ``devkv_pack_windows_total`` would have grown by."""
+    return {k: v - before[k] for k, v in dev.pack_windows.items()}
 
 
 @pytest.mark.parametrize("n", [12, 16], ids=["padded", "full"])
@@ -1547,16 +1552,18 @@ class TestGatherIntoDirtyPlanes:
     so a plane full of 0xFF ends up the numpy path's on a zeroed one."""
 
     def test_native_gather_over_changing_widths(
-        self, pack_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, n, allow
     ):
         dev = pack_tables[n]
         shapes = set()
         for klen, vmax in _WIDTHS:  # consecutive windows, other buckets
             blocks = _width_blocks(n, allow, klen, vmax)
-            want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+            want = _numpy_planes_on_zeros(dev, blocks, allow)
             native_gather_calls.clear()
+            before = dict(dev.pack_windows)
             got = _gather_into_dirty_pool(dev, blocks, allow)
             assert native_gather_calls == [True, True]
+            assert _packed_by(dev, before) == {"native": 2, "numpy": 0}
             _assert_same_planes(got, want)
             shapes.add(got[3].shape[2:] + got[4].shape[2:])
         assert len(shapes) == 2
@@ -1568,45 +1575,47 @@ class TestGatherIntoDirtyPlanes:
 
         spy = DeviceKVTable._native_pack_gather
 
-        def short_buffer(self_, dbufs, *a):
+        def short_block(self_, scan, *planes):
             # the C loop writes the first waves' rows, then meets an op
-            # that ends past the bytes of its block
-            last = dbufs[-1]
-            return spy(self_, [*dbufs[:-1], last[: len(last) // 2]], *a)
+            # that ends past the bytes it is told its block has (the
+            # scan saw them all: only memory that changed under the
+            # pack could do this)
+            scan[0].data_len[-1] //= 2
+            return spy(self_, scan, *planes)
 
         dev = pack_tables[n]
         blocks = _width_blocks(n, allow, 13, 40)
-        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
-        monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", short_buffer)
+        want = _numpy_planes_on_zeros(dev, blocks, allow)
+        monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", short_block)
         native_gather_calls.clear()
+        before = dict(dev.pack_windows)
         got = _gather_into_dirty_pool(dev, blocks, allow)
         assert native_gather_calls == [False, False]
+        assert _packed_by(dev, before) == {"native": 0, "numpy": 2}
         _assert_same_planes(got, want)
 
-    def test_block_bytes_of_another_layout_are_copied_for_c(
-        self, pack_tables, native_gather_calls, monkeypatch, n, allow
+    def test_block_arrays_of_another_layout_are_copied_for_c(
+        self, pack_tables, native_gather_calls, n, allow
     ):
-        from rabia_tpu.apps.device_kv import DeviceKVTable
-
-        spy = DeviceKVTable._native_pack_gather
-
-        def strided(self_, dbufs, *a):
-            # every other byte of a buffer twice as long: the same bytes,
-            # not contiguous
-            wide = np.repeat(dbufs[0], 2)
-            return spy(self_, [wide[::2], *dbufs[1:]], *a)
-
         dev = pack_tables[n]
         blocks = _width_blocks(n, allow, 13, 40)
-        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
-        monkeypatch.setattr(DeviceKVTable, "_native_pack_gather", strided)
+        want = _numpy_planes_on_zeros(dev, blocks, allow)
+        for b in blocks[::2]:
+            # every other element of an array twice as long, and 32-bit
+            # counts: the same numbers, not as C reads them
+            b.cmd_sizes = np.repeat(b.cmd_sizes, 2)[::2]
+            b.shards = np.repeat(b.shards, 2)[::2]
+            b.counts = b.counts.astype(np.int32)
+            assert not b.cmd_sizes.flags.c_contiguous
         native_gather_calls.clear()
+        before = dict(dev.pack_windows)
         got = _gather_into_dirty_pool(dev, blocks, allow)
         assert native_gather_calls == [True, True]
+        assert _packed_by(dev, before) == {"native": 2, "numpy": 0}
         _assert_same_planes(got, want)
 
     def test_scattered_blocks_take_the_numpy_path(
-        self, pack_tables, native_gather_calls, monkeypatch, n, allow
+        self, pack_tables, native_gather_calls, n, allow
     ):
         # blocks that are not the full sorted grid: the scatter covers
         # only the ops' cells, so the planes are cleared first
@@ -1623,13 +1632,252 @@ class TestGatherIntoDirtyPlanes:
             )
             for b in full
         ]
-        want = _numpy_planes_on_zeros(dev, blocks, allow, monkeypatch)
+        want = _numpy_planes_on_zeros(dev, blocks, allow)
+        before = dict(dev.pack_windows)
         got = _gather_into_dirty_pool(dev, blocks, allow)
         assert native_gather_calls == []
+        assert _packed_by(dev, before) == {"native": 0, "numpy": 2}
         _assert_same_planes(got, want)
         covered = np.zeros(dev.S, bool)
         covered[shards] = True
         assert got[1][:, covered].all() and not got[1][:, ~covered].any()
+
+
+# -- the native window pack: one C scan, one C gather, numpy the owner ------
+
+_K, _VW = 32, 64  # the tables' widest key and value (_mk's defaults)
+
+
+def _op(code: int, key: bytes, value: bytes = b"") -> bytes:
+    """One binary op as the wire has it: u8 opcode | u16 klen LE | key |
+    value. Says nothing about what the lane admits."""
+    return bytes([code]) + len(key).to_bytes(2, "little") + key + value
+
+
+def _shape_blocks(n: int, allow: str, shape: str) -> list:
+    """A window inside the ``allow`` envelope, by ``shape``: the four op
+    kinds interleaved (a mixed window; a SET or GET window has its one
+    kind), SETs of empty values among others, keys of exactly K and
+    values of exactly VW bytes, one block, or two (a lower rung of a
+    table built for five)."""
+    W = {"one_block": 1, "lower_rung": 2}.get(shape, 5)
+
+    def cmd(s: int, w: int) -> bytes:
+        code = {"set": 1, "get": 2, "mixed": 1 + (s + w) % 4}[allow]
+        klen = _K if shape == "widest" else 1 + (3 * s + w) % 11
+        key = bytes([97 + (s + w) % 26]) * klen
+        if code != 1:
+            return _op(code, key)
+        vlen = {
+            "zero_values": 0 if (s + w) % 8 else 7,
+            "widest": _VW,
+        }.get(shape, (5 * s + w) % 23)
+        return _op(1, key, bytes([48 + s % 10]) * vlen)
+
+    return [
+        build_block(list(range(n)), [[cmd(s, w)] for s in range(n)])
+        for w in range(W)
+    ]
+
+
+def _both_paths(dev, blocks, allow, monkeypatch) -> tuple:
+    """``_gather_window`` as it runs and under ``RABIA_PY_DEVPACK=1``,
+    with the windows each run packed, by path."""
+    monkeypatch.delenv("RABIA_PY_DEVPACK", raising=False)
+    before = dict(dev.pack_windows)
+    got = dev._gather_window(blocks, allow)
+    packed = _packed_by(dev, before)
+    monkeypatch.setenv("RABIA_PY_DEVPACK", "1")
+    before = dict(dev.pack_windows)
+    want = dev._gather_window(blocks, allow)
+    assert _packed_by(dev, before) == {"native": 0, "numpy": 1}
+    monkeypatch.delenv("RABIA_PY_DEVPACK")
+    return got, want, packed
+
+
+@pytest.mark.parametrize("n", [12, 16], ids=["padded", "full"])
+@pytest.mark.parametrize("allow", ["set", "get", "mixed"])
+@pytest.mark.parametrize(
+    "shape",
+    ["interleaved", "zero_values", "widest", "one_block", "lower_rung"],
+)
+def test_native_window_pack_matches_numpy(
+    pack_tables, native_gather_calls, monkeypatch, n, allow, shape
+):
+    """The native scan and gather against the numpy path: all five
+    planes byte for byte, the bucketed widths with them, and the
+    counter says that the first run was native (no case compares numpy
+    with numpy)."""
+    dev = pack_tables[n]
+    blocks = _shape_blocks(n, allow, shape)
+    got, want, packed = _both_paths(dev, blocks, allow, monkeypatch)
+    assert packed == {"native": 1, "numpy": 0}
+    assert native_gather_calls == [True]
+    _assert_same_planes(got, want)
+    _assert_same_planes(got, _numpy_planes_on_zeros(dev, blocks, allow))
+    _parsed, ku, vu = dev._parse_window(blocks, allow)
+    assert dev._native_scan(blocks, allow)[1:] == (ku, vu)
+    assert (got[3].shape[2], got[4].shape[2]) == (ku, vu)
+    if shape == "widest":
+        assert ku == _K and (allow == "get" or vu == _VW)
+        assert got[1][:, :n].min() == _K
+    if shape == "interleaved" and allow == "mixed":
+        assert set(np.unique(got[0][:, :n])) == {1, 2, 3, 4}
+    if shape == "zero_values" and allow != "get":
+        sets = got[0][:, :n] == 1
+        assert (got[2][:, :n][sets] == 0).any()
+    assert not got[1][:, n:].any() and not got[3][:, n:].any()
+
+
+def _rebuilt(block, *, shards=None, counts=None, sizes=None, data=None):
+    """``block`` with some of its arrays or its bytes replaced."""
+    from rabia_tpu.core.blocks import PayloadBlock
+
+    shards = block.shards if shards is None else shards
+    return PayloadBlock(
+        block.id,
+        shards,
+        np.full(len(shards), -1, np.int64),
+        block.counts if counts is None else counts,
+        block.cmd_sizes if sizes is None else sizes,
+        block.data if data is None else data,
+    )
+
+
+def _with_op(block, s: int, op: bytes):
+    """``block`` with shard ``s``'s one op replaced by ``op``."""
+    o = block.cmd_offsets
+    sizes = block.cmd_sizes.copy()
+    sizes[s] = len(op)
+    data = block.data[: o[s]] + op + block.data[o[s + 1] :]
+    return _rebuilt(block, sizes=sizes, data=data)
+
+
+# every way out of the envelope or the grid: what it does to the last
+# block of a sound window, and whether numpy then refuses the window
+# (None: the caller demotes) or packs it on its scatter path
+_WAYS_OUT = {
+    "opcode_outside_allow": (
+        lambda b, n, allow: _with_op(
+            b, 3, _op({"set": 2, "get": 1, "mixed": 9}[allow], b"k")
+        ),
+        True,
+    ),
+    "klen_zero": (lambda b, n, allow: _with_op(b, 3, _op(1, b"")), True),
+    "klen_over_K": (
+        lambda b, n, allow: _with_op(
+            b, 3, _op(2 if allow == "get" else 1, b"k" * (_K + 1))
+        ),
+        True,
+    ),
+    "vlen_over_VW": (
+        lambda b, n, allow: _with_op(b, 3, _op(1, b"k", b"v" * (_VW + 1))),
+        True,
+    ),
+    "get_with_value_bytes": (
+        lambda b, n, allow: _with_op(b, 3, _op(2, b"k", b"v")),
+        True,
+    ),
+    "two_ops_in_a_shard": (
+        lambda b, n, allow: _rebuilt(
+            b,
+            counts=np.r_[2, np.ones(n - 2, np.int64)],
+            shards=np.arange(n - 1),
+        ),
+        True,
+    ),
+    "missing_shard": (
+        lambda b, n, allow: build_block(
+            list(range(n - 1)),
+            [[bytes(c)] for s in range(n - 1) for c in b.commands_for(s)],
+        ),
+        False,
+    ),
+    "unsorted_shards": (
+        lambda b, n, allow: _rebuilt(b, shards=np.arange(n)[::-1].copy()),
+        False,
+    ),
+    "block_shorter_than_its_headers": (
+        lambda b, n, allow: _rebuilt(
+            b, sizes=np.full(n, 2, np.int64), data=b.data[: 2 * n]
+        ),
+        True,
+    ),
+    "cmd_size_under_3": (
+        lambda b, n, allow: _with_op(b, 3, b.data[:2]),
+        True,
+    ),
+}
+
+
+@pytest.mark.parametrize("allow", ["set", "get", "mixed"])
+@pytest.mark.parametrize("way", list(_WAYS_OUT))
+def test_window_outside_the_envelope_takes_the_numpy_path(
+    pack_tables, native_gather_calls, monkeypatch, allow, way
+):
+    """The native scan takes no window that has left the grid shape or
+    the envelope; the numpy parse then decides, as under
+    ``RABIA_PY_DEVPACK=1``: it refuses the window or packs it."""
+    n = 12
+    dev = pack_tables[n]
+    spoil, refused = _WAYS_OUT[way]
+    sound = _shape_blocks(n, allow, "interleaved")
+    assert dev._native_scan(sound, allow) is not None
+    for at in (0, len(sound) - 1):
+        blocks = list(sound)
+        blocks[at] = spoil(sound[at], n, allow)
+        assert dev._native_scan(blocks, allow) is None
+        got, want, packed = _both_paths(dev, blocks, allow, monkeypatch)
+        assert packed == {"native": 0, "numpy": 1}
+        assert native_gather_calls == []
+        if refused:
+            assert got is None and want is None
+        else:
+            _assert_same_planes(got, want)
+
+
+def test_engine_packs_a_mixed_window_natively_and_in_numpy(monkeypatch):
+    """One engine packs its windows natively, one under
+    ``RABIA_PY_DEVPACK=1``: the same replies, the same table, and
+    ``devkv_pack_windows_total`` counts a window each way."""
+    from rabia_tpu.native.build import load_hostkernel
+
+    if load_hostkernel() is None:
+        pytest.skip("native host kernel unavailable")
+    n, W = 12, 5
+    windows = [_shape_blocks(n, "set", "interleaved")] + [
+        _shape_blocks(n, "mixed", shape)
+        for shape in ("interleaved", "zero_values", "widest")
+    ]
+    replies, tables, counted = {}, {}, {}
+    for path in ("native", "numpy"):
+        if path == "numpy":
+            monkeypatch.setenv("RABIA_PY_DEVPACK", "1")
+        else:
+            monkeypatch.delenv("RABIA_PY_DEVPACK", raising=False)
+        eng = _mk(n, device=True, window=W)
+        futs = []
+        for blocks in windows:
+            futs += [eng.submit_block(_rebuilt(b)) for b in blocks]
+            eng.run_cycle()
+        eng.flush()
+        assert eng.device_lane_active
+        replies[path] = [_frames(f) for f in futs]
+        snap = eng.metrics.snapshot()
+        counted[path] = {
+            p: snap[f'rabia_devkv_pack_windows_total{{path="{p}"}}']
+            for p in ("native", "numpy")
+        }
+        assert f'rabia_devkv_pack_windows_total{{path="{path}"}}' in (
+            eng.metrics.render_prometheus()
+        )
+        eng.sync_to_host()
+        tables[path] = [_store_content(sm, n) for sm in eng.sms]
+        eng.close()
+    assert counted["native"] == {"native": len(windows), "numpy": 0}
+    assert counted["numpy"] == {"native": 0, "numpy": len(windows)}
+    assert replies["native"] == replies["numpy"]
+    assert tables["native"] == tables["numpy"] and tables["native"][0]
 
 
 def _same_window_read_blocks(n: int, W: int, k: int) -> list:

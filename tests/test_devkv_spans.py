@@ -233,6 +233,54 @@ def test_profiler_events_tile_the_dispatch(tmp_path):
     assert len(events["rabia.cycle.settle"]) == 2  # both windows settled
 
 
+@pytest.mark.parametrize("path", ["native", "numpy"])
+def test_parse_event_and_counter_say_which_path_packed(
+    tmp_path, monkeypatch, path
+):
+    """``rabia.cycle.pack.parse`` carries ``path=`` in the profiler's trace
+    (an argument added once the scan has answered), the name stays bare,
+    and ``devkv_pack_windows_total{path=}`` counts the same windows."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from rabia_tpu.native.build import load_hostkernel
+
+    if load_hostkernel() is None:
+        pytest.skip("native host kernel unavailable")
+    if path == "numpy":
+        monkeypatch.setenv("RABIA_PY_DEVPACK", "1")
+    else:
+        monkeypatch.delenv("RABIA_PY_DEVPACK", raising=False)
+    eng = _engine()
+    rng = np.random.default_rng(23)
+    _window(eng, "mixed", rng)  # the program's first call, untraced
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for kind in ("mixed", "set", "mixed"):
+            _window(eng, kind, rng)
+        eng.flush()
+    finally:
+        jax.profiler.stop_trace()
+    snap = eng.metrics.snapshot()
+    eng.close()
+    (file,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    said = [
+        dict(e.stats).get("path")
+        for plane in ProfileData.from_file(file).planes
+        for line in plane.lines
+        for e in line.events
+        if e.name == "rabia.cycle.pack.parse"
+    ]
+    assert said == [path] * 3
+    other = {"native": "numpy", "numpy": "native"}[path]
+    assert snap[f'rabia_devkv_pack_windows_total{{path="{path}"}}'] == 4
+    assert snap[f'rabia_devkv_pack_windows_total{{path="{other}"}}'] == 0
+
+
 class TestPipeSpans:
     """The pipe's own events (PR 38): the rung marker at every dispatch,
     the governor's resizes, the ladder's builds."""
