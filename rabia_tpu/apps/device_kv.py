@@ -445,9 +445,6 @@ class DeviceKVTable:
         self.rungs = tuple(sorted({int(w) for w in rungs}))
         self._laddered = len(self.rungs) > 1
         self._building_ladder = False
-        # programs the ladder built ahead of need, ever (the engine's
-        # devkv_ladder_programs_total reads it)
-        self.ladder_programs = 0
         # host bytes device_put for window dispatches, ever (the engine's
         # devkv_upload_bytes_total reads it)
         self.upload_bytes = 0
@@ -786,9 +783,7 @@ class DeviceKVTable:
         ``upload_bytes``."""
         nbytes = sum(a.nbytes for a in operands)
         self.upload_bytes += nbytes
-        return device_annotation(
-            "rabia.dispatch.place", bytes=nbytes, devices=self.n_devices
-        )
+        return device_annotation("rabia.dispatch.place", bytes=nbytes)
 
     def _program(self, key: tuple, W: int, build, at_rung):
         """The jitted program of signature ``key`` (static window size
@@ -810,9 +805,7 @@ class DeviceKVTable:
         self.compiled_on_last_call = fn is None
         if fn is None:
             fn = self._fused_cache[key] = build()
-            if self._building_ladder:
-                self.ladder_programs += 1
-            else:
+            if not self._building_ladder:
                 self._build_ladder(key, W, at_rung)
         return fn
 
@@ -824,10 +817,7 @@ class DeviceKVTable:
         others = [w for w in self.rungs if w != W]
         self._building_ladder = True
         try:
-            with device_annotation(
-                "rabia.ladder.build", sig=str(key),
-                rungs=",".join(map(str, others)),
-            ):
+            with device_annotation("rabia.ladder.build", sig=str(key)):
                 for w in others:
                     jax.block_until_ready(at_rung(w))
         finally:
@@ -1468,12 +1458,11 @@ class DeviceKVTable:
         shard-major slot order, plus the per-shard counters. Each plane
         is fetched once (gathered from every device of the mesh) and no
         Python runs per row."""
-        # the flags first: they say how many rows the span is about
+        # the flags first: they say which slots hold a row
         used = self._fetch(self.state[0])[: self.n_shards]
         s_idx, p_idx = np.nonzero(used)
         n = len(s_idx)
-        nbytes = sum(a.nbytes for a in self.state)
-        with device_annotation("rabia.sync.dump", rows=n, bytes=nbytes):
+        with device_annotation("rabia.sync.dump"):
             keyw, klen, ver, valw, vlen, sver = map(
                 self._fetch, self.state[1:]
             )
@@ -1615,7 +1604,7 @@ class DeviceKVTable:
         if "rows" in d:
             d = TableDump.from_rows(d["rows"], d["shard_version"], self.K)
         n = len(d["shards"])
-        with device_annotation("rabia.sync.rebuild", rows=n):
+        with device_annotation("rabia.sync.rebuild"):
             store = VectorKVStore(
                 self.n_shards, capacity=max(1 << 10, 2 * n)
             )

@@ -19,26 +19,37 @@ store.rs:15) and leaves profiling to external tools. Here:
 - :func:`device_annotation` — the device lane's one span helper:
   ``with device_annotation("rabia.cycle.pack"): ...`` emits a
   ``jax.profiler.TraceAnnotation`` (a TraceMe event in the profiler's own
-  ``.xplane.pb``, on the device trace's clock) and, when the tracer is
-  enabled, records the same interval into the :class:`Tracer` under the
-  same name. No-op when jax is absent and the tracer is off.
+  ``.xplane.pb``, on the device trace's clock) while a profiler session
+  is listening and, when the tracer is enabled, records the same interval
+  into the :class:`Tracer` under the same name. With neither it costs one
+  check (``TraceAnnotation.is_enabled()``) and returns the shared no-op:
+  no annotation is built and no clock read, which is what lets
+  ``submit_block`` (64 calls a window) and the readback workers carry
+  spans.
 
 Span naming taxonomy (dotted, coarse→fine):
   engine.tick.{drain,open,kernel,apply,timeouts}
   engine.kernel.{start,route,step,outbox}
   wire.{serialize,deserialize}
   sm.apply
-  rabia.cycle.{pack,book,wait,settle}, rabia.cycle.pack.{parse,alloc,gather},
-    rabia.cycle.settle.download
-  rabia.fetch.values (on a readback worker's thread, not the window's)
+  rabia.submit.{validate,route} (the client's call: two a ``submit_block``)
+  rabia.cycle.{kinds,pack,book,wait,settle}, rabia.cycle.pack.{parse,alloc,gather},
+    rabia.cycle.book.{versions,segment,handoff},
+    rabia.cycle.settle.{download,blocks}
+  rabia.fetch.{flags,meta,values} (on a readback worker's thread, not the
+    window's)
   rabia.devkv.{decide_apply,lookup_window,mixed_apply,read_probe}
     (reserved for the dispatch spans: the benchmark selects them by prefix)
   rabia.dispatch.{place,call}, rabia.jit.first_call
+  rabia.window.w<W>, rabia.governor.resize, rabia.ladder.build (the pipe)
+  rabia.setup.{native,engine} (set-up: read under ``RABIA_TRACE=1``)
+  rabia.sync.{dump,rebuild}
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -140,14 +151,25 @@ def span(name: str):
 
 
 _annotation_cls = False  # unresolved; None when jax is absent
+# TraceAnnotation.is_enabled, resolved with the class: is a profiler
+# session listening? None where jax is absent or has no such method
+# (every span is then built, as before there was a gate)
+_listening = None
 
 
 def _resolve_annotation_cls():
-    global _annotation_cls
+    global _annotation_cls, _listening
+    if "jax" not in sys.modules:
+        # nothing has imported jax yet, so no profiler session can be
+        # listening, and a span is not what imports it (the native
+        # loaders' spans are entered by processes that never touch jax):
+        # unresolved until somebody has
+        return None
     try:
         from jax.profiler import TraceAnnotation
     except ImportError:
         TraceAnnotation = None
+    _listening = getattr(TraceAnnotation, "is_enabled", None)
     _annotation_cls = TraceAnnotation
     return TraceAnnotation
 
@@ -179,17 +201,19 @@ def device_annotation(name: str, **stats):
     device lane's span: a TraceMe event in the JAX profiler's trace (the
     ``stats`` become the event's arguments in the trace viewer), and an
     aggregate under the same name in the :class:`Tracer` when that is
-    enabled. With no profiler session and the tracer off it reads no
-    clock and allocates only the annotation. The span begins where this
-    is called (a ``TraceAnnotation``'s event starts at its construction,
-    not at ``__enter__``): call it in the ``with`` statement itself.
+    enabled. With no profiler session listening and the tracer off it is
+    one check and the shared no-op: no annotation is built, no clock
+    read. The span begins where this is called (a ``TraceAnnotation``'s
+    event starts at its construction, not at ``__enter__``): call it in
+    the ``with`` statement itself.
     ``with device_annotation(name) as span:`` binds the profiler's
-    annotation, or None where there is none: an argument known only
-    inside the span is added by ``span.set_metadata(path=...)``."""
+    annotation, or None where there is none (nothing listens, or jax is
+    absent): an argument known only inside the span is added by
+    ``span.set_metadata(path=...)`` where ``span`` is not None."""
     cls = _annotation_cls
     if cls is False:
         cls = _resolve_annotation_cls()
-    ann = cls(name, **stats) if cls is not None else None
-    if tracer.enabled:
-        return _DeviceSpan(name, ann)
-    return ann if ann is not None else _NOOP
+    if cls is None or (_listening is not None and not _listening()):
+        return _DeviceSpan(name, None) if tracer.enabled else _NOOP
+    ann = cls(name, **stats)
+    return _DeviceSpan(name, ann) if tracer.enabled else ann

@@ -19,6 +19,9 @@ Four artifacts, all digest-keyed and built on first use:
   client session/dedup table; the Python SessionTable in
   gateway/session.py stays the semantics owner, RABIA_PY_GATEWAY=1
   forces it)
+
+The two the device lane loads (codec, hostkernel) build and load under the
+span ``rabia.setup.native`` (``RABIA_TRACE=1``: set-up by part).
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ import threading
 from pathlib import Path
 
 from rabia_tpu.core.errors import InternalError
+from rabia_tpu.core.tracing import device_annotation
 
 _HERE = Path(__file__).parent
 _SRC = _HERE / "transport.cpp"
@@ -168,14 +172,15 @@ def load_codec():
         if _CODEC_FAILED is not None:
             return None
         try:
-            target = _codec_path()
-            if not target.exists():
-                _build_codec(target)
-            spec = importlib.util.spec_from_file_location(
-                "rabia_native_codec", target
-            )
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
+            with device_annotation("rabia.setup.native"):
+                target = _codec_path()
+                if not target.exists():
+                    _build_codec(target)
+                spec = importlib.util.spec_from_file_location(
+                    "rabia_native_codec", target
+                )
+                mod = importlib.util.module_from_spec(spec)
+                spec.loader.exec_module(mod)
         except Exception as e:  # noqa: BLE001 - any failure means fallback
             _CODEC_FAILED = str(e)
             return None
@@ -203,13 +208,14 @@ def load_hostkernel() -> ctypes.CDLL | None:
         if _HK_FAILED is not None:
             return None
         try:
-            target = _hk_path()
-            if not target.exists():
-                _compile(
-                    _HK_SRC, target, ["-O3"], "_hostkernel_*.so",
-                    "hostkernel",
-                )
-            lib = ctypes.CDLL(os.fspath(target))
+            with device_annotation("rabia.setup.native"):
+                target = _hk_path()
+                if not target.exists():
+                    _compile(
+                        _HK_SRC, target, ["-O3"], "_hostkernel_*.so",
+                        "hostkernel",
+                    )
+                lib = ctypes.CDLL(os.fspath(target))
         except Exception as e:  # noqa: BLE001 - any failure means fallback
             _HK_FAILED = str(e)
             return None

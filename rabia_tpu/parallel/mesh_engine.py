@@ -190,6 +190,19 @@ def _prefetch_values(planes: tuple) -> tuple:
         return tuple(map(DeviceKVTable._fetch, planes))
 
 
+def _fetch_readback(name: str, *handles):
+    """A window's flags or meta on the host, as a readback worker fetches
+    them: the array of one handle, a tuple of several. ``name`` is the
+    span (``rabia.fetch.flags`` / ``rabia.fetch.meta``), which lies on
+    the worker's thread like ``rabia.fetch.values`` and on the same
+    clock as the device's ops: a trace viewer shows each fetch against
+    the window program that produced it and the span the window's
+    thread was in when the worker woke."""
+    with device_annotation(name, bytes=sum(int(h.nbytes) for h in handles)):
+        got = tuple(map(np.asarray, handles))
+    return got[0] if len(got) == 1 else got
+
+
 class MeshFuture:
     """Synchronously settled result holder for one submitted batch.
 
@@ -443,16 +456,6 @@ class MeshEngine:
                 fn=lambda w=_w: self._dev_windows[w],
             )
         m.counter(
-            "devkv_ladder_programs_total",
-            "Window programs the table's ladder built ahead of need: the "
-            "siblings, at every other rung, of a signature first needed "
-            "at one (inside the rabia.ladder.build spans; 0 without a "
-            "latency target)",
-            fn=lambda: (
-                self._dev.ladder_programs if self._dev is not None else 0
-            ),
-        )
-        m.counter(
             "engine_decided_total", "Slots decided (bulk device lane)",
             {"value": "v1"}, fn=lambda: self.decided_v1,
         )
@@ -663,10 +666,13 @@ class MeshEngine:
                     "device_store requires VectorShardedKV replica SMs "
                     "(the demotion target)"
                 )
-            self._dev = DeviceKVTable(
-                self.n_shards, self.kernel, rungs=self._ladder(),
-                **(device_store_kw or {}),
-            )
+            # set-up by part (RABIA_TRACE=1): the table's seven planes
+            # placed on the device, the engine's ladder handed to the table
+            with device_annotation("rabia.setup.engine"):
+                self._dev = DeviceKVTable(
+                    self.n_shards, self.kernel, rungs=self._ladder(),
+                    **(device_store_kw or {}),
+                )
             self._dev_active = True
             # host mirror of the device per-shard version counters:
             # response versions derive from it (no per-op readback)
@@ -761,42 +767,52 @@ class MeshEngine:
         engine's submit_block analog). Decided entries apply with ZERO
         repacking — the submitted block IS the apply input — so per-slot
         Python overhead drops to a queue pop and a future index."""
-        shards = np.asarray(block.shards, np.int64)
-        if len(shards) == 0:
-            raise ValidationError("empty block")
-        if int(shards.min()) < 0 or int(shards.max()) >= self.n_shards:
-            raise ValidationError("block shard out of range")
-        if len(np.unique(shards)) != len(shards):
-            # build_block enforces this, but a hand-constructed or
-            # codec-decoded PayloadBlock may not have been through it —
-            # a duplicate shard would corrupt slot accounting
-            raise ValidationError("block shards must be unique")
-        bfut = MeshBlockFuture(len(shards))
-        if len(shards) == self.n_shards and self._queued_entries == 0:
-            if (
-                self._dev_read_lane
-                and self._dev_active
-                and _block_op_kind(block) == 2
-            ):
-                # read-index lane: the GET never enters the consensus
-                # stream — it parks with a write barrier (every block
-                # staged so far) and serves from a consensus-free probe
-                # window once those writes have dispatched
-                self._read_pending.append((block, bfut, self._dev_wseq))
-                return bfut
-            # full-width block with nothing queued: the vectorized lane
-            inv = np.empty(self.n_shards, np.int64)
-            inv[shards] = np.arange(len(shards))
-            self._full_blocks.append((block, bfut, inv))
-            self._dev_wseq += 1
-            return bfut
-        if self._full_blocks:
-            self._demote_full_blocks()
-        for i, s in enumerate(shards.tolist()):
-            self.queues[s].append(
-                _Pending(None, None, block=block, bidx=i, bfut=bfut)
-            )
-            self._queued_entries += 1
+        # the client's call: two spans a call and none per op, which the
+        # one check a disabled span costs makes affordable (core/tracing)
+        with device_annotation("rabia.submit.validate", n=len(block.shards)):
+            shards = np.asarray(block.shards, np.int64)
+            if len(shards) == 0:
+                raise ValidationError("empty block")
+            if int(shards.min()) < 0 or int(shards.max()) >= self.n_shards:
+                raise ValidationError("block shard out of range")
+            if len(np.unique(shards)) != len(shards):
+                # build_block enforces this, but a hand-constructed or
+                # codec-decoded PayloadBlock may not have been through it —
+                # a duplicate shard would corrupt slot accounting
+                raise ValidationError("block shards must be unique")
+        with device_annotation("rabia.submit.route") as span:
+            bfut = MeshBlockFuture(len(shards))
+            if len(shards) == self.n_shards and self._queued_entries == 0:
+                if (
+                    self._dev_read_lane
+                    and self._dev_active
+                    and _block_op_kind(block) == 2
+                ):
+                    # read-index lane: the GET never enters the consensus
+                    # stream — it parks with a write barrier (every block
+                    # staged so far) and serves from a consensus-free probe
+                    # window once those writes have dispatched
+                    lane = "read"
+                    self._read_pending.append((block, bfut, self._dev_wseq))
+                else:
+                    # full-width block with nothing queued: the vectorized
+                    # lane
+                    lane = "full"
+                    inv = np.empty(self.n_shards, np.int64)
+                    inv[shards] = np.arange(len(shards))
+                    self._full_blocks.append((block, bfut, inv))
+                    self._dev_wseq += 1
+            else:
+                lane = "queue"
+                if self._full_blocks:
+                    self._demote_full_blocks()
+                for i, s in enumerate(shards.tolist()):
+                    self.queues[s].append(
+                        _Pending(None, None, block=block, bidx=i, bfut=bfut)
+                    )
+                    self._queued_entries += 1
+            if span is not None:
+                span.set_metadata(lane=lane)
         return bfut
 
     # -- fault injection -----------------------------------------------------
@@ -1241,16 +1257,17 @@ class MeshEngine:
         # GET ops — runs the MIXED program over the full window instead
         # of splitting at the boundary (round-4 behavior), so
         # interleaved workloads no longer pay window quantization
-        kinds = [
-            _block_op_kind(self._full_blocks[i][0])
-            for i in range(min(len(self._full_blocks), W))
-        ]
-        head_kind = kinds[0] if kinds else None
-        depth = 0
-        for k in kinds:
-            if k != head_kind:
-                break
-            depth += 1
+        count = min(len(self._full_blocks), W)
+        with device_annotation("rabia.cycle.kinds", blocks=count):
+            kinds = [
+                _block_op_kind(self._full_blocks[i][0]) for i in range(count)
+            ]
+            head_kind = kinds[0] if kinds else None
+            depth = 0
+            for k in kinds:
+                if k != head_kind:
+                    break
+                depth += 1
         # mixed and GET windows PIPELINE like SET windows: they dispatch
         # chained on the newest in-flight window's output state and join
         # _dev_pipe. (They used to drain the pipe and read their
@@ -1261,7 +1278,7 @@ class MeshEngine:
             or depth < len(kinds)
             or head_kind in (3, 4)  # DEL/EXISTS runs ride the mixed program
         ):
-            return self._run_cycle_fullwidth_device_mixed(len(kinds))
+            return self._run_cycle_fullwidth_device_mixed(count)
         if head_kind == 2:
             return self._run_cycle_fullwidth_device_get(depth)
         entries = [self._full_blocks[i] for i in range(depth)]  # peek
@@ -1304,35 +1321,39 @@ class MeshEngine:
             # derivation then defers to settlement like the mixed lane's
             # (_dev_settle_set patches the provisional segment).
             deferred = self._dev_defer > 0
-            if deferred:
-                vers = None
-                sver_delta = None
-                seg_start = np.zeros_like(self._dev_sver)
-                seg_end = np.zeros_like(self._dev_sver)
-            else:
-                vers = (
-                    self._dev_sver[None, : self.S]
-                    + np.arange(1, W + 1, dtype=np.int64)[:, None]
-                )
+            with device_annotation("rabia.cycle.book.versions"):
+                if deferred:
+                    vers = None
+                    sver_delta = None
+                    seg_start = np.zeros_like(self._dev_sver)
+                    seg_end = np.zeros_like(self._dev_sver)
+                else:
+                    vers = (
+                        self._dev_sver[None, : self.S]
+                        + np.arange(1, W + 1, dtype=np.int64)[:, None]
+                    )
+                    seg_start = self._dev_sver.copy()
+                    seg_end = seg_start.copy()
+                    seg_end[:n] += depth
+                    sver_delta = np.zeros_like(self._dev_sver)
+                    sver_delta[:n] = depth
+                    self._dev_sver[:n] += depth
+            with device_annotation("rabia.cycle.book.segment") as span:
                 # retain this window's value bytes host-side: (shard,
                 # version) uniquely identifies content, so the GET lane can
                 # answer reads without downloading values (see _dev_resolve)
-                seg_start = self._dev_sver.copy()
-                seg_end = seg_start.copy()
-                seg_end[:n] += depth
-            seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
-            if deferred:
-                seg.provisional = True
-                self._dev_defer += 1
-            self._dev_push_segment(seg)
-            if not deferred:
-                self._dev_sver[:n] += depth
-                sver_delta = np.zeros_like(self._dev_sver)
-                sver_delta[:n] = depth
+                seg = _RowSeg(seg_start, seg_end, ops.vlen, ops.vwin)
+                if deferred:
+                    seg.provisional = True
+                    self._dev_defer += 1
+                self._dev_push_segment(seg)
+                if span is not None:
+                    span.set_metadata(bytes=seg.nbytes)
             self._dev_commit_window(entries, depth)
+            flags_fut, _, _ = self._dev_hand_off(flags_dev, (), None)
             rec = {
                 "kind": "set",
-                "flags_fut": self._dev_fetcher().submit(np.asarray, flags_dev),
+                "flags_fut": flags_fut,
                 "new_state": new_state,
                 "entries": entries,
                 "depth": depth,
@@ -1424,6 +1445,29 @@ class MeshEngine:
                 thread_name_prefix="devkv-flags",
             )
         return self._dev_fetcher_pool
+
+    def _dev_hand_off(self, flags, meta: tuple, planes):
+        """The end of a window's ``book``: its readbacks handed to the
+        workers, the small ones first (flags, then meta) so that neither
+        queues behind the value planes' bulk copy. Returns the three
+        futures, None for a readback the window does not have (a probe
+        window's flags, a SET window's meta) or does not prefetch."""
+        with device_annotation("rabia.cycle.book.handoff") as span:
+            pool = self._dev_fetcher()
+            flags_fut = meta_fut = None
+            if flags is not None:
+                flags_fut = pool.submit(
+                    _fetch_readback, "rabia.fetch.flags", flags
+                )
+            if meta:
+                meta_fut = pool.submit(
+                    _fetch_readback, "rabia.fetch.meta", *meta
+                )
+            val_fut = self._dev_prefetch_values(pool, planes)
+            if span is not None:
+                futs = (flags_fut, meta_fut, val_fut)
+                span.set_metadata(fetches=sum(f is not None for f in futs))
+        return flags_fut, meta_fut, val_fut
 
     def close(self) -> None:
         """Release engine-held resources: settle in-flight device
@@ -1576,11 +1620,13 @@ class MeshEngine:
             self._dev_evict_segments()
             self._dev_sver[:n] += depth
             self._dev_defer -= 1
-        for t, (block, bfut, _inv) in enumerate(rec["entries"]):
-            row = vers[t, np.asarray(block.shards, np.int64)]
-            frames = VectorShardedKV._vers_frames(row)
-            bounds = np.arange(len(block) + 1, dtype=np.int64)
-            bfut._settle_bulk(FrameGroups(frames, bounds))
+        entries = rec["entries"]
+        with device_annotation("rabia.cycle.settle.blocks", blocks=len(entries)):
+            for t, (block, bfut, _inv) in enumerate(entries):
+                row = vers[t, np.asarray(block.shards, np.int64)]
+                frames = VectorShardedKV._vers_frames(row)
+                bounds = np.arange(len(block) + 1, dtype=np.int64)
+                bfut._settle_bulk(FrameGroups(frames, bounds))
 
     def _dev_prefetch_values(self, pool, planes):
         """At dispatch: start the fetch of a GET-bearing window's value
@@ -1647,16 +1693,18 @@ class MeshEngine:
             # eviction edge: the window pays the value-plane download
             self._read_stats["fallback"] += depth * rec["n"]
             vlen, valw = planes
-        for t, (block, bfut, _inv) in enumerate(rec["entries"]):
-            sh = np.asarray(block.shards, np.int64)
-            if resolved:
-                bfut._settle_bulk(
-                    ResolvedGetFrameGroups(sh, found[t], ver[t], rsv)
-                )
-            else:
-                bfut._settle_bulk(
-                    GetFrameGroups(sh, found[t], ver[t], vlen[t], valw[t])
-                )
+        entries = rec["entries"]
+        with device_annotation("rabia.cycle.settle.blocks", blocks=len(entries)):
+            for t, (block, bfut, _inv) in enumerate(entries):
+                sh = np.asarray(block.shards, np.int64)
+                if resolved:
+                    bfut._settle_bulk(
+                        ResolvedGetFrameGroups(sh, found[t], ver[t], rsv)
+                    )
+                else:
+                    bfut._settle_bulk(
+                        GetFrameGroups(sh, found[t], ver[t], vlen[t], valw[t])
+                    )
 
     def _dev_settle_mixed(self, rec) -> None:
         """Settle a clean mixed window: SET versions derive from the
@@ -1721,34 +1769,36 @@ class MeshEngine:
                 rsv = self._dev_make_resolver()
             else:
                 (gval_h,) = planes
-        for t, (block, bfut, _inv) in enumerate(rec["entries"]):
-            sh = np.asarray(block.shards, np.int64)
-            row_kind = kind[t]
-            gf = None
-            if t in gpos:
-                j = gpos[t]
-                if resolved:
-                    gf = ResolvedGetFrameGroups(
-                        sh, gfound_h[j], gver_h[j], rsv
-                    )
+        entries = rec["entries"]
+        with device_annotation("rabia.cycle.settle.blocks", blocks=len(entries)):
+            for t, (block, bfut, _inv) in enumerate(entries):
+                sh = np.asarray(block.shards, np.int64)
+                row_kind = kind[t]
+                gf = None
+                if t in gpos:
+                    j = gpos[t]
+                    if resolved:
+                        gf = ResolvedGetFrameGroups(
+                            sh, gfound_h[j], gver_h[j], rsv
+                        )
+                    else:
+                        gf = GetFrameGroups(
+                            sh, gfound_h[j], gver_h[j], gvlen_h[j], gval_h[j]
+                        )
+                if gf is None:
+                    # pure-SET wave inside a mixed window: the lean framing
+                    frames = VectorShardedKV._vers_frames(svers[t, sh])
+                    bounds = np.arange(len(block) + 1, dtype=np.int64)
+                    bfut._settle_bulk(FrameGroups(frames, bounds))
+                elif not bool(((row_kind == 1) | (row_kind >= 3)).any()):
+                    bfut._settle_bulk(gf)  # pure-GET wave (GET framing only)
                 else:
-                    gf = GetFrameGroups(
-                        sh, gfound_h[j], gver_h[j], gvlen_h[j], gval_h[j]
+                    # the reply owns its kind row: a view would hold the
+                    # window's whole kind plane out of the pack's pool for
+                    # as long as a client keeps the reply unread
+                    bfut._settle_bulk(
+                        MixedFrameGroups(sh, row_kind.copy(), svers[t], gf)
                     )
-            if gf is None:
-                # pure-SET wave inside a mixed window: the lean framing
-                frames = VectorShardedKV._vers_frames(svers[t, sh])
-                bounds = np.arange(len(block) + 1, dtype=np.int64)
-                bfut._settle_bulk(FrameGroups(frames, bounds))
-            elif not bool(((row_kind == 1) | (row_kind >= 3)).any()):
-                bfut._settle_bulk(gf)  # pure-GET wave (GET framing only)
-            else:
-                # the reply owns its kind row: a view would hold the
-                # window's whole kind plane out of the pack's pool for
-                # as long as a client keeps the reply unread
-                bfut._settle_bulk(
-                    MixedFrameGroups(sh, row_kind.copy(), svers[t], gf)
-                )
 
     def _dev_drain_pipe(self) -> int:
         """Resolve every in-flight device window (used before any
@@ -1814,15 +1864,16 @@ class MeshEngine:
             self._read_stats["probe"] += depth * n
             self._read_stats["probe_windows"] += 1
             self._h_read_batch.observe(float(depth))
-            pool = self._dev_fetcher()
+            # no flags: nothing decided, nothing to read
+            _, meta_fut, val_fut = self._dev_hand_off(
+                None, (found_d, ver_d), (vlen_d, valw_d)
+            )
             rec = {
                 "kind": "read",
-                "flags_fut": None,  # nothing decided, nothing to read
-                "meta_fut": pool.submit(
-                    lambda f=found_d, v=ver_d: (np.asarray(f), np.asarray(v))
-                ),
+                "flags_fut": None,
+                "meta_fut": meta_fut,
                 "val_dev": (vlen_d, valw_d),
-                "val_fut": self._dev_prefetch_values(pool, (vlen_d, valw_d)),
+                "val_fut": val_fut,
                 # read-only: the chained state passes through untouched
                 "new_state": state_base,
                 "entries": batch,
@@ -1884,15 +1935,15 @@ class MeshEngine:
             self.cycles += 1
             self._read_stats["slot"] += depth * n  # GETs that consumed slots
             self._dev_commit_window(entries, depth)
-            pool = self._dev_fetcher()
+            flags_fut, meta_fut, val_fut = self._dev_hand_off(
+                all_v1_d, (found_d, ver_d), (vlen_d, valw_d)
+            )
             rec = {
                 "kind": "get",
-                "flags_fut": pool.submit(np.asarray, all_v1_d),
-                "meta_fut": pool.submit(
-                    lambda f=found_d, v=ver_d: (np.asarray(f), np.asarray(v))
-                ),
+                "flags_fut": flags_fut,
+                "meta_fut": meta_fut,
                 "val_dev": (vlen_d, valw_d),
-                "val_fut": self._dev_prefetch_values(pool, (vlen_d, valw_d)),
+                "val_fut": val_fut,
                 # read-only window: the chained state passes through
                 "new_state": state_base,
                 "entries": entries,
@@ -1959,9 +2010,6 @@ class MeshEngine:
                 self._dev.compiled_on_last_call and self._lat_timing
             )
             self.cycles += 1
-            # GET ops that rode consensus slots inside the mixed window
-            # (kind 2; DEL/EXISTS are not reads for the read-lane counters)
-            self._read_stats["slot"] += int((kind == 2).sum())
             # derived SET versions: host mirror + inclusive per-shard SET
             # count (GET waves advance nothing). Deferred windows push a
             # PROVISIONAL segment (empty placeholder range — matches no
@@ -1969,48 +2017,52 @@ class MeshEngine:
             # untouched; settlement patches range + svers from the exact
             # bump vector (SET always, DEL on found) and advances the
             # mirror then.
-            is_set = kind == 1  # [count, S]
-            set_cum = np.cumsum(is_set, axis=0, dtype=np.int64)
-            if deferred:
-                svers = None
-                sver_delta = None
+            with device_annotation("rabia.cycle.book.versions"):
+                # GET ops that rode consensus slots inside the mixed window
+                # (kind 2; DEL/EXISTS are not reads for the read-lane
+                # counters)
+                self._read_stats["slot"] += int((kind == 2).sum())
+                is_set = kind == 1  # [count, S]
+                set_cum = np.cumsum(is_set, axis=0, dtype=np.int64)
+                if deferred:
+                    svers = None
+                    sver_delta = None
+                    seg_start = np.zeros_like(self._dev_sver)
+                    seg_end = np.zeros_like(self._dev_sver)
+                else:
+                    svers = self._dev_sver[None, : self.S] + set_cum
+                    seg_start = self._dev_sver.copy()
+                    seg_end = seg_start + set_cum[-1]
+                    sver_delta = np.zeros_like(self._dev_sver)
+                    sver_delta[: self.S] = set_cum[-1]
+                    self._dev_sver += sver_delta
+            with device_annotation("rabia.cycle.book.segment") as span:
                 seg = _MixedSeg(
-                    np.zeros_like(self._dev_sver),
-                    np.zeros_like(self._dev_sver),
-                    ops.vlen, ops.vwin, set_cum, kind,
+                    seg_start, seg_end, ops.vlen, ops.vwin,
+                    set_cum if deferred else svers, kind,
                 )
-                seg.provisional = True
+                if deferred:
+                    seg.provisional = True
+                    self._dev_defer += 1
                 self._dev_push_segment(seg)
-                self._dev_defer += 1
-            else:
-                svers = self._dev_sver[None, : self.S] + set_cum
-                seg_start = self._dev_sver.copy()
-                seg = _MixedSeg(
-                    seg_start, seg_start + set_cum[-1], ops.vlen, ops.vwin,
-                    svers, kind,
-                )
-                self._dev_push_segment(seg)
-                sver_delta = np.zeros_like(self._dev_sver)
-                sver_delta[: self.S] = set_cum[-1]
-                self._dev_sver += sver_delta
+                if span is not None:
+                    span.set_metadata(bytes=seg.nbytes)
             self._dev_commit_window(entries, count)
-            pool = self._dev_fetcher()
             val_dev = (gval_dev,) if len(get_waves) else None
+            # meta fetched optimistically alongside the flags (a dirty
+            # window wastes one small transfer — the rollback edge); the
+            # value plane stays on the device unless the last settle had
+            # to download one (then a worker fetches it from here on) or
+            # this one's settle has to
+            flags_fut, meta_fut, val_fut = self._dev_hand_off(
+                flags_dev, (meta_dev,) if len(get_waves) else (), val_dev
+            )
             rec = {
                 "kind": "mixed",
-                "flags_fut": pool.submit(np.asarray, flags_dev),
-                # meta fetched optimistically alongside the flags (a
-                # dirty window wastes one small transfer — the rollback
-                # edge); the value plane stays on the device unless the
-                # last settle had to download one (then a worker
-                # fetches it from here on) or this one's settle has to
-                "meta_fut": (
-                    pool.submit(np.asarray, meta_dev)
-                    if len(get_waves)
-                    else None
-                ),
+                "flags_fut": flags_fut,
+                "meta_fut": meta_fut,
                 "val_dev": val_dev,
-                "val_fut": self._dev_prefetch_values(pool, val_dev),
+                "val_fut": val_fut,
                 "new_state": new_state,
                 "entries": entries,
                 "depth": count,
@@ -2314,14 +2366,13 @@ class MeshEngine:
         discarded speculative dispatch is not a cycle."""
         import jax.numpy as jnp
 
-        with device_annotation("rabia.mesh.slot_window"):
-            return self.kernel.slot_window(
-                jnp.asarray(votes),
-                self.kernel.place(jnp.asarray(self.alive)),
-                jnp.asarray(base),
-                n_slots=W,
-                max_phases=self.max_phases,
-            )
+        return self.kernel.slot_window(
+            jnp.asarray(votes),
+            self.kernel.place(jnp.asarray(self.alive)),
+            jnp.asarray(base),
+            n_slots=W,
+            max_phases=self.max_phases,
+        )
 
     def _run_window_multihost(
         self, votes: np.ndarray, base: np.ndarray, W: int

@@ -1,12 +1,16 @@
 """The device lane's spans and counters (core/tracing.device_annotation).
 
 Every window of the device lane enters the same spans once, whatever its
-kind: ``rabia.cycle.{pack,book,wait,settle}`` beside the dispatch span
-``rabia.devkv.<program>``, with ``rabia.cycle.pack.*`` and
-``rabia.dispatch.*`` / ``rabia.jit.first_call`` nested inside. With the
+kind: ``rabia.cycle.{kinds,pack,book,wait,settle}`` beside the dispatch span
+``rabia.devkv.<program>``, with ``rabia.cycle.pack.*``,
+``rabia.cycle.book.*``, ``rabia.cycle.settle.*`` and ``rabia.dispatch.*`` /
+``rabia.jit.first_call`` nested inside; ``submit_block`` enters
+``rabia.submit.{validate,route}`` once a call, and the readback workers
+``rabia.fetch.{flags,meta,values}`` on their own threads. With the
 tracer on (``RABIA_TRACE=1``) they aggregate into ``Tracer.report()`` and
-``rabia_span_seconds``; with it off nothing is recorded and nothing the
-lane computes changes. Runs on the virtual CPU mesh.
+``rabia_span_seconds``; with it off and no profiler session listening a
+span is the shared no-op: no annotation is built, nothing is recorded and
+nothing the lane computes changes. Runs on the virtual CPU mesh.
 """
 
 from __future__ import annotations
@@ -31,6 +35,10 @@ PACK_PARTS = tuple(
     f"rabia.cycle.pack.{p}" for p in ("parse", "alloc", "gather")
 )
 CALLS = ("rabia.dispatch.call", "rabia.jit.first_call")
+BOOK_PARTS = tuple(
+    f"rabia.cycle.book.{p}" for p in ("versions", "segment", "handoff")
+)
+SUBMIT_PARTS = ("rabia.submit.validate", "rabia.submit.route")
 PROGRAM = {
     "set": "rabia.devkv.decide_apply",
     "get": "rabia.devkv.lookup_window",
@@ -110,10 +118,20 @@ class TestSpansPerWindow:
             eng.flush()
             assert eng.device_lane_active
             rep = traced.report()
-            for name in ("rabia.cycle.pack", "rabia.cycle.book",
-                         "rabia.cycle.settle", PROGRAM[kind], *PACK_PARTS,
-                         "rabia.dispatch.place"):
+            # a GET window derives no version and retains no segment
+            book_parts = BOOK_PARTS[2:] if kind == "get" else BOOK_PARTS
+            for name in ("rabia.cycle.kinds", "rabia.cycle.pack",
+                         "rabia.cycle.book", *book_parts, "rabia.cycle.settle",
+                         "rabia.cycle.settle.blocks", PROGRAM[kind],
+                         *PACK_PARTS, "rabia.dispatch.place"):
                 assert rep[name]["count"] == N_WINDOWS, (kind, name)
+            assert not (set(BOOK_PARTS) - set(book_parts)) & set(rep), kind
+            for name in SUBMIT_PARTS:  # once a submit_block call
+                assert rep[name]["count"] == N_WINDOWS * WINDOW, (kind, name)
+            # the workers' fetches: flags in every window, meta where it reads
+            assert rep["rabia.fetch.flags"]["count"] == N_WINDOWS, kind
+            fetched_meta = _count(rep, "rabia.fetch.meta")
+            assert fetched_meta == (0 if kind == "set" else N_WINDOWS), kind
             assert _count(rep, *CALLS) == N_WINDOWS, kind
             # a SET window waits for its flags, the others for meta too
             waits = rep["rabia.cycle.wait"]["count"]
@@ -123,6 +141,8 @@ class TestSpansPerWindow:
             assert devkv == [PROGRAM[kind]], kind
             # nested spans lie inside their parents
             assert sum(map(_total, PACK_PARTS)) <= _total("rabia.cycle.pack")
+            assert sum(map(_total, BOOK_PARTS)) <= _total("rabia.cycle.book")
+            assert _total("rabia.cycle.settle.blocks") <= _total("rabia.cycle.settle")
             inside = _total("rabia.dispatch.place") + sum(map(_total, CALLS))
             assert inside <= _total(PROGRAM[kind])
             first_calls += _count(rep, "rabia.jit.first_call")
@@ -143,10 +163,15 @@ class TestSpansPerWindow:
         eng.flush()
         rep = traced.report()
         for name in ("rabia.cycle.pack", "rabia.devkv.read_probe",
-                     "rabia.cycle.book", "rabia.cycle.settle",
-                     "rabia.dispatch.place"):
+                     "rabia.cycle.book", "rabia.cycle.book.handoff",
+                     "rabia.cycle.settle", "rabia.cycle.settle.blocks",
+                     "rabia.dispatch.place", "rabia.fetch.meta"):
             assert rep[name]["count"] == 1, name
         assert rep["rabia.cycle.wait"]["count"] == 1  # meta; no flags
+        # nothing was decided: no flags to fetch, no kind to choose a lane by
+        assert "rabia.fetch.flags" not in rep and "rabia.cycle.kinds" not in rep
+        for name in SUBMIT_PARTS:
+            assert rep[name]["count"] == WINDOW, name
         eng.close()
 
     @pytest.mark.parametrize("kind", ["get", "mixed"])
@@ -175,8 +200,11 @@ class TestSpansPerWindow:
                 _window(eng, kind, np.random.default_rng(5))
         eng.flush()
         text = eng.metrics.render_prometheus()
-        for name in ("rabia.cycle.pack", *PACK_PARTS, "rabia.cycle.book",
+        for name in (*SUBMIT_PARTS, "rabia.cycle.kinds", "rabia.cycle.pack",
+                     *PACK_PARTS, "rabia.cycle.book", *BOOK_PARTS,
                      "rabia.cycle.wait", "rabia.cycle.settle",
+                     "rabia.cycle.settle.blocks", "rabia.fetch.flags",
+                     "rabia.fetch.meta", "rabia.setup.engine",
                      *PROGRAM.values(), "rabia.dispatch.place", *CALLS):
             assert f'rabia_span_seconds_count{{span="{name}"}}' in text, name
         assert "rabia_devkv_upload_bytes_total" in text
@@ -184,37 +212,50 @@ class TestSpansPerWindow:
         eng.close()
 
 
-def test_profiler_events_tile_the_dispatch(tmp_path):
-    """The same spans as TraceMe events in a profiler trace (an annotation's
-    event begins where it is made): inside one ``rabia.devkv.*`` event the
-    place comes first and the call after it, without overlap, and the pack's
-    parts follow one another inside the pack."""
+def _profiled(tmp_path, work) -> list:
+    """Run ``work()`` under a profiler session; ``(name, thread, start, end,
+    stats)`` of every ``rabia.*`` event of its trace, a thread being one
+    line of a host plane (the threads share a name)."""
     import glob
 
     import jax
     from jax.profiler import ProfileData
 
-    eng = _engine()
-    rng = np.random.default_rng(19)
-    _window(eng, "mixed", rng)  # the program's first call, untraced
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        _window(eng, "mixed", np.random.default_rng(19))
-        eng.flush()
+        work()
     finally:
         jax.profiler.stop_trace()
-    eng.close()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    return [
+        (e.name, (plane.name, i), e.start_ns,
+         e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for i, line in enumerate(plane.lines)
+        for e in line.events
+        if e.name.startswith("rabia.")
+    ]
+
+
+def test_profiler_events_tile_the_dispatch(tmp_path):
+    """The same spans as TraceMe events in a profiler trace (an annotation's
+    event begins where it is made): inside one ``rabia.devkv.*`` event the
+    place comes first and the call after it, without overlap, and the pack's
+    parts follow one another inside the pack."""
+    eng = _engine()
+    rng = np.random.default_rng(19)
+    _window(eng, "mixed", rng)  # the program's first call, untraced
+
+    def work():
+        _window(eng, "mixed", np.random.default_rng(19))
+        eng.flush()
+
     events = {}
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("rabia."):
-                    events.setdefault(e.name, []).append(
-                        (e.start_ns, e.start_ns + e.duration_ns)
-                    )
+    for name, _, start, end, _ in _profiled(tmp_path, work):
+        events.setdefault(name, []).append((start, end))
+    eng.close()
     assert all("#" not in name for name in events)  # the stats stay apart
     one = {name: v[0] for name, v in events.items() if len(v) == 1}
     order = ["rabia.cycle.pack", "rabia.devkv.mixed_apply", "rabia.cycle.book"]
@@ -233,6 +274,188 @@ def test_profiler_events_tile_the_dispatch(tmp_path):
     assert len(events["rabia.cycle.settle"]) == 2  # both windows settled
 
 
+def test_profiler_children_lie_inside_parents_and_fetches_on_workers(tmp_path):
+    """The spans of ISSUE 40 as TraceMe events: on the window's thread each
+    child lies inside an event of its parent and the children of one parent
+    follow one another without overlap (the benchmark's ``_segments`` pairs
+    them by name), ``rabia.submit.*`` lie outside every window span, and
+    ``rabia.fetch.flags`` / ``.meta`` lie on a thread other than
+    ``rabia.cycle.book``'s, with the bytes they fetched."""
+    eng = _engine()
+    rng = np.random.default_rng(29)
+    _window(eng, "mixed", rng)  # the program's first call, untraced
+    eng.flush()
+
+    def work():
+        for _ in range(2):
+            _window(eng, "mixed", rng)
+        eng.flush()
+
+    events = _profiled(tmp_path, work)
+    eng.close()
+    by_name: dict = {}
+    for name, thread, a, b, stats in events:
+        by_name.setdefault(name, []).append((thread, a, b, stats))
+    (main,) = {t for t, *_ in by_name["rabia.cycle.book"]}
+    children = {
+        "rabia.cycle.book": BOOK_PARTS,
+        "rabia.cycle.settle": ("rabia.cycle.settle.blocks",),
+    }
+    for parent, parts in children.items():
+        assert len(by_name[parent]) == 2
+        for _, p0, p1, _ in by_name[parent]:
+            inside = sorted(
+                (a, b) for part in parts for t, a, b, _ in by_name[part]
+                if p0 <= a and b <= p1
+            )
+            assert len(inside) == len(parts), parent  # one of each, nested
+            assert all(x[1] <= y[0] for x, y in zip(inside, inside[1:]))
+        for part in parts:
+            assert {t for t, *_ in by_name[part]} == {main}, part
+            assert len(by_name[part]) == 2, part
+    assert [st["blocks"] for *_, st in by_name["rabia.cycle.kinds"]] == [WINDOW] * 2
+    assert [st["blocks"] for *_, st in by_name["rabia.cycle.settle.blocks"]] == [WINDOW] * 2
+    assert [st["fetches"] for *_, st in by_name["rabia.cycle.book.handoff"]] == [2, 2]
+    assert all(st["bytes"] > 0 for *_, st in by_name["rabia.cycle.book.segment"])
+    # the client's call: two spans a call, one after the other, inside no
+    # span of a window
+    windows = [(a, b) for n in ("rabia.cycle.kinds", "rabia.cycle.pack",
+                                "rabia.cycle.book", "rabia.cycle.settle")
+               for _, a, b, _ in by_name[n]]
+    val, route = (sorted(by_name[n], key=lambda e: e[1]) for n in SUBMIT_PARTS)
+    assert len(val) == len(route) == 2 * WINDOW
+    for (tv, a0, a1, sv), (tr, b0, b1, sr) in zip(val, route):
+        assert tv == tr == main and a1 <= b0
+        assert sv == {"n": N_SHARDS} and sr == {"lane": "full"}
+        assert not any(w0 < b1 and a0 < w1 for w0, w1 in windows)
+    # the workers' side of the handoff
+    for name in ("rabia.fetch.flags", "rabia.fetch.meta"):
+        fetches = by_name[name]
+        assert len(fetches) == 2, name
+        assert all(t != main for t, *_ in fetches), name
+        assert all(st["bytes"] > 0 for *_, st in fetches), name
+    # a fetch begins after the handoff that submitted it began
+    first_handoff = min(a for _, a, _, _ in by_name["rabia.cycle.book.handoff"])
+    assert all(a >= first_handoff for _, a, _, _ in by_name["rabia.fetch.flags"])
+
+
+def test_submit_spans_say_which_lane_took_the_block(tmp_path, traced):
+    """``rabia.submit.validate`` and ``rabia.submit.route`` once a call on
+    each of the three lanes (``Tracer.report()`` counts), ``lane=`` on the
+    route's event; a block the validation refuses enters no route."""
+    from rabia_tpu.core.errors import ValidationError
+
+    eng = _engine(device_read_lane=True)
+    rng = np.random.default_rng(37)
+    _window(eng, "set", rng)
+    eng.flush()
+    traced.reset()
+    narrow = build_block([0, 3], [[encode_set_bin("a", "b")]] * 2)
+
+    def work():
+        eng.submit_block(_block("set", rng))  # full width, nothing queued
+        eng.submit_block(_block("get", rng))  # skimmed onto the read lane
+        eng.submit_block(narrow)  # two shards: one queue entry each
+        eng.submit_block(_block("set", rng))  # full width behind a queue
+        with pytest.raises(ValidationError):
+            eng.submit_block(build_block([0, N_SHARDS], [[b"x"]] * 2))
+
+    events = _profiled(tmp_path, work)
+    rep = traced.report()
+    assert rep["rabia.submit.validate"]["count"] == 5
+    assert rep["rabia.submit.route"]["count"] == 4
+    lanes = [st["lane"] for name, _, a, _, st in sorted(events, key=lambda e: e[2])
+             if name == "rabia.submit.route"]
+    assert lanes == ["full", "read", "queue", "queue"]
+    sizes = [st["n"] for name, *_, st in events if name == "rabia.submit.validate"]
+    assert sorted(sizes) == [2, 2, N_SHARDS, N_SHARDS, N_SHARDS]
+    eng.flush()
+    eng.close()
+
+
+def test_native_load_is_a_setup_span_once_a_process(traced, monkeypatch):
+    """``rabia.setup.native`` wraps a library's build and load, which run
+    once a process: a later call returns the cached library, span-free."""
+    from rabia_tpu.native import build
+
+    if build.load_hostkernel() is None:
+        pytest.skip("native host kernel unavailable")
+    monkeypatch.setattr(build, "_HK_CACHED", None)  # as in a new process
+    traced.reset()
+    assert build.load_hostkernel() is not None
+    assert build.load_hostkernel() is not None
+    assert traced.report()["rabia.setup.native"]["count"] == 1
+
+
+class TestDisabledSpanIsOneCheck:
+    """With no profiler session listening and the tracer off
+    ``device_annotation`` returns the shared no-op and builds nothing."""
+
+    @pytest.fixture
+    def counting(self, monkeypatch):
+        """``TraceAnnotation`` with its constructions counted, in place of
+        the class the helper resolved."""
+        from jax.profiler import TraceAnnotation
+
+        from rabia_tpu.core import tracing
+
+        class Counting(TraceAnnotation):
+            made = 0
+
+            def __init__(self, *args, **kwargs):
+                Counting.made += 1
+                super().__init__(*args, **kwargs)
+
+        tracing.device_annotation("resolve")  # the lazy import, before the patch
+        monkeypatch.setattr(tracing, "_annotation_cls", Counting)
+        monkeypatch.setattr(tracing, "_listening", Counting.is_enabled)
+        return Counting
+
+    def test_no_annotation_is_built_while_nothing_listens(self, counting):
+        from rabia_tpu.core import tracing
+
+        assert not tracer.enabled
+        assert tracing.device_annotation("rabia.cycle.pack") is tracing._NOOP
+        assert tracing.device_annotation("rabia.x", bytes=3) is tracing._NOOP
+        with tracing.device_annotation("rabia.x") as span:
+            assert span is None
+        eng = _engine()
+        rng = np.random.default_rng(41)
+        for kind in ("set", "mixed", "get"):
+            _window(eng, kind, rng)
+        eng.flush()
+        eng.sync_to_host()
+        eng.close()
+        assert counting.made == 0
+
+    def test_tracer_alone_records_without_an_annotation(self, counting, traced):
+        from rabia_tpu.core import tracing
+
+        with tracing.device_annotation("rabia.x", bytes=3) as span:
+            assert span is None  # what set_metadata's callers test for
+        assert traced.report()["rabia.x"]["count"] == 1
+        assert counting.made == 0
+
+    def test_a_listening_session_gets_the_annotations(self, counting, tmp_path):
+        eng = _engine()
+        rng = np.random.default_rng(43)
+        _window(eng, "mixed", rng)
+        eng.flush()
+        events = _profiled(tmp_path, lambda: (_window(eng, "mixed", rng), eng.flush()))
+        eng.close()
+        assert counting.made >= len(events) > 0
+        assert {n for n, *_ in events} >= {"rabia.cycle.book.versions",
+                                           "rabia.submit.route", "rabia.fetch.flags"}
+
+    def test_without_is_enabled_every_span_is_built_as_before(self, counting, monkeypatch):
+        from rabia_tpu.core import tracing
+
+        monkeypatch.setattr(tracing, "_listening", None)
+        with tracing.device_annotation("rabia.x", bytes=3) as span:
+            assert isinstance(span, counting)
+        assert counting.made == 1
+
+
 @pytest.mark.parametrize("path", ["native", "numpy"])
 def test_parse_event_and_counter_say_which_path_packed(
     tmp_path, monkeypatch, path
@@ -240,11 +463,6 @@ def test_parse_event_and_counter_say_which_path_packed(
     """``rabia.cycle.pack.parse`` carries ``path=`` in the profiler's trace
     (an argument added once the scan has answered), the name stays bare,
     and ``devkv_pack_windows_total{path=}`` counts the same windows."""
-    import glob
-
-    import jax
-    from jax.profiler import ProfileData
-
     from rabia_tpu.native.build import load_hostkernel
 
     if load_hostkernel() is None:
@@ -256,24 +474,18 @@ def test_parse_event_and_counter_say_which_path_packed(
     eng = _engine()
     rng = np.random.default_rng(23)
     _window(eng, "mixed", rng)  # the program's first call, untraced
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
-    try:
+
+    def work():
         for kind in ("mixed", "set", "mixed"):
             _window(eng, kind, rng)
         eng.flush()
-    finally:
-        jax.profiler.stop_trace()
+
+    events = _profiled(tmp_path, work)
     snap = eng.metrics.snapshot()
     eng.close()
-    (file,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
     said = [
-        dict(e.stats).get("path")
-        for plane in ProfileData.from_file(file).planes
-        for line in plane.lines
-        for e in line.events
-        if e.name == "rabia.cycle.pack.parse"
+        stats.get("path") for name, *_, stats in events
+        if name == "rabia.cycle.pack.parse"
     ]
     assert said == [path] * 3
     other = {"native": "numpy", "numpy": "native"}[path]
@@ -325,7 +537,6 @@ class TestPipeSpans:
         builds = rep["rabia.ladder.build"]["count"]
         assert rep["rabia.jit.first_call"]["count"] == 2 * builds
         assert len(eng._dev._fused_cache) == 2 * builds
-        assert eng._dev.ladder_programs == builds
         marks = {n: rep[n]["count"] for n in rep if n.startswith("rabia.window.")}
         assert marks == {f"rabia.window.w{w}": eng._dev_windows[w] for w in (2, WINDOW)}
         assert marks["rabia.window.w2"] >= 2 and marks[f"rabia.window.w{WINDOW}"] >= 4
@@ -372,7 +583,9 @@ def _content(sm: VectorShardedKV) -> dict:
     return out
 
 
-def _replies_and_state(enabled: bool):
+def _replies_and_state(enabled: bool, profile_dir=None):
+    """One stream's replies, final table and host stores, with the tracer
+    on or off and, given a directory, under a profiler session."""
     was = tracer.enabled
     tracer.reset()
     tracer.enabled = enabled
@@ -380,9 +593,16 @@ def _replies_and_state(enabled: bool):
         eng = _engine()
         rng = np.random.default_rng(21)
         futs = []
-        for kind in ("set", "mixed", "get", "set", "mixed"):
-            futs += _window(eng, kind, rng)
-        eng.flush()
+
+        def work():
+            for kind in ("set", "mixed", "get", "set", "mixed"):
+                futs.extend(_window(eng, kind, rng))
+            eng.flush()
+
+        if profile_dir is None:
+            work()
+        else:
+            assert _profiled(profile_dir, work)
         assert eng.device_lane_active
         replies = [[bytes(g[0]) for g in f.result()] for f in futs]
         report = tracer.report()
@@ -396,14 +616,23 @@ def _replies_and_state(enabled: bool):
         tracer.reset()
 
 
-def test_tracer_off_records_nothing_and_changes_nothing():
+def test_tracer_off_records_nothing_and_changes_nothing(tmp_path):
     replies_on, state_on, stores_on, report_on = _replies_and_state(True)
     replies_off, state_off, stores_off, report_off = _replies_and_state(False)
     assert report_on and "rabia.cycle.pack" in report_on
+    for name in (*SUBMIT_PARTS, "rabia.cycle.kinds", *BOOK_PARTS,
+                 "rabia.cycle.settle.blocks", "rabia.fetch.flags",
+                 "rabia.fetch.meta", "rabia.setup.engine"):
+        assert name in report_on, name
+    assert report_on["rabia.submit.route"]["count"] == 5 * WINDOW
     assert report_off == {}
     assert replies_on == replies_off
     assert state_on == state_off
     assert stores_on == stores_off
+    # and with a profiler session listening (every span an annotation)
+    replies, state, stores, report = _replies_and_state(False, tmp_path)
+    assert report == {}
+    assert (replies, state, stores) == (replies_off, state_off, stores_off)
 
 
 class TestNamedScopes:
