@@ -240,7 +240,9 @@ class MeshBlockFuture:
     __slots__ = ("_results", "_pending")
 
     def __init__(self, k: int) -> None:
-        self._results: list = [None] * k
+        # the per-entry list is built by the first per-entry settle: a
+        # block the bulk lane settles at once never needs one
+        self._results: Optional[list] = None if k else []
         self._pending = k
 
     def _settle(self, i: int, value) -> None:
@@ -253,6 +255,8 @@ class MeshBlockFuture:
                 "ignoring post-bulk settle of entry %d (%r)", i, value
             )
             return
+        if self._results is None:
+            self._results = [None] * self._pending  # nothing settled yet
         if self._results[i] is None:
             self._pending -= 1
         self._results[i] = value
@@ -393,6 +397,12 @@ class MeshEngine:
         # range-compressed decision log for full-width waves:
         # (start_slots i64[n], wave_offset, block, shard->bidx inv)
         self._bulk_log: deque = deque()
+        # the block every deployment sends covers each shard once, in
+        # order: submit_block recognises it by one compare against this
+        # array, which is also the shared (read-only) inv of such a block
+        self._shard_ids = np.arange(self.n_shards, dtype=np.int64)
+        self._shard_ids.flags.writeable = False
+        self._submit_blocks = {"identity": 0, "checked": 0}
         self.next_slot = np.zeros(self.n_shards, np.int64)
         self.alive = np.ones((self.S, self.R), bool)
         # per-shard decision log: slot -> (value, batch or None); bounded
@@ -578,6 +588,18 @@ class MeshEngine:
                     if self._dev is not None
                     else 0
                 ),
+            )
+        for _path in ("identity", "checked"):
+            m.counter(
+                "mesh_submit_blocks_total",
+                "Blocks submit_block was given by the path that validated "
+                "them (the rabia.submit.validate spans): identity = every "
+                "shard once and in order, proven by one compare, routed "
+                "with the engine's one shared inv; checked = any other "
+                "block (partial-width, permuted, refused): the range "
+                "check and a sort",
+                {"path": _path},
+                fn=lambda p=_path: self._submit_blocks[p],
             )
         m.counter(
             "devkv_sync_rows_total",
@@ -771,15 +793,22 @@ class MeshEngine:
         # one check a disabled span costs makes affordable (core/tracing)
         with device_annotation("rabia.submit.validate", n=len(block.shards)):
             shards = np.asarray(block.shards, np.int64)
-            if len(shards) == 0:
-                raise ValidationError("empty block")
-            if int(shards.min()) < 0 or int(shards.max()) >= self.n_shards:
-                raise ValidationError("block shard out of range")
-            if len(np.unique(shards)) != len(shards):
-                # build_block enforces this, but a hand-constructed or
-                # codec-decoded PayloadBlock may not have been through it —
-                # a duplicate shard would corrupt slot accounting
-                raise ValidationError("block shards must be unique")
+            identity = self._is_identity(shards)
+            if identity:
+                # every shard once, in order: in range and unique by
+                # construction, so the checks below could only pass
+                self._submit_blocks["identity"] += 1
+            else:
+                self._submit_blocks["checked"] += 1
+                if len(shards) == 0:
+                    raise ValidationError("empty block")
+                if int(shards.min()) < 0 or int(shards.max()) >= self.n_shards:
+                    raise ValidationError("block shard out of range")
+                if len(np.unique(shards)) != len(shards):
+                    # build_block enforces this, but a hand-constructed or
+                    # codec-decoded PayloadBlock may not have been through
+                    # it — a duplicate shard would corrupt slot accounting
+                    raise ValidationError("block shards must be unique")
         with device_annotation("rabia.submit.route") as span:
             bfut = MeshBlockFuture(len(shards))
             if len(shards) == self.n_shards and self._queued_entries == 0:
@@ -798,9 +827,9 @@ class MeshEngine:
                     # full-width block with nothing queued: the vectorized
                     # lane
                     lane = "full"
-                    inv = np.empty(self.n_shards, np.int64)
-                    inv[shards] = np.arange(len(shards))
-                    self._full_blocks.append((block, bfut, inv))
+                    self._full_blocks.append(
+                        (block, bfut, self._block_inv(shards, identity))
+                    )
                     self._dev_wseq += 1
             else:
                 lane = "queue"
@@ -814,6 +843,27 @@ class MeshEngine:
             if span is not None:
                 span.set_metadata(lane=lane)
         return bfut
+
+    def _is_identity(self, shards: np.ndarray) -> bool:
+        """Whether a block's shards are ``0 .. n_shards - 1`` in order:
+        the two ends first, then one compare."""
+        n = self.n_shards
+        return (
+            len(shards) == n
+            and shards[0] == 0
+            and shards[-1] == n - 1
+            and bool((shards == self._shard_ids).all())
+        )
+
+    def _block_inv(self, shards: np.ndarray, identity: bool) -> np.ndarray:
+        """A full-width block's shard -> entry map: the engine's one
+        read-only identity array for a block in shard order, a fresh
+        permutation for any other."""
+        if identity:
+            return self._shard_ids
+        inv = np.empty(self.n_shards, np.int64)
+        inv[shards] = np.arange(len(shards))
+        return inv
 
     # -- fault injection -----------------------------------------------------
 
@@ -2195,8 +2245,7 @@ class MeshEngine:
         while self._read_pending:
             block, bfut, _barrier = self._read_pending.popleft()
             shards = np.asarray(block.shards, np.int64)
-            inv = np.empty(self.n_shards, np.int64)
-            inv[shards] = np.arange(len(shards))
+            inv = self._block_inv(shards, self._is_identity(shards))
             self._full_blocks.append((block, bfut, inv))
         d = self._dev.dump()  # ONE table materialization for all replicas
         for sm in self.sms:
