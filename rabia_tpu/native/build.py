@@ -263,6 +263,10 @@ def load_hostkernel() -> ctypes.CDLL | None:
             p, p, p,
             p, p, p, p, p,
         ]
+        lib.rk_book_versions.restype = ctypes.c_int64
+        lib.rk_book_versions.argtypes = [
+            ctypes.c_int64, ctypes.c_int64, p, p, p,
+        ]
         lib.rk_stall_scan.restype = ctypes.c_int32
         lib.rk_stall_scan.argtypes = [
             ctypes.c_int32, p, p, ctypes.c_double, ctypes.c_double,

@@ -354,6 +354,30 @@ int32_t rk_pack_gather(
   return 0;
 }
 
+// Device-KV mixed window's SET versions (the engine's book.versions;
+// semantics owner: the numpy lines beside the call in
+// parallel/mesh_engine.py). Over a C-contiguous i8[W, S] kind plane it
+// writes svers[t, s] = base[s] + #{u <= t : kind[u, s] == 1} as one
+// i64[W, S] pass, each row from the row above it, and returns the count
+// of kind == 2 (the window's GETs). The sums wrap as numpy's i64 adds
+// do (unsigned arithmetic: no overflow to be undefined).
+int64_t rk_book_versions(
+    int64_t W, int64_t S, const int8_t* kind, const int64_t* base,
+    int64_t* svers) {
+  int64_t gets = 0;
+  const uint64_t* prev = (const uint64_t*)base;
+  for (int64_t t = 0; t < W; t++) {
+    const int8_t* k = kind + t * S;
+    uint64_t* row = (uint64_t*)(svers + t * S);
+    for (int64_t s = 0; s < S; s++) {
+      row[s] = prev[s] + (uint64_t)(k[s] == 1);
+      gets += k[s] == 2;
+    }
+    prev = row;
+  }
+  return gets;
+}
+
 // Columnar open-candidate scan (engine _open_slots prologue): one pass
 // instead of ~9 numpy dispatches per tick. Fills head[s] =
 // max(next_slot, applied) and cand[s]; returns the candidate count so an

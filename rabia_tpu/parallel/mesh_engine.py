@@ -131,6 +131,31 @@ class _MixedSeg:
         return self.vwin8[t, s, : int(self.vlen[t, s])].tobytes()
 
 
+def _cum_versions(plane: np.ndarray, base: np.ndarray) -> tuple:
+    """A mixed window's derived versions from its i8[W, S] ``plane``:
+    ``(svers, gets, path)`` with ``svers = base + cumsum(plane == 1,
+    axis=0)`` a fresh i64[W, S] (its last row is the window's end row)
+    and ``gets`` the count of ``plane == 2``. One native pass
+    (``rk_book_versions``) where the host kernel builds, ``path``
+    "native"; else the numpy expression, ``path`` "numpy". Both give
+    the same arrays bit for bit (tests/test_book_versions.py)."""
+    from rabia_tpu.native.build import load_hostkernel
+
+    lib = load_hostkernel()
+    if lib is None:
+        set_cum = np.cumsum(plane == 1, axis=0, dtype=np.int64)
+        return base[None, :] + set_cum, int((plane == 2).sum()), "numpy"
+    plane = np.ascontiguousarray(plane, np.int8)
+    base = np.ascontiguousarray(base, np.int64)
+    W, S = plane.shape
+    assert base.shape == (S,), (base.shape, S)
+    svers = np.empty((W, S), np.int64)
+    gets = lib.rk_book_versions(
+        W, S, plane.ctypes.data, base.ctypes.data, svers.ctypes.data
+    )
+    return svers, int(gets), "native"
+
+
 class _SegResolver:
     """Snapshot (shard, version) -> value-bytes resolver handed to
     settled GET views: pins exactly the segments and seed epoch live at
@@ -418,6 +443,9 @@ class MeshEngine:
         self._shard_ids = np.arange(self.n_shards, dtype=np.int64)
         self._shard_ids.flags.writeable = False
         self._submit_blocks = {"identity": 0, "checked": 0}
+        # mixed device windows booked, by the path that derived their
+        # versions (mesh_book_versions_windows_total reads it)
+        self._book_versions = {"native": 0, "numpy": 0}
         self.next_slot = np.zeros(self.n_shards, np.int64)
         self.alive = np.ones((self.S, self.R), bool)
         # the replicas crash_replica took down and heal_replica has not
@@ -633,6 +661,18 @@ class MeshEngine:
                     if self._dev is not None
                     else 0
                 ),
+            )
+        for _path in ("native", "numpy"):
+            m.counter(
+                "mesh_book_versions_windows_total",
+                "Mixed device windows booked by the path that derived "
+                "their SET versions (the path= of the "
+                "rabia.cycle.book.versions spans): native = one C pass "
+                "over the kind plane (rk_book_versions), numpy = the "
+                "numpy cumsum, where the host kernel is unavailable "
+                "(RABIA_PY_HOSTKERNEL=1 included)",
+                {"path": _path},
+                fn=lambda p=_path: self._book_versions[p],
             )
         for _path in ("identity", "checked"):
             m.counter(
@@ -1860,19 +1900,21 @@ class MeshEngine:
             gvlen_h = meta_h[1] >> 1
             gfound_h = (meta_h[1] & 1).astype(bool)
         if rec.get("deferred"):
-            bump = (kind == 1).astype(np.int64)
+            # the bump plane, 1 where a version moves: SET always, DEL
+            # on found; the versions then come from the same pass as a
+            # clean window's
+            bump = kind == 1
             for j, t in enumerate(get_waves):
                 t = int(t)
-                bump[t] += ((kind[t] == 3) & gfound_h[j]).astype(np.int64)
-            cum = np.cumsum(bump, axis=0)
-            svers = self._dev_sver[None, : self.S] + cum
+                bump[t] |= (kind[t] == 3) & gfound_h[j]
+            svers, _, _ = _cum_versions(bump.view(np.int8), self._dev_sver)
             seg = rec["seg"]
             seg.start = self._dev_sver.copy()
-            seg.end = seg.start + cum[-1]
+            seg.end = svers[-1].copy()
             seg.svers = svers
             seg.provisional = False
             self._dev_evict_segments()
-            self._dev_sver[: self.S] += cum[-1]
+            self._dev_sver[:] = seg.end
             self._dev_defer -= 1
         else:
             svers = rec["svers"]
@@ -2140,29 +2182,34 @@ class MeshEngine:
             # untouched; settlement patches range + svers from the exact
             # bump vector (SET always, DEL on found) and advances the
             # mirror then.
-            with device_annotation("rabia.cycle.book.versions"):
-                # GET ops that rode consensus slots inside the mixed window
-                # (kind 2; DEL/EXISTS are not reads for the read-lane
-                # counters)
-                self._read_stats["slot"] += int((kind == 2).sum())
-                is_set = kind == 1  # [count, S]
-                set_cum = np.cumsum(is_set, axis=0, dtype=np.int64)
+            with device_annotation("rabia.cycle.book.versions") as span:
+                # one pass over the kind plane; the GETs it counts are
+                # the ops that rode consensus slots inside the mixed
+                # window (kind 2; DEL/EXISTS are not reads for the
+                # read-lane counters). A deferred window keeps the bare
+                # per-shard SET count (a zero base) as its placeholder
+                vers, gets, path = _cum_versions(
+                    kind,
+                    np.zeros_like(self._dev_sver) if deferred else self._dev_sver,
+                )
+                self._book_versions[path] += 1
+                self._read_stats["slot"] += gets
+                if span is not None:
+                    span.set_metadata(path=path)
                 if deferred:
                     svers = None
                     sver_delta = None
                     seg_start = np.zeros_like(self._dev_sver)
                     seg_end = np.zeros_like(self._dev_sver)
                 else:
-                    svers = self._dev_sver[None, : self.S] + set_cum
+                    svers = vers
                     seg_start = self._dev_sver.copy()
-                    seg_end = seg_start + set_cum[-1]
-                    sver_delta = np.zeros_like(self._dev_sver)
-                    sver_delta[: self.S] = set_cum[-1]
-                    self._dev_sver += sver_delta
+                    seg_end = svers[-1].copy()
+                    sver_delta = seg_end - seg_start
+                    self._dev_sver[:] = seg_end
             with device_annotation("rabia.cycle.book.segment") as span:
                 seg = _MixedSeg(
-                    seg_start, seg_end, ops.vlen, ops.vwin,
-                    set_cum if deferred else svers, kind,
+                    seg_start, seg_end, ops.vlen, ops.vwin, vers, kind
                 )
                 if deferred:
                     seg.provisional = True
