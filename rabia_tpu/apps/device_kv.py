@@ -1322,7 +1322,7 @@ class DeviceKVTable:
         )
         return gval, valw
 
-    def _build_mixed(self, Ku4: int, VWu4: int, Gp: int):
+    def _build_mixed(self, Ku4: int, VWu4: int, Gp: int, net: bool = False):
         """Jitted MIXED window: consensus + per-op kind mask over the
         same table — SET ops mutate (identical update rules to
         :meth:`_build_fused`), GET ops read the wave-entry state (reads
@@ -1341,7 +1341,16 @@ class DeviceKVTable:
         program gathers those waves' lookup outputs ON DEVICE (the host
         knows the wave indices at pack time) and packs found/ver/vlen
         into one two-plane i32 tensor, so the readback is two transfers,
-        not four take-dispatch round trips."""
+        not four take-dispatch round trips.
+
+        ``net`` builds the lossy network's twin (``mixed_net``, for a
+        kernel with ``net_loss`` > 0): the same scan and reads, its
+        consensus :meth:`MeshPhaseKernel.lossy_window` over the per-shard
+        slot counter ``slots`` (device state, chained like the table) in
+        place of ``base``. It returns the counter moved on by the slots
+        consumed, flags i32[4 + H] (``[all decided, overflow,
+        ver_overflow, phase iterations, H bins of slots by phases]``) and
+        the null slots before each op, u8[W, S]."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -1353,18 +1362,8 @@ class DeviceKVTable:
         I8, I32 = jnp.int8, jnp.int32
         col = jnp.arange(S) < n
 
-        def mixed(state, alive, base, depth, kind_w, gidx, ops, *, W,
-                  max_phases):
-            with jax.named_scope("consensus"):
-                wave = jnp.arange(W, dtype=I32)[:, None] < depth
-                present = wave & col[None, :]
-                votes = jnp.where(
-                    present[:, :, None], I8(V1), I8(V0)
-                ) * jnp.ones((1, 1, kernel.R), I8)
-                decided = kernel.slot_window(
-                    votes, alive, base, n_slots=W, max_phases=max_phases
-                )
-                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+        def waves(state, present, kind_w, ops):
+            """The wave scan over the table: one op a shard a wave."""
 
             def wave_step(carry, inp):
                 ok_w, kind_t, klen_t, vlen_t, kwin_t = inp
@@ -1443,14 +1442,12 @@ class DeviceKVTable:
             ) = lax.scan(
                 wave_step, (used0, keyw0, klen0, ver0, vlen0, sver0), xs
             )
-            with jax.named_scope("flags"):
-                flags = jnp.stack(
-                    [
-                        all_v1.astype(I32),
-                        jnp.any(over_w).astype(I32),
-                        jnp.any(sver >= jnp.int32(2**31 - 2)).astype(I32),
-                    ]
-                )
+            return (used, keyw, klen, ver, vlen, sver), (
+                over_w, gfound, gver, gvlen, gslot, wslot), valw0, vwin_full
+
+        def reads(gidx, gfound, gver, gvlen, gslot, wslot, valw0, vwin_full):
+            """The GET-bearing waves' meta and values, gathered on the
+            device, and the value plane the window leaves."""
             # device-side gather of the GET-bearing waves + two-plane
             # meta pack: [0]=version, [1]=(vlen<<1)|found
             with jax.named_scope("get_gather"):
@@ -1463,23 +1460,81 @@ class DeviceKVTable:
                     valw0, jnp.take(gslot, gidx, axis=0), gidx, wslot,
                     vwin_full,
                 )
+            return meta, gval_g, valw
+
+        def mixed(state, alive, base, depth, kind_w, gidx, ops, *, W,
+                  max_phases):
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth
+                present = wave & col[None, :]
+                votes = jnp.where(
+                    present[:, :, None], I8(V1), I8(V0)
+                ) * jnp.ones((1, 1, kernel.R), I8)
+                decided = kernel.slot_window(
+                    votes, alive, base, n_slots=W, max_phases=max_phases
+                )
+                all_v1 = jnp.all(jnp.where(present, decided == V1, True))
+
+            (used, keyw, klen, ver, vlen, sver), (
+                over_w, gfound, gver, gvlen, gslot, wslot,
+            ), valw0, vwin_full = waves(state, present, kind_w, ops)
+            with jax.named_scope("flags"):
+                flags = jnp.stack(
+                    [
+                        all_v1.astype(I32),
+                        jnp.any(over_w).astype(I32),
+                        jnp.any(sver >= jnp.int32(2**31 - 2)).astype(I32),
+                    ]
+                )
+            meta, gval_g, valw = reads(
+                gidx, gfound, gver, gvlen, gslot, wslot, valw0, vwin_full
+            )
             new_state = (used, keyw, klen, ver, valw, vlen, sver)
             return new_state, flags, meta, gval_g
 
+        def mixed_net(state, alive, slots, depth, kind_w, gidx, ops, *, W):
+            # the lossy network's consensus: every shard's ops take the
+            # slots weak-MVC decides 1, the null slots between them are
+            # counted, and the slot counter moves on by what was consumed
+            with jax.named_scope("consensus"):
+                wave = jnp.arange(W, dtype=I32)[:, None] < depth
+                present = wave & col[None, :]
+                need = jnp.where(col, depth, 0)
+                nulls, used_slots, ok, iters, hist = kernel.lossy_window(
+                    alive, slots, need, W=W
+                )
+            (used, keyw, klen, ver, vlen, sver), (
+                over_w, gfound, gver, gvlen, gslot, wslot,
+            ), valw0, vwin_full = waves(state, present, kind_w, ops)
+            with jax.named_scope("flags"):
+                flags = jnp.concatenate(
+                    [
+                        jnp.stack(
+                            [
+                                ok.astype(I32),
+                                jnp.any(over_w).astype(I32),
+                                jnp.any(sver >= jnp.int32(2**31 - 2)).astype(I32),
+                                iters,
+                            ]
+                        ),
+                        hist,
+                    ]
+                )
+            meta, gval_g, valw = reads(
+                gidx, gfound, gver, gvlen, gslot, wslot, valw0, vwin_full
+            )
+            new_state = (used, keyw, klen, ver, valw, vlen, sver)
+            return new_state, slots + used_slots, flags, nulls, meta, gval_g
+
+        if net:
+            return jax.jit(mixed_net, static_argnames=("W",))
         return jax.jit(mixed, static_argnames=("W", "max_phases"))
 
-    def mixed_apply(self, alive, base, depth: int, kind: np.ndarray,
-                    get_waves: np.ndarray, ops: DeviceWindowOps, W: int,
-                    max_phases: int = 4, state=None):
-        """Dispatch one mixed decide+apply+lookup window. Returns device
-        handles ``(new_state, flags, meta, gval)`` where ``meta`` is
-        i32[2, Gp, S] ([0]=version, [1]=(vlen<<1)|found) and ``gval``
-        u32[Gp, S, VW4], both gathered to the ``get_waves`` rows (padded
-        to a power of two; the caller maps real rows). The caller reads
-        the 12-byte flags first and fetches meta/gval only on a clean
-        window. ``state`` overrides the table state to run against (the
-        pipelined lane chains on the previous in-flight window's
-        unresolved output, same as :meth:`decide_apply`)."""
+    def _mixed_operands(self, ops, kind, get_waves, W: int) -> tuple:
+        """A mixed window's host operands at the static size ``W``: the op
+        and kind planes padded with empty waves, and the GET-bearing
+        waves' indices padded to ``Gp``. Returns ``(ops, kind, gidx,
+        Gp)``."""
         if ops.klen.shape[0] < W:
             pad = W - ops.klen.shape[0]
             ops = DeviceWindowOps(
@@ -1506,6 +1561,21 @@ class DeviceKVTable:
                 Gp <<= 1
         gidx = np.zeros(Gp, np.int32)
         gidx[: len(get_waves)] = get_waves
+        return ops, kind, gidx, Gp
+
+    def mixed_apply(self, alive, base, depth: int, kind: np.ndarray,
+                    get_waves: np.ndarray, ops: DeviceWindowOps, W: int,
+                    max_phases: int = 4, state=None):
+        """Dispatch one mixed decide+apply+lookup window. Returns device
+        handles ``(new_state, flags, meta, gval)`` where ``meta`` is
+        i32[2, Gp, S] ([0]=version, [1]=(vlen<<1)|found) and ``gval``
+        u32[Gp, S, VW4], both gathered to the ``get_waves`` rows (padded
+        to a power of two; the caller maps real rows). The caller reads
+        the 12-byte flags first and fetches meta/gval only on a clean
+        window. ``state`` overrides the table state to run against (the
+        pipelined lane chains on the previous in-flight window's
+        unresolved output, same as :meth:`decide_apply`)."""
+        ops, kind, gidx, Gp = self._mixed_operands(ops, kind, get_waves, W)
         key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
         fn = self._program(
             key, W, lambda: self._build_mixed(key[2], key[3], Gp),
@@ -1531,6 +1601,41 @@ class DeviceKVTable:
                 ops_d,
                 W=W,
                 max_phases=max_phases,
+            )
+
+    def mixed_apply_net(self, alive, slots, depth: int, kind: np.ndarray,
+                        get_waves: np.ndarray, ops: DeviceWindowOps, W: int,
+                        state=None):
+        """:meth:`mixed_apply` under the lossy network (a kernel with
+        ``net_loss`` > 0): the same window, its consensus
+        ``lossy_window`` over ``slots``, the per-shard slot counter as the
+        device holds it (the settled one, or the in-flight window's
+        output it chains on, like ``state``). Returns device handles
+        ``(new_state, new_slots, flags, nulls, meta, gval)`` (see
+        :meth:`_build_mixed`)."""
+        ops, kind, gidx, Gp = self._mixed_operands(ops, kind, get_waves, W)
+        key = ("mixnet", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
+        fn = self._program(
+            key, W, lambda: self._build_mixed(key[2], key[3], Gp, net=True),
+            lambda w: self.mixed_apply_net(
+                alive, slots, 0, kind[:0], get_waves[:0],
+                DeviceWindowOps(*(a[:0] for a in ops)), W=w, state=state,
+            ),
+        )
+        with self._placing(alive, kind, *ops):
+            alive_d = self.kernel.place(alive)
+            kind_d = self._put_waves(kind)
+            ops_d = self._place_ops(ops)
+        with self._calling(key):
+            return fn(
+                self.state if state is None else state,
+                alive_d,
+                slots,
+                np.int32(depth),
+                kind_d,
+                gidx,
+                ops_d,
+                W=W,
             )
 
     def _build_bank(self, Ku4: int, Tu4: int):

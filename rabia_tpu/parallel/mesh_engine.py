@@ -27,6 +27,20 @@ applies, future settles) or V0 (null slot — the batch retries in the next
 window). An undecided slot (quorum of replicas crashed) parks the shard;
 the whole window re-runs deterministically after heal.
 
+Lossy network (``net_loss`` > 0)
+--------------------------------
+The lockstep model's votes never split. Under ``net_loss`` the device
+lane decides every window as upstream's lossy network would have it
+(:meth:`MeshPhaseKernel.lossy_window`): every replica proposes (a replica
+the request has not reached proposes none), each exchange is tallied over
+a view of n − f senders drawn from the seed, and the phases run until
+every alive replica has decided, the common coin included. A null slot is
+a clean outcome: the op takes the shard's next slot inside the same
+window. The per-shard slot counter is then device state chained like the
+table, and the host learns each window's slots at its settle
+(``_dev_book_slots``). Such an engine never demotes: the host lanes
+decide with full views, and no run mixes two network models.
+
 Multi-host (DCN)
 ----------------
 Pass a mesh spanning every process's devices (built after
@@ -68,7 +82,7 @@ from rabia_tpu.core.types import (
     ShardId,
     quorum_size,
 )
-from rabia_tpu.parallel.mesh import MeshPhaseKernel, make_mesh
+from rabia_tpu.parallel.mesh import NET_MAX_PHASES, MeshPhaseKernel, make_mesh
 
 __all__ = ["MeshBlockFuture", "MeshEngine", "MeshFuture"]
 
@@ -377,6 +391,14 @@ class MeshEngine:
         per-window wall time walks ``window`` along a power-of-two
         ladder within [min_window, max_window] to keep the p99 window
         latency under the target (see :meth:`run_cycle`).
+    net_loss:
+        The share of messages the network loses, in [0, 1). Above 0 the
+        device lane decides every window under the lossy network
+        (:meth:`MeshPhaseKernel.lossy_window`: every replica proposes,
+        views of n − f senders, the common coin, null slots decided on
+        the chip), which needs ``device_store``; the lane then never
+        demotes (a window it cannot run raises), since the host lanes
+        decide with full views and no run may mix two network models.
 
     State machines implementing
     :class:`~rabia_tpu.core.state_machine.VectorStateMachine` get the
@@ -398,6 +420,7 @@ class MeshEngine:
         max_phases: int = 4,
         coin_p1: float = 0.5,
         seed: int = 0,
+        net_loss: float = 0.0,
         max_decision_history: int = 4096,
         device_store: bool = False,
         device_store_kw: Optional[dict] = None,
@@ -410,6 +433,14 @@ class MeshEngine:
     ) -> None:
         if n_shards < 1 or n_replicas < 1:
             raise ValidationError("need at least 1 shard and 1 replica")
+        if not 0.0 <= float(net_loss) < 1.0:
+            raise ValidationError(f"net_loss {net_loss!r} is not in [0, 1)")
+        self.net_loss = float(net_loss)
+        if self.net_loss and (not device_store or device_read_lane):
+            raise ValidationError(
+                "net_loss runs on the device lane alone: device_store=True "
+                "and no device_read_lane"
+            )
         self.mesh = mesh if mesh is not None else make_mesh()
         axis = self.mesh.shape["shard"]
         self.n_shards = int(n_shards)
@@ -418,7 +449,8 @@ class MeshEngine:
         self.window = int(window)
         self.max_phases = int(max_phases)
         self.kernel = MeshPhaseKernel(
-            self.S, self.R, self.mesh, coin_p1=coin_p1, seed=seed
+            self.S, self.R, self.mesh, coin_p1=coin_p1, seed=seed,
+            net_loss=self.net_loss,
         )
         import jax
 
@@ -435,7 +467,9 @@ class MeshEngine:
         # while NO per-shard entries are pending, else demoted in order
         self._full_blocks: deque = deque()
         # range-compressed decision log for full-width waves:
-        # (start_slots i64[n], wave_offset, block, shard->bidx inv)
+        # (start_slots i64[n], wave_offset, block, shard->bidx inv), and
+        # under a lossy network a fifth item: the null slots before each
+        # shard's op (u8[n]), whose slot is then start_slots itself
         self._bulk_log: deque = deque()
         # the block every deployment sends covers each shard once, in
         # order: submit_block recognises it by one compare against this
@@ -736,23 +770,46 @@ class MeshEngine:
             buckets=tuple(float(1 << i) for i in range(11)),
         )
         # randomized-termination evidence (chaos/runner.collect_evidence
-        # reads this family from every engine): the colocated lockstep
-        # mesh decides every counted slot unanimously in its first
-        # phase — a theorem of the model, not a measurement, so the
-        # curve is a spike at 1 sourced from the decision counter
+        # reads this family from every engine). Two sources. In the
+        # colocated lockstep model (net_loss 0) every live replica sees
+        # every vote, so every decided slot is unanimous in its first
+        # phase: a theorem of the model, not a measurement, and the curve
+        # is a spike at 1 sourced from the decision counter. Under a
+        # lossy network the device counts each consumed slot by the
+        # phases it took (the window program's histogram, summed here at
+        # each settle)
         _phase_bounds = tuple(float(b) for b in range(1, 33))
+        self._net_phases = np.zeros(NET_MAX_PHASES, np.int64)
 
         def _mesh_phase_curve():
+            if self.net_loss:
+                h = self._net_phases
+                n = int(h.sum())
+                at = np.arange(1, len(h) + 1)
+                return h[:32].tolist(), n, float((h * at).sum())
             d = int(self.decided_v1)
             return [d] + [0] * 31, d, float(d)
 
         m.histogram(
             "phases_to_decide",
-            "Weak-MVC phases per decided slot (colocated lockstep: "
-            "every decided slot is unanimous, phase 1 by construction)",
+            "Weak-MVC phases per decided slot (net_loss 0: every decided "
+            "slot is unanimous, phase 1 by construction; under a lossy "
+            "network: the device's count of each consumed slot's phases)",
             buckets=_phase_bounds,
             fn=_mesh_phase_curve,
         )
+        for _outcome, _fn in (
+            ("decided", lambda: self.decided_v1),
+            ("null", lambda: self.decided_v0),
+        ):
+            m.counter(
+                "mesh_slots_total",
+                "Slots decided, by outcome: decided = an op applied at the "
+                "slot, null = decided 0, its op retried at the shard's "
+                "next slot",
+                {"outcome": _outcome},
+                fn=_fn,
+            )
         if device_store:
             from rabia_tpu.apps.device_kv import DeviceKVTable
 
@@ -781,6 +838,15 @@ class MeshEngine:
                     **(device_store_kw or {}),
                 )
             self._dev_active = True
+            # under a lossy network the per-shard slot counter is device
+            # state: each window's program moves it on by the slots its
+            # ops consumed, chained like the table, and the settle adopts
+            # it with the table (next_slot follows at each settle)
+            self._dev_slots = (
+                self._dev._put_shards(np.zeros(self.S, np.int32))
+                if self.net_loss
+                else None
+            )
             # host mirror of the device per-shard version counters:
             # response versions derive from it (no per-op readback)
             self._dev_sver = np.zeros(self.S, np.int64)
@@ -1297,6 +1363,11 @@ class MeshEngine:
         return served + self._run_cycle_body()
 
     def _run_cycle_body(self) -> int:
+        if self.net_loss and not self._dev_active and self._has_pending():
+            raise RabiaError(
+                "net_loss runs on the device lane alone; the host lanes "
+                "decide with full views"
+            )
         if (
             self._dev_active
             and self._dev_pipe
@@ -1428,6 +1499,11 @@ class MeshEngine:
                     break
                 depth += 1
         if head_kind == _BANK:
+            if self.net_loss:
+                raise RabiaError(
+                    "banking windows have no program under net_loss; the "
+                    "lane cannot demote"
+                )
             return self._run_cycle_fullwidth_device_bank(depth)
         # mixed and GET windows PIPELINE like SET windows: they dispatch
         # chained on the newest in-flight window's output state and join
@@ -1438,6 +1514,7 @@ class MeshEngine:
             head_kind is None
             or depth < len(kinds)
             or head_kind in (3, 4)  # DEL/EXISTS runs ride the mixed program
+            or self.net_loss  # the lossy network's one program
         ):
             return self._run_cycle_fullwidth_device_mixed(count)
         if head_kind == 2:
@@ -1526,25 +1603,71 @@ class MeshEngine:
             }
         return self._dev_push_window(rec)
 
-    def _dev_commit_window(self, entries, depth: int):
+    def _dev_commit_window(self, entries, depth: int, slots: bool = True):
         """Shared commit bookkeeping for every device window kind: pop
         the consumed blocks, advance the slot counters, append to the
         bulk decision log (trimmed to the retention budget). Returns
-        the per-shard start slots (for the log records)."""
+        the per-shard start slots (for the log records). ``slots``
+        False (the lossy network, whose slots only the settle learns:
+        :meth:`_dev_book_slots`) pops the blocks and nothing else."""
         n = self.n_shards
         for _ in range(depth):
             self._full_blocks.popleft()
         self._dev_wdisp += depth  # read-lane write barrier advances
+        if not slots:
+            return None
         start = self.next_slot.copy()
         self.next_slot[:n] += depth
         self.decided_v1 += depth * n
         for t, (block, bfut, inv) in enumerate(entries):
             self._bulk_log.append((start, t, block, inv))
+        self._dev_trim_log()
+        return start
+
+    def _dev_trim_log(self) -> None:
         while len(self._bulk_log) > max(
             1, self.max_decision_history // max(1, self.window)
         ):
             self._bulk_log.popleft()
-        return start
+
+    def _dev_chain_slots(self):
+        """The slot counter a new lossy-network window dispatches against:
+        the newest in-flight window's output, else the settled counter."""
+        return self._dev_pipe[-1]["slots"] if self._dev_pipe else self._dev_slots
+
+    def _dev_book_slots(self, rec, flags, nulls) -> None:
+        """A settled lossy-network window's outcome on the host: the slot
+        of each op (the shard's next slot, plus the op's wave, plus every
+        null slot up to it), ``next_slot`` and the decision counters, the
+        decision log (one entry a wave: its ops' slots and the null slots
+        before each, expanded by :meth:`decisions_for`), the phase
+        histogram, and the marker ``rabia.consensus.p<P>`` of the phases
+        the window's loops ran."""
+        n, depth = rec["n"], rec["depth"]
+        iters = int(flags[3])
+        with device_annotation(f"rabia.consensus.p{iters}"):
+            pass
+        with device_annotation("rabia.cycle.book.slots") as span:
+            nulls = nulls[:depth, :n]
+            # each wave's slots from the last: a row at a time, since
+            # numpy's buffered cumsum down axis 0 costs ~20 ns an element
+            at = np.empty((depth, n), np.int64)
+            row = self.next_slot - 1
+            for t in range(depth):
+                row = row + nulls[t]
+                row += 1
+                at[t] = row
+            null_slots = int(nulls.sum(dtype=np.int64))
+            for t, (block, _bfut, inv) in enumerate(rec["entries"]):
+                self._bulk_log.append((at[t], 0, block, inv, nulls[t]))
+            self._dev_trim_log()
+            if depth:
+                self.next_slot[:] = at[-1] + 1
+            self.decided_v1 += depth * n
+            self.decided_v0 += null_slots
+            self._net_phases += flags[4:]
+            if span is not None:
+                span.set_metadata(nulls=null_slots)
 
     def _dev_chain_base(self):
         """Table state a new device window dispatches against: the
@@ -1618,7 +1741,9 @@ class MeshEngine:
             flags_fut = meta_fut = None
             if flags is not None:
                 flags_fut = pool.submit(
-                    _fetch_readback, "rabia.fetch.flags", flags
+                    _fetch_readback,
+                    "rabia.fetch.flags",
+                    *(flags if isinstance(flags, tuple) else (flags,)),
                 )
             if meta:
                 meta_fut = pool.submit(
@@ -1658,6 +1783,8 @@ class MeshEngine:
             if rec["kind"] == "get":
                 dirty = not int(flags)  # lookup returns the all_v1 scalar
             else:
+                if rec.get("slots") is not None:
+                    flags, nulls = flags  # the lossy network's null slots
                 dirty = not flags[0] or flags[1] or flags[2]
         if dirty:
             # roll back EVERY optimistic window, newest first — the
@@ -1682,20 +1809,22 @@ class MeshEngine:
                     for e in reversed(r["entries"]):
                         self._read_pending.appendleft(e)
                     continue
-                for _ in range(d):
+                lossy = r.get("slots") is not None  # booked at settle only
+                for _ in range(0 if lossy else d):
                     if self._bulk_log:
                         self._bulk_log.pop()
                 for e in reversed(r["entries"]):
                     self._full_blocks.appendleft(e)
                 self._dev_wdisp -= d
-                self.next_slot[:rn] -= d
+                if not lossy:
+                    self.next_slot[:rn] -= d
+                    self.decided_v1 -= d * rn
                 if r["sver_delta"] is not None:
                     self._dev_sver -= r["sver_delta"]
                 if r.get("deferred"):
                     # deferred windows never advanced the mirror — the
                     # pending count is the only bookkeeping to unwind
                     self._dev_defer -= 1
-                self.decided_v1 -= d * rn
                 if (
                     r["seg"] is not None
                     and self._dev_vseg
@@ -1749,6 +1878,9 @@ class MeshEngine:
             # state they chained on, so adopting is a no-op by value and
             # keeps the pipe invariant uniform
             self._dev.adopt(rec["new_state"])
+            if rec.get("slots") is not None:
+                self._dev_slots = rec["slots"]  # adopted with the table
+                self._dev_book_slots(rec, flags, nulls)
             if rec["kind"] == "set":
                 self._dev_settle_set(rec)
             elif rec["kind"] == "mixed":
@@ -2162,14 +2294,26 @@ class MeshEngine:
         # the meta plane, it bumps nothing and forces no deferral.
         deferred = bool((kind == 3).any()) or self._dev_defer > 0
         get_waves = np.nonzero((kind >= 2).any(axis=1))[0].astype(np.int32)
-        base = np.zeros(self.S, np.int32)
-        base[:n] = self.next_slot
         state_base = self._dev_chain_base()
+        new_slots = nulls_dev = None
         with device_annotation("rabia.devkv.mixed_apply"):
-            new_state, flags_dev, meta_dev, gval_dev = self._dev.mixed_apply(
-                self.alive, base, count, kind, get_waves, ops, W=W,
-                max_phases=self.max_phases, state=state_base,
-            )
+            if self.net_loss:
+                # the slot counter chains on the device like the table:
+                # the host learns each window's slots at its settle
+                (
+                    new_state, new_slots, flags_dev, nulls_dev, meta_dev,
+                    gval_dev,
+                ) = self._dev.mixed_apply_net(
+                    self.alive, self._dev_chain_slots(), count, kind,
+                    get_waves, ops, W=W, state=state_base,
+                )
+            else:
+                base = np.zeros(self.S, np.int32)
+                base[:n] = self.next_slot
+                new_state, flags_dev, meta_dev, gval_dev = self._dev.mixed_apply(
+                    self.alive, base, count, kind, get_waves, ops, W=W,
+                    max_phases=self.max_phases, state=state_base,
+                )
         with device_annotation("rabia.cycle.book"):
             self._lat_invalidate |= (
                 self._dev.compiled_on_last_call and self._lat_timing
@@ -2217,15 +2361,18 @@ class MeshEngine:
                 self._dev_push_segment(seg)
                 if span is not None:
                     span.set_metadata(bytes=seg.nbytes)
-            self._dev_commit_window(entries, count)
+            self._dev_commit_window(entries, count, slots=not self.net_loss)
             val_dev = (gval_dev,) if len(get_waves) else None
             # meta fetched optimistically alongside the flags (a dirty
             # window wastes one small transfer — the rollback edge); the
             # value plane stays on the device unless the last settle had
             # to download one (then a worker fetches it from here on) or
-            # this one's settle has to
+            # this one's settle has to. Under a lossy network the null
+            # slots ride with the flags
             flags_fut, meta_fut, val_fut = self._dev_hand_off(
-                flags_dev, (meta_dev,) if len(get_waves) else (), val_dev
+                flags_dev if nulls_dev is None else (flags_dev, nulls_dev),
+                (meta_dev,) if len(get_waves) else (),
+                val_dev,
             )
             rec = {
                 "kind": "mixed",
@@ -2243,6 +2390,7 @@ class MeshEngine:
                 "seg": seg,
                 "sver_delta": sver_delta,
                 "deferred": deferred,
+                "slots": new_slots,
             }
         return self._dev_push_window(rec)
 
@@ -2416,8 +2564,21 @@ class MeshEngine:
         once and fanned into the host stores, and the engine re-promotes
         automatically after ``device_store_repromote`` clean full-width
         cycles. Host-lane (or non-device) engines are already in sync —
-        a no-op."""
+        a no-op. Under ``net_loss`` the lane stays on (it cannot demote):
+        the host stores receive a copy of the settled table, as a
+        checkpoint's do."""
+        if self.net_loss and self._dev_active:
+            self._dev_drain_pipe()
+            self._dev_copy_to_host()
+            return
         self._demote_device_store()
+
+    def _dev_copy_to_host(self) -> None:
+        """The device table, as it stands, into every replica's host store
+        (one dump for all of them); the lane stays on."""
+        d = self._dev.dump()
+        for sm in self.sms:
+            self._dev.sync_into(sm, dump=d)
 
     def _demote_device_store(self) -> None:
         """Leave device-store mode: the device table becomes the host
@@ -2425,6 +2586,13 @@ class MeshEngine:
         the host replicas saw none of the applies)."""
         if not self._dev_active:
             return
+        if self.net_loss:
+            raise RabiaError(
+                f"the device lane cannot demote under net_loss "
+                f"{self.net_loss}: the host lanes decide with full views "
+                "(a window outside the lane's envelope, a dirty window or "
+                "per-shard submissions)"
+            )
         if self._dev_pipe:
             # the sync-down below must see the SETTLED table: resolve
             # every in-flight window first (a dirty one rolls the pipe
@@ -2915,9 +3083,7 @@ class MeshEngine:
             # the device table is authoritative in device mode: reflect
             # it into the host replicas so the snapshot below sees it
             # (device mode stays active; the host copies are snapshots)
-            d = self._dev.dump()
-            for sm in self.sms:
-                self._dev.sync_into(sm, dump=d)
+            self._dev_copy_to_host()
 
         return PersistedEngineState(
             current_phase=int(self.next_slot.max(initial=0)),
@@ -2975,13 +3141,15 @@ class MeshEngine:
         """Committed decision log: slot -> (value, batch). ``batch`` is
         None only for V0 null slots; block-lane commits materialize their
         batch from the (log-retained) source block on access. Full-width
-        waves live range-compressed in ``_bulk_log`` and expand here."""
+        waves live range-compressed in ``_bulk_log`` and expand here (a
+        lossy-network wave with the null slots before each op's slot)."""
         out: dict[int, tuple[int, Optional[CommandBatch]]] = {}
-        for start, t, block, inv in self._bulk_log:
-            out[int(start[shard]) + t] = (
-                V1,
-                block.materialize_batch(int(inv[shard])),
-            )
+        for start, t, block, inv, *nulls in self._bulk_log:
+            slot = int(start[shard]) + t
+            out[slot] = (V1, block.materialize_batch(int(inv[shard])))
+            # a lossy-network wave: the null slots just before its op's
+            for k in range(int(nulls[0][shard]) if nulls else 0):
+                out[slot - 1 - k] = (V0, None)
         for slot, (v, b) in self.decisions[shard].items():
             if isinstance(b, tuple):
                 b = b[0].materialize_batch(b[1])

@@ -11,8 +11,40 @@ import json
 
 from chipbench import run, spec
 from chipbench.tests.test_bank_family import *  # noqa: F401,F403
-from chipbench.tests.test_bank_family import CELL, CONFIG, TINY, TRAFFIC
+from chipbench.tests.test_bank_family import CELL, CONFIG, TINY, TRAFFIC, _json
 from tests.test_chipbench_cells import _window_thread_spans
+
+
+def test_configuration_and_entries():
+    """``chipbench/tests/test_bank_family.py``'s test of the same name, with
+    the place of the bank cell as it stands since a later cell was appended
+    after it: right after ``kv-r3-s64.ycsb-b-sat``, and not necessarily
+    last."""
+    config = _json(CONFIG)
+    bench = spec.load_benchmark()
+    entry = {c["name"]: c for c in bench["configs"]}["bank-r7-s1024"]
+    assert entry["file"] == str(CONFIG.relative_to(spec.REPO_ROOT))
+    assert entry["source"] == config["source"] and len(entry["source"]) <= 200
+    assert entry["reduced"] == list(config["reduced"]) == ["replica_processes", "transport"]
+    assert "no state transfer" in config["reduced"]["replica_processes"]
+    assert (config["n_shards"], config["n_replicas"], config["window"]) == (1024, 7, 64)
+    assert config["per_shard_capacity"] * config["n_shards"] == config[
+        "records_at_capacity"] == 1_048_576
+    assert (config["key_bytes"], config["value_bytes"]) == (32, 8)
+    assert config["family"] == "bank" and config["reference"] == "bank_plain"
+    assert {"initial_balances", "smallbank_mapping"} <= set(config["assumed"])
+    cells = {w["name"]: w for w in bench["workloads"]}
+    assert cells[CELL]["chips"] == cells["kv-r3-s64.ycsb-b-sat"]["chips"] == 1
+    names = [w["name"] for w in bench["workloads"]]
+    at = names.index(CELL)
+    assert names[at - 1 : at + 1] == ["kv-r3-s64.ycsb-b-sat", CELL] and at >= 7
+    cell = spec.load_cell(CELL)
+    assert cell.family.__name__ == "chipbench_family_bank"
+    assert cell.faults == ((0.25, "crash", (0, 1, 2)), (0.75, "heal", (0, 1, 2)))
+    layer = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("bank_hbm_share", "bank_settle_ms_per_window", "degraded_cycle_ms"):
+        assert layer[name]["workloads"] == [CELL]
+        assert name in cell.readers
 
 
 def test_rehearsal_of_the_bank_cell_reads_its_spans(tmp_path):
