@@ -266,8 +266,9 @@ class GetFrameGroups(_ShardFrameGroups):
         self.found = found  # bool[S]
         self.ver = ver  # i32[S]
         self.vlen = vlen  # i32[S]
-        # contiguous: a fetched device array slice can come back with a
-        # non-contiguous layout, which .view(uint8) rejects
+        # the engine's rows are views of a whole row-major plane and copy
+        # nothing here; the guard stays for a strided row (a plane that
+        # came back in another layout), which .view(uint8) rejects
         self.valb = np.ascontiguousarray(val_words).view(np.uint8)  # u8[S, VW]
 
     def _frame(self, s: int) -> bytes:
@@ -469,6 +470,7 @@ class DeviceKVTable:
         wave_sharding = NamedSharding(kernel.mesh, P(None, SHARD_AXIS))
         put = self._put_shards = lambda a: jax.device_put(a, shard_sharding)
         self._put_waves = lambda a: jax.device_put(a, wave_sharding)
+        self._wave_sharding = wave_sharding
         planes = _state_planes(S, Pc, self.K4, self.VW4)
         self.state = tuple(put(jnp.zeros(sh, dt)) for sh, dt in planes)
         # what the table holds on the device, every chip's share together
@@ -917,6 +919,21 @@ class DeviceKVTable:
             return device_annotation("rabia.jit.first_call", sig=str(key))
         return device_annotation("rabia.dispatch.call")
 
+    def _host_rows(self, rows):
+        """A value plane ``u32[G, S, VW4]`` as a window program hands it to
+        the host: ``u32[G, S·VW4]``, split over the shard axis like a wave
+        plane, so a chip's piece is whole rows. Left 3-D, a chip lays it
+        out with the shard on the lanes (16 words are under a lane row)
+        and the host's copy comes back strided; flat, the default layout
+        is the host's row order (``S·VW4`` is a multiple of 128 at every S
+        the cells run), and the engine views it as ``[G, S, VW4]`` again
+        without a copy (``mesh_engine._host_values``)."""
+        import jax
+
+        return jax.lax.with_sharding_constraint(
+            rows.reshape(rows.shape[0], -1), self._wave_sharding
+        )
+
     def _build_lookup(self, Ku4: int):
         """Jitted GET window: consensus slot window + a read-only match
         over the table (no state mutation, no version advance)."""
@@ -930,6 +947,7 @@ class DeviceKVTable:
         n = self.n_shards
         I8, I32 = jnp.int8, jnp.int32
         col = jnp.arange(self.S) < n
+        host_rows = self._host_rows
 
         def lookup(state, alive, base, depth, klen_t, kwin_t, *, W,
                    max_phases):
@@ -966,7 +984,7 @@ class DeviceKVTable:
             _, (found, rver, rvlen, rval) = lax.scan(
                 wave_match, None, (klen_t, kwin_full)
             )
-            return all_v1.astype(I32), found, rver, rvlen, rval
+            return all_v1.astype(I32), found, rver, rvlen, host_rows(rval)
 
         return jax.jit(lookup, static_argnames=("W", "max_phases"))
 
@@ -977,7 +995,8 @@ class DeviceKVTable:
         can chain on an in-flight window's output). ``ops`` is
         :meth:`pack_get_window`'s ``(klen i16[W,S], kwin u32[W,S,Ku4])``
         pair. Returns DEVICE handles
-        ``(all_v1, found[W,S], ver[W,S], vlen[W,S], val_words[W,S,VW4])``
+        ``(all_v1, found[W,S], ver[W,S], vlen[W,S], val_words[W,S·VW4])``
+        (the words flat: :meth:`_host_rows`)
         — the caller fetches selectively: found+ver are ~5 bytes/op;
         the value planes (~70 bytes/op) only need to be downloaded
         when a version cannot be resolved from the host-side value
@@ -1032,6 +1051,7 @@ class DeviceKVTable:
         from jax import lax
 
         K4 = self.K4
+        host_rows = self._host_rows
 
         def lookup_only(state, klen_t, kwin_t, *, W):
             used, keyw, klen, ver, valw, vlen, _sver = state
@@ -1057,7 +1077,7 @@ class DeviceKVTable:
             _, (found, rver, rvlen, rval) = lax.scan(
                 wave_match, None, (klen_t, kwin_full)
             )
-            return found, rver, rvlen, rval
+            return found, rver, rvlen, host_rows(rval)
 
         return jax.jit(lookup_only, static_argnames=("W",))
 
@@ -1066,7 +1086,7 @@ class DeviceKVTable:
         lane's probe serve): ``ops`` exactly as :meth:`lookup_window`
         takes them, padded to the static window size ``W``
         (padding waves carry klen 0 and match nothing). Returns DEVICE
-        handles ``(found[W,S], ver[W,S], vlen[W,S], val_words)`` — no
+        handles ``(found[W,S], ver[W,S], vlen[W,S], val_words[W,S·VW4])`` — no
         all_v1 scalar, because nothing was decided. The caller fetches
         meta-only in the steady state, exactly like the slot-consuming
         GET window."""
@@ -1341,7 +1361,8 @@ class DeviceKVTable:
         program gathers those waves' lookup outputs ON DEVICE (the host
         knows the wave indices at pack time) and packs found/ver/vlen
         into one two-plane i32 tensor, so the readback is two transfers,
-        not four take-dispatch round trips.
+        not four take-dispatch round trips. The values go back flat, in
+        the host's row order (:meth:`_host_rows`).
 
         ``net`` builds the lossy network's twin (``mixed_net``, for a
         kernel with ``net_loss`` > 0): the same scan and reads, its
@@ -1361,6 +1382,7 @@ class DeviceKVTable:
         n = self.n_shards
         I8, I32 = jnp.int8, jnp.int32
         col = jnp.arange(S) < n
+        host_rows = self._host_rows
 
         def waves(state, present, kind_w, ops):
             """The wave scan over the table: one op a shard a wave."""
@@ -1460,7 +1482,7 @@ class DeviceKVTable:
                     valw0, jnp.take(gslot, gidx, axis=0), gidx, wslot,
                     vwin_full,
                 )
-            return meta, gval_g, valw
+            return meta, host_rows(gval_g), valw
 
         def mixed(state, alive, base, depth, kind_w, gidx, ops, *, W,
                   max_phases):
@@ -1569,12 +1591,13 @@ class DeviceKVTable:
         """Dispatch one mixed decide+apply+lookup window. Returns device
         handles ``(new_state, flags, meta, gval)`` where ``meta`` is
         i32[2, Gp, S] ([0]=version, [1]=(vlen<<1)|found) and ``gval``
-        u32[Gp, S, VW4], both gathered to the ``get_waves`` rows (padded
-        to a power of two; the caller maps real rows). The caller reads
-        the 12-byte flags first and fetches meta/gval only on a clean
-        window. ``state`` overrides the table state to run against (the
-        pipelined lane chains on the previous in-flight window's
-        unresolved output, same as :meth:`decide_apply`)."""
+        u32[Gp, S·VW4] (:meth:`_host_rows`), both gathered to the
+        ``get_waves`` rows (padded to a power of two; the caller maps real
+        rows). The caller reads the 12-byte flags first and fetches
+        meta/gval only on a clean window. ``state`` overrides the table
+        state to run against (the pipelined lane chains on the previous
+        in-flight window's unresolved output, same as
+        :meth:`decide_apply`)."""
         ops, kind, gidx, Gp = self._mixed_operands(ops, kind, get_waves, W)
         key = ("mix", W, ops.kwin.shape[2], ops.vwin.shape[2], Gp)
         fn = self._program(
@@ -1896,9 +1919,12 @@ class DeviceKVTable:
 
     @staticmethod
     def _fetch(a) -> np.ndarray:
-        """One device array on the host, whole and contiguous: a fetched
-        sharded array can come back with a non-contiguous layout, which
-        ``.view(uint8)`` rejects."""
+        """One device array on the host, whole and contiguous. The window
+        programs hand their value planes back in the host's row order
+        (:meth:`_host_rows`), and for those this copies nothing; the guard
+        stays for an array a chip lays out otherwise (the table's planes,
+        whose minor axis is under a lane row), whose copy comes back
+        strided, which ``.view(uint8)`` rejects."""
         return np.ascontiguousarray(np.asarray(a))
 
     def upload_from(self, sm, seed_cache: Optional[dict] = None) -> bool:

@@ -330,6 +330,7 @@ def devkv_read_handler(engine) -> ReadHandler:
     semantics owner, synced at demotion). Drive the engine and this
     handler from one thread — the device table is not locked."""
     from rabia_tpu.apps.device_kv import _bucket, _get_frame
+    from rabia_tpu.parallel.mesh_engine import _host_values
 
     def _host_one(shard: int, key: bytes) -> bytes:
         got = engine.sms[0].store.get(shard, key)
@@ -394,10 +395,8 @@ def devkv_read_handler(engine) -> ReadHandler:
                 # eviction edge: this window downloads the value planes
                 engine._read_stats["fallback"] += n_ops
                 resolver = None
-                vlen = np.asarray(vlen_d)
-                valb = np.ascontiguousarray(np.asarray(valw_d)).view(
-                    np.uint8
-                )
+                (vlen, valw), _ = _host_values((vlen_d, valw_d), dev.VW4)
+                valb = valw.view(np.uint8)  # u8[W, S, VW]
             else:
                 resolver = engine._dev_make_resolver()
             for t, wv in enumerate(chunk):

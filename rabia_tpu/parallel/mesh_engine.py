@@ -222,6 +222,7 @@ def _block_op_kind(block) -> Optional[int]:
 
 
 _VALUE_FETCH_OUTCOMES = ("prefetched", "inline", "unused")
+_VALUE_FETCH_LAYOUTS = ("row_major", "strided")
 # Value planes smaller than this are never handed to a readback worker:
 # the hand-over (a pool task, a thread woken, the interpreter lock passed
 # back and forth) costs the window's thread more than downloading them
@@ -229,19 +230,32 @@ _VALUE_FETCH_OUTCOMES = ("prefetched", "inline", "unused")
 _PREFETCH_MIN_BYTES = 1 << 20
 
 
-def _prefetch_values(planes: tuple) -> tuple:
-    """A window's value planes on the host, as a readback worker fetches
-    them: each whole and C-contiguous, so that a wave's row of one is a
-    view and the settle copies nothing. Takes device handles, returns
-    arrays and touches nothing of the engine. The span lies on the
-    worker's thread and outside ``rabia.devkv.*``: no reader of the
-    window's thread sees it."""
+def _host_values(planes: tuple, vw4: int) -> tuple:
+    """A window's value planes on the host: ``(arrays, layout)``. Each
+    array is whole and C-contiguous, so that a wave's row of one is a
+    view and the settle copies nothing; the last, the value words, which
+    the program hands back flat (``DeviceKVTable._host_rows``), is viewed
+    as ``[G, S, VW4]``. ``layout`` says how the device handed them over:
+    ``row_major`` when every plane came back in the host's row order,
+    ``strided`` when ``DeviceKVTable._fetch`` had to copy one. Takes
+    device handles and touches nothing of the engine."""
     from rabia_tpu.apps.device_kv import DeviceKVTable
 
+    got = tuple(map(np.asarray, planes))
+    row_major = all(a.flags.c_contiguous for a in got)
+    *meta, words = map(DeviceKVTable._fetch, got)
+    arrays = (*meta, words.reshape(len(words), -1, vw4))
+    return arrays, "row_major" if row_major else "strided"
+
+
+def _prefetch_values(planes: tuple, vw4: int) -> tuple:
+    """:func:`_host_values` as a readback worker runs it. The span lies on
+    the worker's thread and outside ``rabia.devkv.*``: no reader of the
+    window's thread sees it."""
     with device_annotation(
         "rabia.fetch.values", bytes=sum(int(p.nbytes) for p in planes)
     ):
-        return tuple(map(DeviceKVTable._fetch, planes))
+        return _host_values(planes, vw4)
 
 
 def _fetch_readback(name: str, *handles):
@@ -754,6 +768,18 @@ class MeshEngine:
                 "neither prefetched nor downloaded counts nowhere",
                 {"outcome": _outcome},
                 fn=lambda o=_outcome: self._dev_value_fetch[o],
+            )
+        self._dev_value_layout = dict.fromkeys(_VALUE_FETCH_LAYOUTS, 0)
+        for _layout in _VALUE_FETCH_LAYOUTS:
+            m.counter(
+                "devkv_value_fetch_layout_total",
+                "Value planes a settle took from the device (prefetched or "
+                "inline), by how the device handed them over: row_major = "
+                "in the host's row order, so the fetch was a transfer and "
+                "no copy; strided = the host had to copy them into row "
+                "order (a transpose at ~1 GB/s)",
+                {"layout": _layout},
+                fn=lambda k=_layout: self._dev_value_layout[k],
             )
         m.counter(
             "devkv_program_builds_total",
@@ -1935,7 +1961,7 @@ class MeshEngine:
             return None
         if sum(int(p.nbytes) for p in planes) < _PREFETCH_MIN_BYTES:
             return None
-        return pool.submit(_prefetch_values, planes)
+        return pool.submit(_prefetch_values, planes, self._dev.VW4)
 
     def _dev_settle_values(self, rec, resolved: bool):
         """At settle: the value planes of a GET-bearing window on the
@@ -1957,12 +1983,13 @@ class MeshEngine:
             return None
         outcome = "inline" if fut is None else "prefetched"
         with device_annotation("rabia.cycle.settle.download", outcome=outcome):
-            planes = (
-                tuple(map(np.asarray, rec["val_dev"]))
+            planes, layout = (
+                _host_values(rec["val_dev"], self._dev.VW4)
                 if fut is None
                 else fut.result()
             )
         self._dev_value_fetch[outcome] += 1
+        self._dev_value_layout[layout] += 1
         self._dev_value_download_bytes += sum(p.nbytes for p in planes)
         return planes
 

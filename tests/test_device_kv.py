@@ -2252,7 +2252,10 @@ def _run_both_programs(tab, state, windows, reference=None) -> list:
                 state, alive, base, np.int32(depth), kind, gidx, ops,
                 W=W, max_phases=4,
             )
-            _same_outputs(new, old)
+            # the values go back flat, [Gp, S·VW4]: the same words in
+            # the same order as the reference's [Gp, S, VW4]
+            gval = old[3].reshape(len(gidx), -1)
+            _same_outputs(new, (*old[:3], gval))
         outs.append(new)
         state = new[0]
     return outs

@@ -211,26 +211,39 @@ class TestOutcomes:
 
     def test_prefetched_planes_are_whole_and_contiguous(self, kind, monkeypatch):
         """What the worker hands the settle is one C-contiguous array a
-        plane, so a wave's row of it is a view: the reply copies nothing."""
+        plane, so a wave's row of it is a view: the reply copies nothing.
+        The program hands the value words back flat, ``[G, S·VW4]``, and
+        the worker's ``[G, S, VW4]`` is a view of the array it fetched."""
         from rabia_tpu.apps import device_kv
 
-        rows = []
+        rows, fetched = [], []
         init = device_kv.GetFrameGroups.__init__
+        host_values = mesh_engine._host_values
 
         def recording(self, shards, found, ver, vlen, val_words):
             rows.append(val_words)
             init(self, shards, found, ver, vlen, val_words)
 
+        def recording_fetch(planes, vw4):
+            got = host_values(planes, vw4)
+            fetched.append(np.asarray(planes[-1]))  # the array it fetched
+            return got
+
         monkeypatch.setattr(device_kv.GetFrameGroups, "__init__", recording)
+        monkeypatch.setattr(mesh_engine, "_host_values", recording_fetch)
         dev = _engine(kind)
         _load(dev)
         _run(dev, _read_window(kind, "a"))
-        del rows[:]
+        del rows[:], fetched[:]
         futs = _run(dev, _read_window(kind, "a"))
         assert _fetches(dev)["prefetched"] == 1
+        (plane,) = fetched
+        assert plane.shape == (WINDOW, dev.S * dev._dev.VW4)
+        assert plane.flags["C_CONTIGUOUS"]
         assert len(rows) == WINDOW
         for row, fut in zip(rows, futs):
             assert row.flags["C_CONTIGUOUS"] and row.base is not None
+            assert np.shares_memory(row, plane)
             gf = fut._results
             gf = getattr(gf, "_get", gf)
             assert np.shares_memory(gf.valb, row)
@@ -280,10 +293,10 @@ def slow_worker(monkeypatch):
     fetch = mesh_engine._prefetch_values
     state = {"started": 0, "done": 0, "gate": threading.Event()}
 
-    def held(planes):
+    def held(*args):
         state["started"] += 1
         state["gate"].wait(5.0)
-        out = fetch(planes)
+        out = fetch(*args)
         state["done"] += 1
         return out
 
